@@ -130,7 +130,10 @@ def main() -> None:
     # be forced before jax initializes.  Forcing them here would distort
     # every co-selected single-device benchmark (and the committed
     # artifacts), so unless dist_update is the ONLY selection it runs in
-    # its own subprocess and this process never sees the flag.
+    # its own subprocess and this process never sees the flag.  Keep the
+    # child spawned before this process imports JAX at all: a parent that
+    # has touched JAX holds the chip, and a child that needs it then fails
+    # or hangs.
     dist_selected = wanted is None or "dist_update" in wanted
     dist_done = False
     if dist_selected and wanted == {"dist_update"}:
@@ -148,6 +151,9 @@ def main() -> None:
         dist_done = True
 
     from benchmarks import kernels_bench, paper_tables as P
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     root = pathlib.Path(__file__).resolve().parent.parent
     for name, spec in TABLES.items():

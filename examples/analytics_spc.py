@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analytics import neighbors
+from repro.compile_cache import enable_compile_cache
 from repro.data import graph_stream, random_graph_edges
 from repro.kernels.embedding_bag.ops import embedding_bag
 from repro.models.gnn import from_numpy
@@ -61,6 +62,7 @@ def ego_batch(view, u, candidates, d_in):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200)
     ap.add_argument("--m", type=int, default=600)

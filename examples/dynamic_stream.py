@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph import INF
 from repro.data import graph_stream, random_graph_edges
 from repro.serve import SPCService
@@ -27,6 +28,7 @@ from repro.train import checkpoint as ckpt
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200)
     ap.add_argument("--m", type=int, default=600)

@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import refimpl as R
 from repro.core.graph import INF
 from repro.data import graph_stream, random_graph_edges
@@ -257,6 +258,7 @@ def run_replica(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--role", default="replica",
                     choices=["replica", "updater"])
@@ -291,6 +293,14 @@ def main():
         assert args.dir, "--role updater needs --dir"
         run_updater(args)
         return
+    import jax
+    if jax.default_backend() == "tpu":
+        # checked before the updater is spawned: a chip belongs to one
+        # process, so a second JAX process here would fail or hang
+        sys.exit("fleet_spc.py is a two-host demo: the updater and the "
+                 "replica are separate JAX processes, and one TPU chip "
+                 "serves one process.  Run it with JAX_PLATFORMS=cpu, or "
+                 "run each role on its own host.")
     if args.dir is None:
         with tempfile.TemporaryDirectory(prefix="fleet_spc_") as d:
             args.dir = d

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import molecule_batch
 from repro.models.gnn import egnn
 from repro.models.gnn.graph import from_numpy
@@ -34,6 +35,7 @@ def make_batch(step, batch=16, n_nodes=8, n_edges=16, d_feat=8):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     args = ap.parse_args()

@@ -9,6 +9,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.dynamic import DynamicSPC
 from repro.core.graph import INF
 from repro.core.refimpl import RefGraph, bfs_spc
@@ -38,6 +39,7 @@ def show(svc, edges, s, t, label):
 
 
 def main():
+    enable_compile_cache()
     print("== building SPC-Index of the paper's Figure-2 graph ==")
     svc = DynamicSPC(12, PAPER_EDGES, l_cap=8)
     print(f"  index entries: {svc.index_entries()} "

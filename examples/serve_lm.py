@@ -12,10 +12,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=12)
     ap.add_argument("--batch", type=int, default=4)

@@ -17,6 +17,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import lm_batch
 from repro.models import transformer as tf
 from repro.train import loop, optimizer as opt
@@ -36,6 +37,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=PRESETS, default="tiny")
     ap.add_argument("--steps", type=int, default=30)

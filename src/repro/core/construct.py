@@ -109,7 +109,11 @@ def _hub_batch_round(g: Graph, idx: SPCIndex, h0, hub_batch: int,
     h0 = jnp.asarray(h0, jnp.int32)
     roots = h0 + jnp.arange(hub_batch, dtype=jnp.int32)
     roots_c = jnp.minimum(roots, jnp.int32(g.n))  # inactive -> dump row
-    dbar = jax.vmap(lambda r: one_to_all(idx, r, limit=h0)[0])(roots_c)
+    # one lane at a time: a vmapped gather lays its [lanes, n + 1, L]
+    # index tensor out at many times its size on the TPU (8 GiB of
+    # temporaries at l_cap = 256), one lane's plain gather needs
+    # [n + 1, L] -- the round's footprint stays flat as l_cap grows
+    dbar = jax.lax.map(lambda r: one_to_all(idx, r, limit=h0)[0], roots_c)
     res = multi_pruned_spc_bfs(g, roots, dbar,
                                multi_relax_fn=multi_relax_fn)
     return bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep)

@@ -37,12 +37,8 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
 
 from repro.core import decremental as D
 from repro.core import hybrid as H

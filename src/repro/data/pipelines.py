@@ -8,6 +8,7 @@ noise.
 
 from __future__ import annotations
 
+import bisect
 from typing import Dict, Sequence, Tuple
 
 import numpy as np
@@ -54,23 +55,47 @@ def dien_batch(step: int, batch: int, seq_len: int, n_items: int,
 def random_graph_edges(n: int, m: int, seed: int = 0,
                        power_law: bool = True) -> list[Tuple[int, int]]:
     """Undirected simple graph edge list; power-law degree skew matches
-    the paper's web/social graphs."""
+    the paper's web/social graphs.
+
+    The edge set is that of a per-try loop: draw an endpoint pair, skip
+    self loops, keep the first ``m`` distinct pairs, give up after
+    ``50 m`` tries.  Power-law endpoints are drawn in bulk by searching
+    uniforms against the degree-weight CDF -- the very draw
+    ``rng.choice(n, p=w)`` makes per call, without its O(n) set-up per
+    edge -- so the output is the same for every (n, m, seed).
+    """
     rng = np.random.default_rng(seed)
-    edges: set[Tuple[int, int]] = set()
     if power_law:
         w = 1.0 / (np.arange(1, n + 1) ** 0.8)
         w /= w.sum()
+        cdf = w.cumsum()
+        cdf /= cdf[-1]
+
+        def draw(k):
+            return cdf.searchsorted(rng.random((k, 2)), side="right")
+    else:
+        def draw(k):
+            # per-try calls: bounded-integer draws buffer within a call,
+            # so one bulk call would consume the stream differently
+            return np.array([rng.integers(0, n, size=2) for _ in range(k)],
+                            dtype=np.int64).reshape(k, 2)
+
+    keys = np.empty(0, np.int64)        # accepted lo * n + hi, in order
     tries = 0
-    while len(edges) < m and tries < 50 * m:
-        tries += 1
-        if power_law:
-            a, b = rng.choice(n, size=2, p=w)
-        else:
-            a, b = rng.integers(0, n, size=2)
-        if a == b:
-            continue
-        edges.add((min(int(a), int(b)), max(int(a), int(b))))
-    return sorted(edges)
+    while keys.size < m and tries < 50 * m:
+        k = min(max(1024, 2 * (m - keys.size)), 50 * m - tries)
+        pairs = draw(k)
+        tries += k
+        pairs = pairs[pairs[:, 0] != pairs[:, 1]]
+        lo, hi = pairs.min(axis=1), pairs.max(axis=1)
+        cand = lo.astype(np.int64) * n + hi
+        # first occurrence of each new key, in draw order
+        _, first = np.unique(cand, return_index=True)
+        cand = cand[np.sort(first)]
+        cand = cand[~np.isin(cand, keys)]
+        keys = np.concatenate([keys, cand[: m - keys.size]])
+    keys.sort()
+    return [(int(a), int(b)) for a, b in zip(keys // n, keys % n)]
 
 
 def graph_stream(edges: Sequence[Tuple[int, int]], n: int,
@@ -78,10 +103,14 @@ def graph_stream(edges: Sequence[Tuple[int, int]], n: int,
     """Mixed update stream (Section 4.4): returns list of ('+'/'-', a, b).
 
     Inserted edges are fresh non-edges; deletions pick existing edges
-    (including freshly inserted ones), mirroring the paper's protocol.
+    (including freshly inserted ones), mirroring the paper's protocol:
+    a delete takes the ``rng.integers(0, len(present))``-th present edge
+    in sorted order, kept sorted incrementally instead of re-sorted per
+    delete.
     """
     rng = np.random.default_rng(seed)
     present = set(edges)
+    ordered = sorted(present)
     events = []
     ops = ["+"] * n_insert + ["-"] * n_delete
     rng.shuffle(ops)
@@ -92,13 +121,13 @@ def graph_stream(edges: Sequence[Tuple[int, int]], n: int,
                 key = (min(int(a), int(b)), max(int(a), int(b)))
                 if a != b and key not in present:
                     present.add(key)
+                    bisect.insort(ordered, key)
                     events.append(("+", key[0], key[1]))
                     break
         else:
             if not present:
                 continue
-            idx = rng.integers(0, len(present))
-            key = sorted(present)[idx]
+            key = ordered.pop(rng.integers(0, len(present)))
             present.discard(key)
             events.append(("-", key[0], key[1]))
     return events

@@ -1,24 +1,31 @@
 """Pallas TPU kernel: batched SPC-Index pair queries (Algorithm 1).
 
 Serving hot path: given B (s, t) pairs with their label rows resident, the
-kernel evaluates the hub intersection as an L x L comparison table per
-pair -- a dense VPU pattern replacing the paper's sorted merge-join (data-
-dependent control flow does not map to the TPU vector unit; the L^2 table
-at L <= 256 is cheaper than a serialized merge at 1 element/cycle).
+kernel evaluates the hub intersection as an L x L comparison per pair -- a
+dense VPU pattern replacing the paper's sorted merge-join (data-dependent
+control flow does not map to the TPU vector unit).
 
-Tiling: the pair batch streams through VMEM in blocks of ``block_b``; the
-six label operands of one block occupy 6 * block_b * L * 4 bytes (at the
-default block_b=128, L=128: 384 KiB), leaving the comparison table
-(block_b * L fp32 lanes, materialized L-row-at-a-time by Mosaic) well
-inside the ~16 MiB VMEM budget.
+Layout: the operands arrive transposed, [L, B], so the pair batch lies on
+the 128 vector lanes and the label axis on sublanes.  A block holds
+``block_b`` pairs (a multiple of 128) of all six operands; one
+``fori_loop`` step takes the j-th t-side label of every pair ([1, block_b]),
+compares it against the whole s-side row ([L, block_b]) and folds the
+column's minimum distance and its count into running [1, block_b]
+accumulators.  No [block_b, L, L] table is ever live: VMEM holds the six
+operand blocks (6 * L * block_b * 4 bytes, 768 KiB at L = 256, double
+buffered) plus a few [L, block_b] temporaries.
 
 Counts are fp32 *in the kernel only* (TPU VPU has no int64): exact up to
 2^24.  Callers must not invoke this kernel blind on dense/high-
 multiplicity graphs -- ``ops.index_query_batch`` (and the serving engine
 ``repro.serve``) guard it with the per-row count bound and fall back to
-the int64 sorted-merge path when a row could exceed 2^24; the int64 jnp
-path in ``repro.core.query`` remains the default for index maintenance
-(see DESIGN.md "Hardware adaptation").
+the int64 sorted-merge path when a row could exceed 2^24.  Under that
+bound every product and partial sum is an exact integer, so the order in
+which the column loop adds them does not change the result.
+
+All scalars in the body are explicit int32/fp32: the package enables
+x64, and a weakly typed Python int would trace as int64, which Mosaic
+cannot lower.
 """
 
 from __future__ import annotations
@@ -34,18 +41,43 @@ from repro.kernels.common import ceil_div, pad_to, resolve_interpret
 INF = 1 << 28
 _BIG = INF * 2
 
+#: The pair batch lies on the vector lanes: ``block_b`` must be a
+#: positive multiple of this.
+LANES = 128
+
+
+def check_block_b(block_b) -> int:
+    """Validate a kernel row-block size (a positive multiple of LANES)."""
+    if not isinstance(block_b, int) or block_b <= 0 or block_b % LANES:
+        raise ValueError(f"block_b must be a positive multiple of {LANES}, "
+                         f"got {block_b!r}")
+    return block_b
+
 
 def _kernel(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, d_out, c_out):
-    eq = hub_s[...][:, :, None] == hub_t[...][:, None, :]       # [b, L, L]
-    dsum = dist_s[...][:, :, None] + dist_t[...][:, None, :]
-    dsum = jnp.where(eq, dsum, _BIG)
-    d = jnp.min(dsum, axis=(1, 2))                               # [b]
-    prod = cnt_s[...][:, :, None] * cnt_t[...][:, None, :]
-    hit = dsum == d[:, None, None]
-    c = jnp.sum(jnp.where(hit, prod, 0.0), axis=(1, 2))
-    connected = d < INF
-    d_out[...] = jnp.where(connected, d, INF).astype(jnp.int32)
-    c_out[...] = jnp.where(connected, c, 0.0).astype(jnp.float32)
+    l = hub_t.shape[0]
+    big = jnp.int32(_BIG)
+
+    def column(j, carry):
+        best_d, best_c = carry                       # [1, b] each
+        row = pl.ds(j, 1)                            # j-th t label
+        dsum = jnp.where(hub_s[...] == hub_t[row, :],
+                         dist_s[...] + dist_t[row, :], big)   # [L, b]
+        m = jnp.min(dsum, axis=0, keepdims=True)
+        hit = (dsum == m) & (dsum < big)
+        c = jnp.sum(jnp.where(hit, cnt_s[...], jnp.float32(0)), axis=0,
+                    keepdims=True) * cnt_t[row, :]
+        best_c = jnp.where(m < best_d, c,
+                           jnp.where(m == best_d, best_c + c, best_c))
+        return jnp.minimum(best_d, m), best_c
+
+    shape = d_out.shape
+    d, c = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(l), column,
+        (jnp.full(shape, big, jnp.int32), jnp.zeros(shape, jnp.float32)))
+    connected = d < jnp.int32(INF)
+    d_out[...] = jnp.where(connected, d, jnp.int32(INF))
+    c_out[...] = jnp.where(connected, c, jnp.float32(0))
 
 
 def spc_query_pallas(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
@@ -57,6 +89,7 @@ def spc_query_pallas(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
         whose dist is INF).
       dist_s, dist_t: int32[B, L] hub distances (pad INF).
       cnt_s, cnt_t: float32[B, L] hub counts (pad 0).
+      block_b: pairs per grid step, a positive multiple of ``LANES``.
     Returns:
       (dist int32[B], count float32[B]); disconnected pairs -> (INF, 0).
 
@@ -66,7 +99,7 @@ def spc_query_pallas(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
     call's cached trace.
     """
     return _spc_query_jit(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
-                          block_b=block_b,
+                          block_b=check_block_b(block_b),
                           interpret=resolve_interpret(interpret))
 
 
@@ -75,21 +108,22 @@ def _spc_query_jit(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
                    *, block_b: int, interpret: bool):
     b, l = hub_s.shape
     bp = ceil_div(b, block_b) * block_b
-    args = [pad_to(x, block_b, 0, value=pad) for x, pad in (
+    args = [pad_to(x.T, block_b, 1, value=pad) for x, pad in (
         (hub_s, 0), (dist_s, INF), (cnt_s, 0.0),
         (hub_t, 1), (dist_t, INF), (cnt_t, 0.0))]
-    grid = (bp // block_b,)
-    row = pl.BlockSpec((block_b, l), lambda i: (i, 0))
-    out = pl.BlockSpec((block_b,), lambda i: (i,))
+    # the block index is (0, i): an int32 zero, not a Python 0 that x64
+    # would trace as int64 into the index map
+    col = pl.BlockSpec((l, block_b), lambda i: (i * 0, i))
+    out = pl.BlockSpec((1, block_b), lambda i: (i * 0, i))
     d, c = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[row] * 6,
+        grid=(bp // block_b,),
+        in_specs=[col] * 6,
         out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((bp,), jnp.int32),
-            jax.ShapeDtypeStruct((bp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((1, bp), jnp.float32),
         ],
         interpret=interpret,
     )(*args)
-    return d[:b], c[:b]
+    return d[0, :b], c[0, :b]

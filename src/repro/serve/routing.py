@@ -31,6 +31,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Tuple
 
+from repro.kernels.spc_query.kernel import LANES, check_block_b
+
 #: Kinds a policy may name.  The first four map 1:1 onto the engine's
 #: single-device routes; ``sharded`` selects the multi-device replica
 #: path (``QueryEngine.sharded``) and needs a serving mesh at bind time.
@@ -39,19 +41,20 @@ KINDS = ("auto", "merge", "table", "pallas", "sharded")
 #: Kinds that reach the Pallas kernel and may carry its knobs.
 _KERNEL_KINDS = ("auto", "pallas")
 
-_DEFAULT_BLOCK_B = 128
+_DEFAULT_BLOCK_B = LANES
 
 
 @dataclasses.dataclass(frozen=True)
 class RoutePolicy:
     """One validated serving-route decision (see module doc).
 
-    Build through the classmethods (``RoutePolicy.pallas(block_b=64)``)
+    Build through the classmethods (``RoutePolicy.pallas(block_b=256)``)
     or coerce a legacy string (``RoutePolicy.coerce("merge")``).
     """
 
     kind: str
-    #: Pallas kernel row-block size (kernel kinds only).
+    #: Pallas kernel row-block size (kernel kinds only): a positive
+    #: multiple of 128, the vector lanes the pair batch is laid on.
     block_b: int = _DEFAULT_BLOCK_B
     #: Force/forbid kernel interpret mode; None = derive from backend at
     #: dispatch time (kernel kinds only).
@@ -74,9 +77,7 @@ class RoutePolicy:
             raise ValueError(
                 f"batch_axes only apply to the 'sharded' route, not "
                 f"{self.kind!r}")
-        if not isinstance(self.block_b, int) or self.block_b <= 0:
-            raise ValueError(f"block_b must be a positive int, got "
-                             f"{self.block_b!r}")
+        check_block_b(self.block_b)   # a size the chip's compiler takes
         if self.kind not in _KERNEL_KINDS:
             if self.block_b != _DEFAULT_BLOCK_B or self.interpret is not None:
                 raise ValueError(
@@ -117,7 +118,7 @@ class RoutePolicy:
         """Upgrade a route name (or None) to a policy; pass policies
         through.  The migration shim for the legacy string API.
 
-        A mapping coerces too -- ``{"kind": "pallas", "block_b": 64}``
+        A mapping coerces too -- ``{"kind": "pallas", "block_b": 256}``
         -- so config files and front-door knobs can carry the whole
         route decision as plain data instead of only the kind string."""
         if route is None:

@@ -29,7 +29,7 @@ def rng(seed=0):
 # ---------------------------------------------------------------------------
 class TestSpcQueryKernel:
     @pytest.mark.parametrize("b,l,block_b", [
-        (4, 8, 128), (130, 16, 64), (256, 32, 128), (17, 128, 8),
+        (4, 8, 128), (130, 16, 128), (256, 32, 256), (17, 128, 128),
     ])
     def test_sweep_vs_ref(self, b, l, block_b):
         r = rng(b * l)

@@ -87,7 +87,7 @@ def test_all_four_entries_resolve_through_common(monkeypatch):
                                block_s=8, interpret=True)
         sm.segment_matmul_pallas(vals, dst, 2, block_e=4, block_n=2,
                                  interpret=True)
-        sq.spc_query_pallas(hub, dist, cnt, hub, dist, cnt, block_b=2,
+        sq.spc_query_pallas(hub, dist, cnt, hub, dist, cnt, block_b=128,
                             interpret=True)
 
     for mod in (eb, fd, sm, sq):

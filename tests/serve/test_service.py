@@ -68,7 +68,7 @@ def test_route_policy_validation():
     # kernel knobs only on kernel kinds; axes only on sharded -- all at
     # construction, not at dispatch
     with pytest.raises(ValueError, match="kernel knobs"):
-        RoutePolicy("merge", block_b=64)
+        RoutePolicy("merge", block_b=256)
     with pytest.raises(ValueError, match="kernel knobs"):
         RoutePolicy("table", interpret=True)
     with pytest.raises(ValueError, match="batch_axes"):
@@ -77,16 +77,18 @@ def test_route_policy_validation():
         RoutePolicy("sharded", batch_axes=())
     with pytest.raises(ValueError, match="block_b"):
         RoutePolicy.pallas(block_b=0)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        RoutePolicy.pallas(block_b=64)    # off the lane tiling
     with pytest.raises(dataclasses.FrozenInstanceError):
         RoutePolicy.merge().kind = "table"
-    assert RoutePolicy.pallas(block_b=64) == RoutePolicy.pallas(block_b=64)
+    assert RoutePolicy.pallas(block_b=256) == RoutePolicy.pallas(block_b=256)
     assert len({RoutePolicy.merge(), RoutePolicy.merge()}) == 1
 
 
 def test_route_policy_binds_to_engine():
-    pol = RoutePolicy.pallas(block_b=64, interpret=True)
+    pol = RoutePolicy.pallas(block_b=256, interpret=True)
     eng = QueryEngine(route=pol)
-    assert (eng.route, eng.block_b, eng.interpret) == ("pallas", 64, True)
+    assert (eng.route, eng.block_b, eng.interpret) == ("pallas", 256, True)
     svc = DynamicSPC(N, random_graph_edges(N, M, seed=SEED), l_cap=32)
     eng2 = QueryEngine()
     d, c = eng2.query_batch(svc.index, [0, 1], [2, 3],
@@ -101,7 +103,7 @@ def test_route_policy_binds_to_engine():
                          route=RoutePolicy.sharded())
     with pytest.raises(ValueError, match="kernel knobs"):
         eng2.query_batch(svc.index, [0], [1],
-                         route=RoutePolicy.pallas(block_b=64))
+                         route=RoutePolicy.pallas(block_b=256))
 
 
 # -- differential: façade reads vs the BFS oracle ---------------------------
@@ -474,15 +476,15 @@ def test_close_detects_stuck_updater_thread():
 
 def test_route_policy_coerces_mappings():
     """Configs and front-door knobs carry the route as plain data."""
-    assert RoutePolicy.coerce({"kind": "pallas", "block_b": 64}) == \
-        RoutePolicy.pallas(block_b=64)
+    assert RoutePolicy.coerce({"kind": "pallas", "block_b": 256}) == \
+        RoutePolicy.pallas(block_b=256)
     assert RoutePolicy.coerce({}) == RoutePolicy.auto()
     sh = RoutePolicy.coerce({"kind": "sharded", "batch_axes": ["x", "y"]})
     assert sh.batch_axes == ("x", "y") and sh.needs_mesh
     with pytest.raises(ValueError, match="unknown keys"):
         RoutePolicy.coerce({"kind": "merge", "blocksize": 9})
     with pytest.raises(ValueError, match="kernel knobs"):
-        RoutePolicy.coerce({"kind": "merge", "block_b": 64})
+        RoutePolicy.coerce({"kind": "merge", "block_b": 256})
 
 
 # -- routing through the service -------------------------------------------
@@ -545,10 +547,10 @@ def test_dedicated_policy_engines_are_cached():
     dedicated engine -- ONE per knob pair, however many readers -- and
     the round-robin pool never serves foreign knobs."""
     with _service() as svc:
-        pol = RoutePolicy.pallas(block_b=64)
+        pol = RoutePolicy.pallas(block_b=256)
         rs = [svc.reader(route=pol) for _ in range(3)]
         assert rs[0].engine is rs[1].engine is rs[2].engine
-        assert rs[0].engine.block_b == 64
+        assert rs[0].engine.block_b == 256
         assert len(svc._engines) == 1    # pool: default-knob replicas only
         assert len(svc._dedicated) == 1
         assert svc.reader().engine is svc._engines[0]  # shared path
