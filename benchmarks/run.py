@@ -172,6 +172,8 @@ def main() -> None:
     if wanted is None or "kernels" in wanted:
         t0 = time.perf_counter()
         kernels_bench.query_kernel_vs_jnp()
+        kernels_bench.query_kernel_vs_merge(
+            ls=(64,) if args.fast else (64, 256, 1024))
         kernels_bench.segment_matmul_vs_segment_sum()
         print(f"## kernels done in {time.perf_counter() - t0:.1f}s\n")
 

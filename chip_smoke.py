@@ -2,7 +2,7 @@
 
 One process builds the ``CONFIG`` deployment (``repro.configs.dspc``:
 65,536 vertices, 524,288 power-law edges generated from ``--seed``),
-with n and m halved ``--halvings`` times, through
+with n and m halved ``HALVINGS`` times, through
 ``SPCService.from_config``, ingests a mixed update stream through
 ``submit``, serves pinned, read-your-writes and ``FrontDoor`` reads, and
 checks the answers: every kernel-routed batch against the int64 merge
@@ -41,15 +41,18 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 BUCKETS = (8, 64, 256, 1024)
 
-#: CONFIG's n and m are halved this many times by default (same degree
-#: law, 1/64 of the vertices), and the stream is EVENTS long.  On a v5e
-#: chip the full-size build reaches l_cap 1024 within its first 512 hubs
-#: at seconds per 32-hub round, and at n = 1,024 the update engine applies
+#: CONFIG's n and m are halved this many times (same degree law, 1/64 of
+#: the vertices), and the stream is EVENTS long.  On a v5e chip the
+#: full-size build reaches l_cap 1024 within its first 512 hubs at
+#: seconds per 32-hub round, and at n = 1,024 the update engine applies
 #: 0.6 events/s (every insert repairs one BFS per hub in the union of two
 #: labels), so neither the full build nor a 512-event stream fits the
 #: run's time limit.  Raise both as the build and update engines speed up.
 HALVINGS = 6
 EVENTS = 128
+#: The four-chip phase replays its stream through two services (sharded
+#: and one chip) on four chips' time, so its stream is shorter.
+FOUR_CHIP_EVENTS = 16
 KERNEL_ROUTES = ("pallas", "pallas+merge")
 
 
@@ -287,8 +290,8 @@ def run_one_chip(args, cfg) -> None:
           f"{time.perf_counter() - t0:.2f}s", flush=True)
     svc = build(cfg, edges, "1 chip")
     with svc:
-        events = graph_stream(edges, cfg.n, 3 * args.events // 4,
-                              args.events // 4, seed=args.seed + 1)
+        events = graph_stream(edges, cfg.n, 3 * EVENTS // 4, EVENTS // 4,
+                              seed=args.seed + 1)
         ingest(svc, events, "1 chip")
         check_reads(svc, rng)
         check_read_your_writes(svc)
@@ -309,8 +312,8 @@ def run_four_chips(args, cfg) -> None:
     rng = np.random.default_rng(args.seed)
     devs = np.asarray(jax.devices()[:4])
     edges = random_graph_edges(cfg.n, cfg.m, seed=args.seed)
-    events = graph_stream(edges, cfg.n, 3 * args.events // 4,
-                          args.events // 4, seed=args.seed + 1)
+    events = graph_stream(edges, cfg.n, 3 * FOUR_CHIP_EVENTS // 4,
+                          FOUR_CHIP_EVENTS // 4, seed=args.seed + 1)
     sharded = build(cfg, edges, "4 chips", mesh=Mesh(devs, ("model",)),
                     serve_mesh=Mesh(devs, ("data",)), route="sharded")
     single = build(cfg, edges, "1 chip")
@@ -338,17 +341,13 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the edge-sharded four-chip phase")
-    ap.add_argument("--events", type=int, default=EVENTS,
-                    help="update events (3 inserts : 1 delete)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--halvings", type=int, default=HALVINGS,
-                    help="halve CONFIG's n and m this many times")
     args = ap.parse_args()
 
     from repro.configs.dspc import CONFIG
 
-    cfg = dataclasses.replace(CONFIG, n=CONFIG.n >> args.halvings,
-                              m=CONFIG.m >> args.halvings)
+    cfg = dataclasses.replace(CONFIG, n=CONFIG.n >> HALVINGS,
+                              m=CONFIG.m >> HALVINGS)
 
     device = check_device(args.chips)
     if args.chips == 4:

@@ -44,7 +44,7 @@ from repro.core.graph import Graph, degrees
 from repro.core.labels import (SPCIndex, bulk_append, bulk_append_batch,
                                empty_index, repad)
 from repro.core.order import graph_ordering, relabel_graph
-from repro.core.query import one_to_all
+from repro.core.query import one_to_all, one_to_all_dists
 
 
 def _hub_round(g: Graph, idx: SPCIndex, v,
@@ -109,11 +109,7 @@ def _hub_batch_round(g: Graph, idx: SPCIndex, h0, hub_batch: int,
     h0 = jnp.asarray(h0, jnp.int32)
     roots = h0 + jnp.arange(hub_batch, dtype=jnp.int32)
     roots_c = jnp.minimum(roots, jnp.int32(g.n))  # inactive -> dump row
-    # one lane at a time: a vmapped gather lays its [lanes, n + 1, L]
-    # index tensor out at many times its size on the TPU (8 GiB of
-    # temporaries at l_cap = 256), one lane's plain gather needs
-    # [n + 1, L] -- the round's footprint stays flat as l_cap grows
-    dbar = jax.lax.map(lambda r: one_to_all(idx, r, limit=h0)[0], roots_c)
+    dbar = one_to_all_dists(idx, roots_c, h0)
     res = multi_pruned_spc_bfs(g, roots, dbar,
                                multi_relax_fn=multi_relax_fn)
     return bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep)

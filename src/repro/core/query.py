@@ -198,3 +198,36 @@ def one_to_all(idx: SPCIndex, h, limit=None):
     disconnected = d >= INF
     return (jnp.where(disconnected, INF, d).astype(jnp.int32),
             jnp.where(disconnected, 0, c))
+
+
+def one_to_all_dists(idx: SPCIndex, roots, limit, cols: int = 64):
+    """dist int32[R, n+1]: ``one_to_all(idx, r, limit)[0]`` for each root.
+
+    The roots' dense distance tables sit side by side as [n + 1, R], so
+    each label entry of v fetches one R-wide row, and the label axis is
+    reduced ``cols`` columns at a time: the live candidates are
+    [n + 1, cols, R] at any l_cap.  (Gathering [n + 1, L] once per root
+    is several times slower on a TPU; a vmap over roots needs
+    [n + 1, L, R] at once, past one chip's HBM at l_cap 512 for
+    n = 65,536.)
+    """
+    n = idx.n
+    dense = jax.vmap(lambda r: dense_tables(idx, r, limit)[0],
+                     out_axes=1)(roots)                     # [n+1, R]
+    l = idx.hub.shape[1]
+    cols = min(cols, l)
+    pad = -l % cols
+    hub = jnp.pad(idx.hub, ((0, 0), (0, pad)), constant_values=n)
+    dist = jnp.pad(idx.dist, ((0, 0), (0, pad)), constant_values=INF)
+
+    def chunk(j, best):
+        h = jax.lax.dynamic_slice_in_dim(hub, j * cols, cols, axis=1)
+        cand = dense[h] + jax.lax.dynamic_slice_in_dim(
+            dist, j * cols, cols, axis=1)[:, :, None]      # [n+1, cols, R]
+        live = (h < limit) & (h < n)                        # drop pads
+        cand = jnp.where(live[:, :, None], cand, _BIG)
+        return jnp.minimum(best, jnp.min(cand, axis=1))
+
+    best = jax.lax.fori_loop(0, (l + pad) // cols, chunk,
+                             jnp.full(dense.shape, _BIG, jnp.int32))
+    return jnp.where(best >= INF, INF, best).T.astype(jnp.int32)

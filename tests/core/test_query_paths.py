@@ -8,7 +8,8 @@ import pytest
 from repro.core import build_index, from_edges
 from repro.core.labels import to_ref
 from repro.core.query import (batched_query, batched_query_jit,
-                              batched_query_merge)
+                              batched_query_merge, one_to_all,
+                              one_to_all_dists)
 from repro.data import random_graph_edges
 
 
@@ -45,3 +46,17 @@ def test_merge_handles_disconnected_and_identity():
     assert (int(d[0]), int(c[0])) == (1, 1)
     assert int(c[1]) == 0 and int(d[1]) >= (1 << 28)
     assert (int(d[2]), int(c[2])) == (0, 1)
+
+
+@pytest.mark.parametrize("limit,cols", [(0, 64), (9, 5), (30, 1), (51, 64)])
+def test_one_to_all_dists_equals_one_to_all(limit, cols):
+    """All roots at once, any column chunk (one that does not divide
+    l_cap included), equals ``one_to_all`` root by root."""
+    n = 50
+    g = from_edges(n, random_graph_edges(n, 120, seed=5))
+    idx = build_index(g, l_cap=n + 2)
+    roots = jnp.asarray([0, 1, 7, 30, 49, n], jnp.int32)   # n: dump row
+    got = one_to_all_dists(idx, roots, jnp.int32(limit), cols=cols)
+    for k, r in enumerate(roots):
+        want, _ = one_to_all(idx, r, limit=jnp.int32(limit))
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want))

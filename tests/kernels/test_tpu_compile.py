@@ -58,8 +58,9 @@ def _index(chip, l_cap):
                     overflow=chip((), jnp.int32), n=N)
 
 
-@pytest.mark.parametrize("l_cap", [64, 128, 256])
+@pytest.mark.parametrize("l_cap", [64, 128, 256, 512, 1024])
 def test_spc_query_kernel_compiles(chip, l_cap):
+    """Up to 1024, the label capacity the CONFIG build reaches."""
     i32 = chip((1024, l_cap), jnp.int32)
     f32 = chip((1024, l_cap), jnp.float32)
     compiled = _spc_query_jit.lower(i32, i32, f32, i32, i32, f32,
