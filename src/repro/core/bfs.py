@@ -60,6 +60,25 @@ class BFSResult(NamedTuple):
     levels: jax.Array  # int32: number of relaxation rounds executed
 
 
+class RepairWork(NamedTuple):
+    """Work an update engine did, counted inside its trace (int32
+    scalars): pruned repair BFSs run (one per affected hub), relaxation
+    rounds run (each one full-edge ``relax_fn`` pass; the repairs'
+    ``levels`` plus SRRSearch's), and deletions that took the
+    isolated-vertex fast path."""
+
+    hub_repairs: jax.Array
+    relax_rounds: jax.Array
+    isolated_fast_path: jax.Array
+
+    @classmethod
+    def zero(cls) -> "RepairWork":
+        return cls(jnp.int32(0), jnp.int32(0), jnp.int32(0))
+
+    def plus(self, other: "RepairWork") -> "RepairWork":
+        return RepairWork(*(a + b for a, b in zip(self, other)))
+
+
 class MultiBFSResult(NamedTuple):
     """Per-hub-batch BFS state: every array carries a leading [B] axis."""
 

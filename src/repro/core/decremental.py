@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph as G
-from repro.core.bfs import RelaxFn, conditional_spc_bfs, pruned_spc_bfs
+from repro.core.bfs import (RelaxFn, RepairWork, conditional_spc_bfs,
+                            pruned_spc_bfs)
 from repro.core.graph import INF, Graph
 from repro.core.labels import (SPCIndex, bulk_remove, bulk_upsert,
                                reset_isolated_row)
@@ -39,6 +40,7 @@ class SRRSets(NamedTuple):
     r_a: jax.Array
     r_b: jax.Array
     l_ab: jax.Array  # bool[n + 1]: common hubs of a and b
+    rounds: jax.Array  # int32: relaxation rounds of the two BFSs
 
 
 def _side(g: Graph, idx: SPCIndex, root, d_other, c_other, l_ab,
@@ -50,7 +52,7 @@ def _side(g: Graph, idx: SPCIndex, root, d_other, c_other, l_ab,
     unpruned = visited & (res.dist + 1 == d_other)
     sr = unpruned & (l_ab | (res.cnt == c_other))
     r = unpruned & ~sr
-    return sr, r
+    return sr, r, res.levels
 
 
 def srr_search(g: Graph, idx: SPCIndex, a, b,
@@ -64,29 +66,36 @@ def srr_search(g: Graph, idx: SPCIndex, a, b,
     l_ab = in_a & in_b
     d_b, c_b = one_to_all(idx, b)  # SpcQuery(v, b) for every v
     d_a, c_a = one_to_all(idx, a)
-    sr_a, r_a = _side(g, idx, a, d_b, c_b, l_ab, relax_fn)
-    sr_b, r_b = _side(g, idx, b, d_a, c_a, l_ab, relax_fn)
-    return SRRSets(sr_a=sr_a, sr_b=sr_b, r_a=r_a, r_b=r_b, l_ab=l_ab)
+    sr_a, r_a, rounds_a = _side(g, idx, a, d_b, c_b, l_ab, relax_fn)
+    sr_b, r_b, rounds_b = _side(g, idx, b, d_a, c_a, l_ab, relax_fn)
+    return SRRSets(sr_a=sr_a, sr_b=sr_b, r_a=r_a, r_b=r_b, l_ab=l_ab,
+                   rounds=rounds_a + rounds_b)
 
 
 def _dec_update(g: Graph, idx: SPCIndex, h, affected, h_ab,
-                relax_fn: RelaxFn | None = None) -> SPCIndex:
-    """Algorithm 6, bulk form (post-deletion graph)."""
+                relax_fn: RelaxFn | None = None
+                ) -> tuple[SPCIndex, jax.Array]:
+    """Algorithm 6, bulk form (post-deletion graph).  Returns the index
+    and the repair BFS's relaxation rounds."""
     dpre, _ = one_to_all(idx, h, limit=h)  # PreQuery(h, v) for every v
     res = pruned_spc_bfs(g, h, 0, 1, dbar=dpre, rank_floor=h,
                          relax_fn=relax_fn)
     upd = res.keep & affected  # U[.]
     idx = bulk_upsert(idx, h, res.dist, res.cnt, upd)
     remove_mask = affected & ~upd
-    return jax.lax.cond(
+    idx = jax.lax.cond(
         h_ab,
         lambda i: bulk_remove(i, h, remove_mask),
         lambda i: i, idx)
+    return idx, res.levels
 
 
-def _dec_spc(g: Graph, idx: SPCIndex, a, b,
-             relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
-    """Algorithm 4 (traced body; see :func:`dec_spc`)."""
+def _dec_spc_work(g: Graph, idx: SPCIndex, a, b,
+                  relax_fn: RelaxFn | None = None
+                  ) -> tuple[Graph, SPCIndex, RepairWork]:
+    """Algorithm 4 (traced body; see :func:`dec_spc`), with the work it
+    did: one hub repair per hub of SR, and the rounds of those repairs
+    and of SRRSearch."""
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
     n = idx.n
@@ -102,18 +111,29 @@ def _dec_spc(g: Graph, idx: SPCIndex, a, b,
     k_max = sr_ids.shape[0]
 
     def cond(state):
-        k, _ = state
+        k, _, _ = state
         return (k < k_max) & (sr_ids[jnp.minimum(k, k_max - 1)] < n)
 
     def body(state):
-        k, idx = state
+        k, idx, rounds = state
         h = sr_ids[k]
         is_a_side = sets.sr_a[h]
         affected = jnp.where(is_a_side, aff_b, aff_a)
-        idx = _dec_update(g2, idx, h, affected, sets.l_ab[h], relax_fn)
-        return k + 1, idx
+        idx, levels = _dec_update(g2, idx, h, affected, sets.l_ab[h],
+                                  relax_fn)
+        return k + 1, idx, rounds + levels
 
-    _, idx = jax.lax.while_loop(cond, body, (jnp.int32(0), idx))
+    repairs, idx, rounds = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), idx, jnp.int32(0)))
+    return g2, idx, RepairWork(hub_repairs=repairs,
+                               relax_rounds=sets.rounds + rounds,
+                               isolated_fast_path=jnp.int32(0))
+
+
+def _dec_spc(g: Graph, idx: SPCIndex, a, b,
+             relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
+    """Algorithm 4 (traced body; see :func:`dec_spc`)."""
+    g2, idx, _ = _dec_spc_work(g, idx, a, b, relax_fn)
     return g2, idx
 
 
@@ -121,10 +141,12 @@ def _dec_spc(g: Graph, idx: SPCIndex, a, b,
 dec_spc = jax.jit(_dec_spc, static_argnames=("relax_fn",))
 
 
-def dec_spc_step(g: Graph, idx: SPCIndex, a, b,
-                 relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
+def dec_spc_step_work(g: Graph, idx: SPCIndex, a, b,
+                      relax_fn: RelaxFn | None = None
+                      ) -> tuple[Graph, SPCIndex, RepairWork]:
     """Traced single deletion with the Section 3.2.3 isolated-vertex fast
-    path folded in.
+    path folded in, and the work it did (a fast path counts as one
+    ``isolated_fast_path`` and no repair).
 
     Mirrors the host driver's ``delete_edge`` exactly: when the
     lower-ranked endpoint has degree 1 it becomes isolated, is never a
@@ -141,13 +163,21 @@ def dec_spc_step(g: Graph, idx: SPCIndex, a, b,
 
     def fast(args):
         g, idx = args
-        return G.delete_edge(g, a, b), reset_isolated_row(idx, hi)
+        return (G.delete_edge(g, a, b), reset_isolated_row(idx, hi),
+                RepairWork.zero()._replace(isolated_fast_path=jnp.int32(1)))
 
     def full(args):
         g, idx = args
-        return _dec_spc(g, idx, a, b, relax_fn)
+        return _dec_spc_work(g, idx, a, b, relax_fn)
 
     return jax.lax.cond(deg_hi == 1, fast, full, (g, idx))
+
+
+def dec_spc_step(g: Graph, idx: SPCIndex, a, b,
+                 relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
+    """:func:`dec_spc_step_work` without the work counts."""
+    g2, idx, _ = dec_spc_step_work(g, idx, a, b, relax_fn)
+    return g2, idx
 
 
 #: One-dispatch variant of :func:`dec_spc_step` (the distributed updater

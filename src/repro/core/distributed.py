@@ -149,7 +149,7 @@ class DistributedUpdater:
     dec_spc: Callable        # (g, idx, a, b) -> (g, idx), no fast path
     dec_spc_step: Callable   # dec_spc + traced isolated-vertex fast path
     dec_spc_batch: Callable  # (g, idx, edges[B, 2]) -> (g, idx)
-    hyb_spc_batch: Callable  # (g, idx, events[B, 3]) -> (g, idx)
+    hyb_spc_batch: Callable  # (g, idx, events[B, 3]) -> ((g, work), idx)
 
     def pad(self, g: Graph) -> Graph:
         return pad_graph_for(g, self.num_shards)

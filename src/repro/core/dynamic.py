@@ -53,6 +53,7 @@ from repro.core.incremental import inc_spc
 from repro.core.labels import SPCIndex
 from repro.core.order import (identity_ordering, ordering_from_state,
                               vertex_ordering)
+from repro.spans import span
 
 
 #: Default chunk size for batched event replay.  Chunks are padded to
@@ -72,6 +73,8 @@ class UpdateStatsView:
     edge_regrows: int
     batches: int
     batched_events: int
+    hub_repairs: int
+    relax_rounds: int
 
     @property
     def events_per_batch(self) -> float:
@@ -82,12 +85,18 @@ class UpdateStatsView:
 class UpdateStats:
     inserts: int = 0
     deletions: int = 0
-    isolated_fast_path: int = 0  # host-side fast path only; the batched
-    # engine takes the same shortcut inside the trace without counting.
+    isolated_fast_path: int = 0  # deletions that took the fast path,
+    # on the host path and inside the hybrid engine's trace
     label_regrows: int = 0
     edge_regrows: int = 0
     batches: int = 0          # jitted hybrid-engine dispatches
     batched_events: int = 0   # events carried by those dispatches
+    #: work the hybrid engine counted inside its dispatches, retried ones
+    #: included (``bfs.RepairWork``): per-hub repair BFSs run, and
+    #: relaxation rounds (one full-edge ``segment_sum`` each) of those
+    #: and of SRRSearch
+    hub_repairs: int = 0
+    relax_rounds: int = 0
 
     def __post_init__(self):
         # one updater thread writes, but serving/monitoring threads read
@@ -234,9 +243,10 @@ class DynamicSPC:
         """Bump the version and publish the committed snapshot (if a
         store is attached).  Called exactly once per successful public
         mutation / event chunk, after overflow-retry has settled."""
-        self.version += 1
-        if self._store is not None:
-            self._store.publish(self.index, version=self.version)
+        with span("spc.update.publish"):
+            self.version += 1
+            if self._store is not None:
+                self._store.publish(self.index, version=self.version)
 
     def query(self, s: int, t: int) -> Tuple[int, int]:
         # bounds validation happens inside the engine (host-side);
@@ -446,8 +456,19 @@ class DynamicSPC:
         falls back to one jitted dispatch per event -- kept as the
         differential-testing and benchmark baseline.
         """
-        events = self._normalize_events(events)
-        if batch_size is None or batch_size <= 1:
+        batched = batch_size is not None and batch_size > 1
+        with span("spc.update.validate"):
+            events = self._normalize_events(events)
+            if batched:
+                # the per-event fallback below translates inside
+                # insert_edge / delete_edge; the chunked path translates
+                # here, once, before the stream is simulated against the
+                # (internal-id) edge set
+                events = [(op, self.order.to_internal(a),
+                           self.order.to_internal(b))
+                          for op, a, b in events]
+                self._validate_events(events)
+        if not batched:
             for op, a, b in events:
                 if op == "+":
                     self.insert_edge(a, b)
@@ -456,39 +477,41 @@ class DynamicSPC:
             return
 
         from repro.core.hybrid import OP_DELETE, OP_INSERT, hyb_spc_batch
-        # the per-event fallback above translates inside insert_edge /
-        # delete_edge; the chunked path translates here, once, before
-        # the stream is simulated against the (internal-id) edge set
-        events = [(op, self.order.to_internal(a), self.order.to_internal(b))
-                  for op, a, b in events]
-        self._validate_events(events)
         hyb = (self._updater.hyb_spc_batch if self._updater is not None
                else hyb_spc_batch)
         code = {"+": OP_INSERT, "-": OP_DELETE}
         for lo in range(0, len(events), batch_size):
             chunk = events[lo:lo + batch_size]
-            arr = np.zeros((batch_size, 3), dtype=np.int32)  # (0,0,0) pads
-            for i, (op, a, b) in enumerate(chunk):
-                arr[i] = (code[op], a, b)
-            n_ins = sum(1 for op, _, _ in chunk if op == "+")
-            cap_before = self.graph.cap_e
-            self.graph = self._pad_for_mesh(
-                G.ensure_capacity(self.graph, 2 * n_ins))
-            if self.graph.cap_e != cap_before:
-                self.stats.bump(edge_regrows=1)
-            g0, idx0 = self.graph, self.index  # pre-chunk snapshot
-            ev = jnp.asarray(arr)
-            while True:
-                g2, idx2 = hyb(self.graph, self.index, ev)
-                if int(idx2.overflow) == 0:
-                    self.graph, self.index = g2, idx2
-                    break
-                self.graph = g0
-                self.index = L.repad(idx0, self.index.l_cap * 2)
-                self.stats.bump(label_regrows=1)
+            with span("spc.update.apply"):
+                arr = np.zeros((batch_size, 3), dtype=np.int32)  # pads
+                for i, (op, a, b) in enumerate(chunk):
+                    arr[i] = (code[op], a, b)
+                n_ins = sum(1 for op, _, _ in chunk if op == "+")
+                cap_before = self.graph.cap_e
+                self.graph = self._pad_for_mesh(
+                    G.ensure_capacity(self.graph, 2 * n_ins))
+                if self.graph.cap_e != cap_before:
+                    self.stats.bump(edge_regrows=1)
+                g0, idx0 = self.graph, self.index  # pre-chunk snapshot
+                ev = jnp.asarray(arr)
+                repairs = rounds = 0
+                while True:
+                    (g2, work), idx2 = hyb(self.graph, self.index, ev)
+                    # the work counts come in the overflow check's fetch
+                    overflow, work = jax.device_get((idx2.overflow, work))
+                    repairs += int(work.hub_repairs)
+                    rounds += int(work.relax_rounds)
+                    if int(overflow) == 0:
+                        self.graph, self.index = g2, idx2
+                        break
+                    self.graph = g0
+                    self.index = L.repad(idx0, self.index.l_cap * 2)
+                    self.stats.bump(label_regrows=1)
             self.stats.bump(batches=1, batched_events=len(chunk),
                             inserts=n_ins,
-                            deletions=len(chunk) - n_ins)
+                            deletions=len(chunk) - n_ins,
+                            hub_repairs=repairs, relax_rounds=rounds,
+                            isolated_fast_path=int(work.isolated_fast_path))
             # one publish per committed chunk: replicas reading through
             # an attached store refresh at chunk granularity, never
             # seeing a mid-retry intermediate

@@ -52,6 +52,13 @@ Label-capacity overflow anywhere in the batch accumulates in the
 returned index's ``overflow`` counter; because every op is functional,
 the driver recovers by re-padding the *pre-batch* snapshot and replaying
 the whole chunk.
+
+The engine also counts its work inside the same dispatch
+(``bfs.RepairWork``: hub repairs, relaxation rounds, isolated-vertex
+fast paths), one int32 add per round, so the driver reads it in the
+same host fetch as the overflow counter.  It returns
+``((graph, work), index)``: the index comes last, as from the per-event
+engines, and the graph carries the replay's work beside it.
 """
 
 from __future__ import annotations
@@ -59,10 +66,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.bfs import RelaxFn
-from repro.core.decremental import dec_spc_step
+from repro.core.bfs import RelaxFn, RepairWork
+from repro.core.decremental import dec_spc_step_work
 from repro.core.graph import Graph
-from repro.core.incremental import _inc_spc
+from repro.core.incremental import _inc_spc_work
 from repro.core.labels import SPCIndex
 
 OP_INSERT = 1
@@ -70,35 +77,38 @@ OP_DELETE = 2
 
 
 def _hyb_spc_batch(g: Graph, idx: SPCIndex, events: jax.Array,
-                   relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
+                   relax_fn: RelaxFn | None = None
+                   ) -> tuple[tuple[Graph, RepairWork], SPCIndex]:
     def step(carry, ev):
-        g, idx = carry
+        g, idx, work = carry
         op, a, b = ev[0], ev[1], ev[2]
 
         def noop(args):
-            return args
+            g, idx = args
+            return g, idx, RepairWork.zero()
 
         def ins(args):
             g, idx = args
-            return _inc_spc(g, idx, a, b, relax_fn)
+            return _inc_spc_work(g, idx, a, b, relax_fn)
 
         def dele(args):
             g, idx = args
-            return dec_spc_step(g, idx, a, b, relax_fn)
+            return dec_spc_step_work(g, idx, a, b, relax_fn)
 
         known = (op == OP_INSERT) | (op == OP_DELETE)
         branch = jnp.where((a == b) | ~known, 0,
                            jnp.where(op == OP_INSERT, 1, 2))
-        g, idx = jax.lax.switch(branch, [noop, ins, dele], (g, idx))
-        return (g, idx), None
+        g, idx, done = jax.lax.switch(branch, [noop, ins, dele], (g, idx))
+        return (g, idx, work.plus(done)), None
 
-    (g, idx), _ = jax.lax.scan(step, (g, idx),
-                               events.astype(jnp.int32))
-    return g, idx
+    (g, idx, work), _ = jax.lax.scan(step, (g, idx, RepairWork.zero()),
+                                     events.astype(jnp.int32))
+    return (g, work), idx
 
 
 #: Apply a tagged ``(op, a, b)`` int32[B, 3] event stream in stream
 #: order inside ONE jitted ``lax.scan`` (see module docstring for the
-#: contract and the correctness argument).  ``relax_fn`` (static) swaps
-#: in the edge-sharded relaxation for distributed replay.
+#: contract and the correctness argument); returns ``((graph, work),
+#: index)``.  ``relax_fn`` (static) swaps in the edge-sharded relaxation
+#: for distributed replay.
 hyb_spc_batch = jax.jit(_hyb_spc_batch, static_argnames=("relax_fn",))

@@ -26,15 +26,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph as G
-from repro.core.bfs import RelaxFn, pruned_spc_bfs
+from repro.core.bfs import RelaxFn, RepairWork, pruned_spc_bfs
 from repro.core.graph import Graph
 from repro.core.labels import SPCIndex, bulk_upsert
 from repro.core.query import one_to_all
 
 
 def _inc_update(g: Graph, idx: SPCIndex, h, va, vb,
-                relax_fn: RelaxFn | None = None) -> SPCIndex:
-    """Algorithm 3, bulk form."""
+                relax_fn: RelaxFn | None = None) -> tuple[SPCIndex, jax.Array]:
+    """Algorithm 3, bulk form.  Returns the index and the repair BFS's
+    relaxation rounds."""
     # Seed from the (h, d, c) entry of L(va):
     eq_a = idx.hub[va] == h
     pos = jnp.argmax(eq_a)
@@ -52,12 +53,14 @@ def _inc_update(g: Graph, idx: SPCIndex, h, va, vb,
     c_i = idx.cnt[rows, at]
     # "if d = d_i then c <- c + c_i": accumulate equal-length counts.
     c_new = res.cnt + jnp.where(has & (res.dist == d_i), c_i, 0)
-    return bulk_upsert(idx, h, res.dist, c_new, res.keep)
+    return bulk_upsert(idx, h, res.dist, c_new, res.keep), res.levels
 
 
-def _inc_spc(g: Graph, idx: SPCIndex, a, b,
-             relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
-    """Algorithm 2 (traced body; see :func:`inc_spc`)."""
+def _inc_spc_work(g: Graph, idx: SPCIndex, a, b,
+                  relax_fn: RelaxFn | None = None
+                  ) -> tuple[Graph, SPCIndex, RepairWork]:
+    """Algorithm 2 (traced body; see :func:`inc_spc`), with the work it
+    did: one hub repair per ``_inc_update`` taken, and its rounds."""
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
     n = idx.n
@@ -72,20 +75,32 @@ def _inc_spc(g: Graph, idx: SPCIndex, a, b,
 
     g2 = G.insert_edge(g, a, b)
 
-    def slot(k, idx):
+    def slot(k, carry):
         h = aff[k]
         valid = first[k] & (h < n)
-        idx = jax.lax.cond(
-            valid & in_a[h] & (h <= b),
-            lambda i: _inc_update(g2, i, h, a, b, relax_fn),
-            lambda i: i, idx)
-        idx = jax.lax.cond(
-            valid & in_b[h] & (h <= a),
-            lambda i: _inc_update(g2, i, h, b, a, relax_fn),
-            lambda i: i, idx)
-        return idx
 
-    idx = jax.lax.fori_loop(0, aff.shape[0], slot, idx)
+        def repair(take, va, vb, carry):
+            idx, work = carry
+            idx, rounds = jax.lax.cond(
+                take,
+                lambda i: _inc_update(g2, i, h, va, vb, relax_fn),
+                lambda i: (i, jnp.int32(0)), idx)
+            return idx, work._replace(
+                hub_repairs=work.hub_repairs + take.astype(jnp.int32),
+                relax_rounds=work.relax_rounds + rounds)
+
+        carry = repair(valid & in_a[h] & (h <= b), a, b, carry)
+        return repair(valid & in_b[h] & (h <= a), b, a, carry)
+
+    idx, work = jax.lax.fori_loop(0, aff.shape[0], slot,
+                                  (idx, RepairWork.zero()))
+    return g2, idx, work
+
+
+def _inc_spc(g: Graph, idx: SPCIndex, a, b,
+             relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
+    """Algorithm 2 (traced body; see :func:`inc_spc`)."""
+    g2, idx, _ = _inc_spc_work(g, idx, a, b, relax_fn)
     return g2, idx
 
 

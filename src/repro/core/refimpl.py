@@ -282,7 +282,17 @@ def hp_spc(g: RefGraph) -> RefSPCIndex:
 # --------------------------------------------------------------------------
 # IncSPC (Algorithms 2 and 3).
 # --------------------------------------------------------------------------
-def _inc_update(g: RefGraph, idx: RefSPCIndex, h: int, va: int, vb: int) -> None:
+def _count(work, repairs: int, levels: Set[int]) -> None:
+    """Add a BFS to a ``work`` counter: ``repairs`` hub repairs, and one
+    relaxation round per level that expanded a vertex (what the
+    level-synchronous engines in ``repro.core`` run)."""
+    if work is not None:
+        work["hub_repairs"] += repairs
+        work["relax_rounds"] += len(levels)
+
+
+def _inc_update(g: RefGraph, idx: RefSPCIndex, h: int, va: int, vb: int,
+                work=None) -> None:
     """Algorithm 3: pruned BFS rooted at hub h, entering through (va, vb)."""
     lab = idx.get(va, h)
     if lab is None:  # defensive: caller guarantees membership
@@ -290,12 +300,14 @@ def _inc_update(g: RefGraph, idx: RefSPCIndex, h: int, va: int, vb: int) -> None
     _, d0, c0 = lab
     dist: Dict[int, int] = {vb: d0 + 1}
     cnt: Dict[int, int] = {vb: c0}
+    levels: Set[int] = set()
     q = collections.deque([vb])
     while q:
         v = q.popleft()
         d_l, _ = idx.query(h, v)
         if d_l < dist[v]:
             continue  # existing index already covers SP(h, v)
+        levels.add(dist[v])
         old = idx.get(v, h)
         if old is not None:
             _, d_i, c_i = old
@@ -313,12 +325,15 @@ def _inc_update(g: RefGraph, idx: RefSPCIndex, h: int, va: int, vb: int) -> None
                     q.append(w)
             elif dist[w] == dist[v] + 1:
                 cnt[w] += cnt[v]
+    _count(work, 1, levels)
 
 
-def inc_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int) -> None:
+def inc_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int,
+            work=None) -> None:
     """Algorithm 2: maintain the index after inserting edge (a, b).
 
-    Mutates ``g`` (inserting the edge) and ``idx`` in place.
+    Mutates ``g`` (inserting the edge) and ``idx`` in place.  ``work``
+    (a ``collections.Counter``) counts hub repairs and relaxation rounds.
     """
     if g.has_edge(a, b):
         raise ValueError(f"edge ({a},{b}) already present")
@@ -328,28 +343,31 @@ def inc_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int) -> None:
     hubs_b = set(idx.hubs(b))
     for h in aff:  # descending rank
         if h in hubs_a and h <= b:
-            _inc_update(g, idx, h, a, b)
+            _inc_update(g, idx, h, a, b, work)
         if h in hubs_b and h <= a:
-            _inc_update(g, idx, h, b, a)
+            _inc_update(g, idx, h, b, a, work)
 
 
 # --------------------------------------------------------------------------
 # DecSPC (Algorithms 4, 5 and 6).
 # --------------------------------------------------------------------------
 def _srr_search(
-    g: RefGraph, idx: RefSPCIndex, a: int, b: int, l_ab: Set[int]
+    g: RefGraph, idx: RefSPCIndex, a: int, b: int, l_ab: Set[int],
+    work=None
 ) -> Tuple[Set[int], Set[int]]:
     """Algorithm 5: compute SR_a and R_a (run before the edge is removed)."""
     sr: Set[int] = set()
     r: Set[int] = set()
     dist = {a: 0}
     cnt = {a: 1}
+    levels: Set[int] = set()
     q = collections.deque([a])
     while q:
         v = q.popleft()
         d, c = idx.query(v, b)
         if dist[v] + 1 != d:
             continue  # v has no shortest path through (a, b)
+        levels.add(dist[v])
         if v in l_ab or cnt[v] == c:
             sr.add(v)
         else:
@@ -361,23 +379,27 @@ def _srr_search(
                 q.append(w)
             elif dist[w] == dist[v] + 1:
                 cnt[w] += cnt[v]
+    _count(work, 0, levels)
     return sr, r
 
 
 def _dec_update(
-    g: RefGraph, idx: RefSPCIndex, h: int, sr: Set[int], r: Set[int], h_ab: bool
+    g: RefGraph, idx: RefSPCIndex, h: int, sr: Set[int], r: Set[int],
+    h_ab: bool, work=None
 ) -> None:
     """Algorithm 6: BFS from affected hub h over the post-deletion graph."""
     affected = sr | r
     dist = {h: 0}
     cnt = {h: 1}
     updated: Set[int] = set()
+    levels: Set[int] = set()
     q = collections.deque([h])
     while q:
         v = q.popleft()
         d_bar, _ = idx.prequery(h, v)
         if d_bar < dist[v]:
             continue
+        levels.add(dist[v])
         if v in affected:
             old = idx.get(v, h)
             if old is None:
@@ -395,17 +417,21 @@ def _dec_update(
                     q.append(w)
             elif dist[w] == dist[v] + 1:
                 cnt[w] += cnt[v]
+    _count(work, 1, levels)
     if h_ab:
         for u in affected:
             if u not in updated and idx.get(u, h) is not None:
                 idx.remove(u, h)
 
 
-def dec_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int) -> None:
+def dec_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int,
+            work=None) -> None:
     """Algorithm 4: maintain the index after deleting edge (a, b).
 
     Mutates ``g`` (removing the edge) and ``idx`` in place.  Applies the
     isolated-vertex optimization of Section 3.2.3 when possible.
+    ``work`` (a ``collections.Counter``) counts hub repairs, relaxation
+    rounds and isolated-vertex fast paths.
     """
     if not g.has_edge(a, b):
         raise ValueError(f"edge ({a},{b}) not present")
@@ -418,17 +444,19 @@ def dec_spc(g: RefGraph, idx: RefSPCIndex, a: int, b: int) -> None:
     if g.degree(hi) == 1:
         g.remove_edge(a, b)
         idx.labels[hi] = [(hi, 0, 1)]
+        if work is not None:
+            work["isolated_fast_path"] += 1
         return
 
     l_ab = set(idx.hubs(a)) & set(idx.hubs(b))
-    sr_a, r_a = _srr_search(g, idx, a, b, l_ab)
-    sr_b, r_b = _srr_search(g, idx, b, a, l_ab)
+    sr_a, r_a = _srr_search(g, idx, a, b, l_ab, work)
+    sr_b, r_b = _srr_search(g, idx, b, a, l_ab, work)
     g.remove_edge(a, b)
     for h in sorted(sr_a | sr_b):  # descending rank
         if h in sr_a:
-            _dec_update(g, idx, h, sr_b, r_b, h in l_ab)
+            _dec_update(g, idx, h, sr_b, r_b, h in l_ab, work)
         else:
-            _dec_update(g, idx, h, sr_a, r_a, h in l_ab)
+            _dec_update(g, idx, h, sr_a, r_a, h in l_ab, work)
 
 
 def srr_sets(

@@ -25,6 +25,7 @@ from repro.core.graph import INF
 from repro.core.labels import SPCIndex
 from repro.core.query import cached_count_bound, gather_rows, merge_rows_jit
 from repro.kernels.spc_query.kernel import spc_query_pallas
+from repro.spans import span
 
 #: Largest integer count the fp32 kernel is guaranteed to report exactly.
 EXACT_COUNT_MAX = 2 ** 24
@@ -94,8 +95,9 @@ def exact_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,
                       interpret: bool | None = None,
                       real_rows: int | None = None):
     """THE exactness-routed kernel call, shared by ``index_query_batch``
-    and the serving engine: gather once, check the per-row bound, run
-    the fp32 kernel on every row that is provably exact under it.
+    and the serving engine (through :func:`exact_query_split`): gather
+    once, check the per-row bound, run the fp32 kernel on every row that
+    is provably exact under it.
 
     ``real_rows`` (optional) marks the tail beyond it as padding whose
     answers the caller discards -- the serving engine bucket-pads with
@@ -109,37 +111,65 @@ def exact_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,
     by the per-row bound) or ``"pallas->merge"`` (no row provably exact;
     whole batch on the int64 fallback).
     """
-    rows, bounds = gather_rows_with_bounds(idx, s, t)
-    inexact = np.asarray(bounds) >= EXACT_COUNT_MAX  # one host sync
+    d, c, route, _ = exact_query_split(idx, s, t, block_b=block_b,
+                                       interpret=interpret,
+                                       real_rows=real_rows)
+    return d, c, route
+
+
+def exact_query_split(idx: SPCIndex, s, t, *, block_b: int = 128,
+                      interpret: bool | None = None,
+                      real_rows: int | None = None):
+    """:func:`exact_query_batch`, also returning how many real rows the
+    int64 merge answered: ``(dist, count, route, merged)``.  The host
+    steps between its dispatches are ``spc.read.*`` spans
+    (``repro.spans``)."""
+    with span("spc.read.gather"):
+        rows, bounds = gather_rows_with_bounds(idx, s, t)
+    with span("spc.read.bound_wait"):
+        inexact = np.asarray(bounds) >= EXACT_COUNT_MAX  # one host sync
     real = inexact if real_rows is None else inexact[:real_rows]
     if not real.any():
-        d, c = rows_query_pallas(*rows, block_b=block_b,
-                                 interpret=interpret)
-        return d, c.astype(jnp.int64), "pallas"
+        with span("spc.read.kernel"):
+            d, c = rows_query_pallas(*rows, block_b=block_b,
+                                     interpret=interpret)
+            c = c.astype(jnp.int64)
+        return d, c, "pallas", 0
     if real.all():
-        d, c = merge_rows_jit(*rows)
-        return d, c, "pallas->merge"
+        with span("spc.read.merge"):
+            d, c = merge_rows_jit(*rows)
+        return d, c, "pallas->merge", real.size
     # Mixed batch: partition on the per-row bound so exact rows keep the
     # kernel route.  Partitions are padded to power-of-two row counts so
     # the merge/kernel compile caches stay bounded regardless of how the
     # split lands; results scatter back host-side into stream order.
-    ex = np.nonzero(~inexact)[0]
-    iex = np.nonzero(inexact)[0]
-    rows_ex = _pad_rows(tuple(r[ex] for r in rows),
-                        _pow2_at_least(len(ex)), idx.n)
-    rows_in = _pad_rows(tuple(r[iex] for r in rows),
-                        _pow2_at_least(len(iex)), idx.n)
-    d_ex, c_ex = rows_query_pallas(*rows_ex, block_b=block_b,
-                                   interpret=interpret)
-    d_in, c_in = merge_rows_jit(*rows_in)
-    b = inexact.shape[0]
-    d = np.empty(b, np.int32)
-    c = np.empty(b, np.int64)
-    d[ex] = np.asarray(d_ex)[: len(ex)]
-    c[ex] = np.asarray(c_ex.astype(jnp.int64))[: len(ex)]
-    d[iex] = np.asarray(d_in)[: len(iex)]
-    c[iex] = np.asarray(c_in)[: len(iex)]
-    return jnp.asarray(d), jnp.asarray(c), "pallas+merge"
+    with span("spc.read.split"):
+        ex = np.nonzero(~inexact)[0]
+        iex = np.nonzero(inexact)[0]
+        rows_ex = _pad_rows(tuple(r[ex] for r in rows),
+                            _pow2_at_least(len(ex)), idx.n)
+        rows_in = _pad_rows(tuple(r[iex] for r in rows),
+                            _pow2_at_least(len(iex)), idx.n)
+    with span("spc.read.kernel"):
+        d_ex, c_ex = rows_query_pallas(*rows_ex, block_b=block_b,
+                                       interpret=interpret)
+    with span("spc.read.merge"):
+        d_in, c_in = merge_rows_jit(*rows_in)
+    with span("spc.read.fetch_wait"):
+        d_ex = np.asarray(d_ex)[: len(ex)]
+        c_ex = np.asarray(c_ex.astype(jnp.int64))[: len(ex)]
+        d_in = np.asarray(d_in)[: len(iex)]
+        c_in = np.asarray(c_in)[: len(iex)]
+    with span("spc.read.scatter"):
+        b = inexact.shape[0]
+        d = np.empty(b, np.int32)
+        c = np.empty(b, np.int64)
+        d[ex] = d_ex
+        c[ex] = c_ex
+        d[iex] = d_in
+        c[iex] = c_in
+        d, c = jnp.asarray(d), jnp.asarray(c)
+    return d, c, "pallas+merge", int(real.sum())
 
 
 def index_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,

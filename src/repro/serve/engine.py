@@ -60,8 +60,9 @@ import numpy as np
 from repro.analysis.shadow import assert_no_locks_held, make_lock
 from repro.core import query as Q
 from repro.core.labels import SPCIndex
-from repro.kernels.spc_query.ops import exact_query_batch
+from repro.kernels.spc_query.ops import exact_query_split
 from repro.serve.routing import RoutePolicy
+from repro.spans import span
 
 #: Static batch shapes the jit cache may hold.  Batches larger than the
 #: last bucket are padded to the next multiple of it.
@@ -150,6 +151,7 @@ class ServeStatsView:
     batches: int
     routes: Mapping[str, int]
     versions: Mapping[int, int]
+    route_pairs: Mapping[str, int]
 
 
 @dataclasses.dataclass
@@ -159,6 +161,10 @@ class ServeStats:
     routes: Dict[str, int] = dataclasses.field(default_factory=dict)
     #: queries answered per pinned snapshot version (``serve_from`` only)
     versions: Dict[int, int] = dataclasses.field(default_factory=dict)
+    #: real pairs per evaluation path that answered them (``pallas``,
+    #: ``merge``, ``table``): a ``pallas+merge`` batch adds its exact
+    #: rows to ``pallas`` and its inexact rows to ``merge``
+    route_pairs: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
         # one engine may front many replica threads (the publish
@@ -166,11 +172,19 @@ class ServeStats:
         # to interleaved read-modify-writes
         self._lock = make_lock("serve_stats.lock")
 
-    def count(self, route: str, queries: int) -> None:
+    def count(self, route: str, queries: int,
+              paths: Mapping[str, int] | None = None) -> None:
+        """One batch of ``queries`` real pairs on ``route``; ``paths``
+        splits them over the evaluation paths that answered them (by
+        default all on the path the route names)."""
         with self._lock:
             self.queries += queries
             self.batches += 1
             self.routes[route] = self.routes.get(route, 0) + 1
+            for path, pairs in (paths or {route: queries}).items():
+                if pairs:
+                    self.route_pairs[path] = (
+                        self.route_pairs.get(path, 0) + pairs)
 
     def count_version(self, version: int, queries: int) -> None:
         with self._lock:
@@ -185,7 +199,8 @@ class ServeStats:
             return ServeStatsView(
                 queries=self.queries, batches=self.batches,
                 routes=types.MappingProxyType(dict(self.routes)),
-                versions=types.MappingProxyType(dict(self.versions)))
+                versions=types.MappingProxyType(dict(self.versions)),
+                route_pairs=types.MappingProxyType(dict(self.route_pairs)))
 
 
 class QueryEngine:
@@ -263,39 +278,44 @@ class QueryEngine:
         if route not in self.ROUTES:
             raise ValueError(f"unknown route {route!r}; want one of "
                              f"{self.ROUTES}")
-        self._validate_ids(idx.n, s, t)
-        assert_no_locks_held("QueryEngine.query_batch")
-        b = s.shape[0]
-        if b == 0:
-            # empty batch: answer host-side -- padding B=0 up to the
-            # smallest bucket would dispatch 8 dump rows and record a
-            # phantom batch of 0 queries in the stats
-            return _EMPTY_DIST, _EMPTY_CNT
-        s = s.astype(np.int32)
-        t = t.astype(np.int32)
-        pad = bucket_size(b, self.buckets) - b
-        if pad:  # dump-row pairs: evaluate to (INF, 0), sliced off below
-            s = np.pad(s, (0, pad), constant_values=idx.n)
-            t = np.pad(t, (0, pad), constant_values=idx.n)
+        with span("spc.read.prep"):
+            self._validate_ids(idx.n, s, t)
+            assert_no_locks_held("QueryEngine.query_batch")
+            b = s.shape[0]
+            if b == 0:
+                # empty batch: answer host-side -- padding B=0 up to the
+                # smallest bucket would dispatch 8 dump rows and record
+                # a phantom batch of 0 queries in the stats
+                return _EMPTY_DIST, _EMPTY_CNT
+            s = s.astype(np.int32)
+            t = t.astype(np.int32)
+            pad = bucket_size(b, self.buckets) - b
+            if pad:  # dump-row pairs: evaluate to (INF, 0), sliced off
+                s = np.pad(s, (0, pad), constant_values=idx.n)
+                t = np.pad(t, (0, pad), constant_values=idx.n)
         want_pallas = route == "pallas" or (route == "auto"
                                             and self._kernel_backend())
         if route == "table":
             chosen = "table"
-            d, c = _serve_table(idx, s, t)
+            with span("spc.read.merge"):  # the merge route's span
+                d, c = _serve_table(idx, s, t)
+            paths = {"table": b}
         elif not want_pallas:
             chosen = "merge"
-            d, c = _serve_merge(idx, s, t)
+            with span("spc.read.merge"):
+                d, c = _serve_merge(idx, s, t)
+            paths = {"merge": b}
         else:
             # The shared exactness-routed kernel call: gathers once,
             # syncs the per-row bound vector, and partitions the batch
             # so only rows that could exceed 2^24 on the fp32 path pay
             # the int64 merge ("pallas" / "pallas+merge" /
             # "pallas->merge").
-            d, c, chosen = exact_query_batch(idx, s, t,
-                                             block_b=self.block_b,
-                                             interpret=self.interpret,
-                                             real_rows=b)
-        self.stats.count(chosen, b)
+            d, c, chosen, merged = exact_query_split(
+                idx, s, t, block_b=self.block_b, interpret=self.interpret,
+                real_rows=b)
+            paths = {"pallas": b - merged, "merge": merged}
+        self.stats.count(chosen, b, paths)
         return d[:b], c[:b]
 
     def query_pair(self, idx: SPCIndex, s: int, t: int) -> Tuple[int, int]:
@@ -354,7 +374,7 @@ class QueryEngine:
             d, c = fn(idx, jnp.asarray(s), jnp.asarray(t))
             # route recorded like the single-device paths record theirs,
             # so mixed single-/multi-device stats stay comparable
-            self.stats.count(f"sharded[{axes}]:merge", b)
+            self.stats.count(f"sharded[{axes}]:merge", b, {"merge": b})
             return d[:b], c[:b]
 
         return serve

@@ -103,6 +103,7 @@ from repro.serve.publish import SnapshotStore
 from repro.serve.replica import ReplicaGroup
 from repro.serve.routing import RoutePolicy
 from repro.serve.transport import make_transport
+from repro.spans import span
 
 _log = logging.getLogger(__name__)
 
@@ -721,25 +722,32 @@ class SPCService:
                  else self._spc.order)
 
         def serve(s, t):
+            with span("spc.read"):
+                return read(s, t)
+
+        def read(s, t):
             self._check_failure()
             # snapshots live in rank space when the driver was built
             # with vertex_order != "id": translate caller ids once per
             # batch (identity order: exact pass-through, zero change)
             if not order.identity:
-                s = order.to_internal(s)
-                t = order.to_internal(t)
+                with span("spc.read.prep"):
+                    s = order.to_internal(s)
+                    t = order.to_internal(t)
             if at_version is not None:
                 # NB: version 0 (the seed snapshot) is a real published
                 # version -- None-check, don't falsy-check
-                self._wait(
-                    lambda: (-1 if self._store.version is None
-                             else self._store.version) >= at_version,
-                    timeout, what=f"publish of version {at_version}")
+                with span("spc.read.ryw_wait"):
+                    self._wait(
+                        lambda: (-1 if self._store.version is None
+                                 else self._store.version) >= at_version,
+                        timeout, what=f"publish of version {at_version}")
             elif consistency == "read_your_writes":
                 # the SESSION's last ticket -- waiting on the globally
                 # last accepted one would block on (and be incorrectly
                 # "covered" by) other callers' writes
-                self.wait_for_ticket(sess.last_ticket, timeout)
+                with span("spc.read.ryw_wait"):
+                    self.wait_for_ticket(sess.last_ticket, timeout)
             snap = self._store.current()   # pinned for the whole batch
             if sharded is not None:
                 # the POLICY's route, not the engine's default -- a

@@ -115,8 +115,8 @@ SCRIPT = textwrap.dedent(
         [[OP_INSERT, absent[0], absent[1]], [0, 0, 0],
          [OP_DELETE, present[0][0], present[0][1]]], np.int32))
     g0 = pad_graph_for(G.ensure_capacity(rep.graph, 4), 4)
-    g_r, i_r = hyb_spc_batch(g0, rep.index, ev)
-    g_s, i_s = sh._updater.hyb_spc_batch(g0, rep.index, ev)
+    (g_r, _), i_r = hyb_spc_batch(g0, rep.index, ev)
+    (g_s, _), i_s = sh._updater.hyb_spc_batch(g0, rep.index, ev)
     assert int(i_s.overflow) == int(i_r.overflow) == 0
     assert to_ref(i_s).labels == to_ref(i_r).labels
     np.testing.assert_array_equal(np.asarray(g_s.src), np.asarray(g_r.src))

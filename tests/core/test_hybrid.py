@@ -65,7 +65,7 @@ def test_prefix_differential_vs_per_event_and_oracle():
     for k in range(B + 1):
         ev = arr.copy()
         ev[k:] = 0  # rows >= k become (0, 0, 0) self-loop padding
-        g2, idx2 = hyb_spc_batch(g0, idx0, jnp.asarray(ev))
+        (g2, _), idx2 = hyb_spc_batch(g0, idx0, jnp.asarray(ev))
         assert int(idx2.overflow) == 0
         assert to_ref(idx2).labels == to_ref(seq.index).labels, k
         assert sorted(G.to_ref(g2).edge_list()) == sorted(rg.edge_list()), k
@@ -93,8 +93,8 @@ def test_padding_rows_are_noops():
         plain[3:],
         np.zeros((2, 3), np.int32),
     ])
-    g_a, idx_a = hyb_spc_batch(g0, svc.index, jnp.asarray(plain))
-    g_b, idx_b = hyb_spc_batch(g0, svc.index, jnp.asarray(padded))
+    (g_a, _), idx_a = hyb_spc_batch(g0, svc.index, jnp.asarray(plain))
+    (g_b, _), idx_b = hyb_spc_batch(g0, svc.index, jnp.asarray(padded))
     assert int(idx_b.overflow) == int(idx_a.overflow) == 0
     assert to_ref(idx_a).labels == to_ref(idx_b).labels
     np.testing.assert_array_equal(np.asarray(g_a.src), np.asarray(g_b.src))

@@ -301,7 +301,7 @@ def test_stats_dataclass_shape():
     st.count_version(4, 3)
     assert dataclasses.asdict(st) == {
         "queries": 8, "batches": 2, "routes": {"merge": 2},
-        "versions": {4: 8}}
+        "versions": {4: 8}, "route_pairs": {"merge": 8}}
 
 
 def test_coalesce_pairs_and_split_rows_round_trip():
