@@ -7,9 +7,9 @@ cheap per-row bound (``sum(cnt_s) * sum(cnt_t)``, which dominates the
 true count and every fp32 partial sum -- see
 ``repro.core.query.count_upper_bound_rows``) and answers every row that
 might exceed it on the int64 sorted-merge path instead of returning
-silently wrong counts.  The bound is enforced *per row*: a mixed batch
-is partitioned host-side so the provably-exact rows still take the
-kernel and only the unprovable rows pay the merge (route
+silently wrong counts.  The bound is enforced *per row*: in a mixed
+batch the kernel answers every row and one jitted dispatch re-answers
+the unprovable rows in int64 and patches them in (route
 ``"pallas+merge"``); a batch where no row is provably exact degrades to
 the all-merge fallback (route ``"pallas->merge"``).  ``exact=False``
 restores the raw fp32 kernel contract for benchmarking.
@@ -21,9 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import INF
 from repro.core.labels import SPCIndex
-from repro.core.query import cached_count_bound, gather_rows, merge_rows_jit
+from repro.core.query import (cached_count_bound, gather_rows, merge_rows,
+                              merge_rows_jit)
 from repro.kernels.spc_query.kernel import spc_query_pallas
 from repro.spans import span
 
@@ -69,19 +69,16 @@ def rows_query_pallas(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, *,
         block_b=block_b, interpret=interpret)
 
 
-def _pad_rows(rows, to: int, n: int):
-    """Pad gathered rows out to ``to`` with all-sentinel label rows.
-
-    Pad pairs intersect nowhere (s hubs = n, t hubs = n + 1), so both
-    evaluation paths answer (INF, 0) for them; callers slice them off.
-    """
-    k = rows[0].shape[0]
-    if k == to:
-        return rows
-    vals = (n, int(INF), 0, n + 1, int(INF), 0)
-    return tuple(
-        jnp.pad(r, ((0, to - k), (0, 0)), constant_values=v)
-        for r, v in zip(rows, vals))
+@jax.jit
+def merge_patch(rows, iex, d, c):
+    """Overwrite the kernel's answers (``d``, fp32 ``c``) at rows ``iex``
+    with their int64 merge answers: one program gathers those rows, merges
+    them and scatters the result.  ``iex`` may repeat an index (its merge
+    writes the same value again), so the host pads it to a power of two
+    and the program count stays one per padded length.
+    Returns (dist int32[B], count int64[B])."""
+    d_in, c_in = merge_rows(*(r[iex] for r in rows))
+    return d.at[iex].set(d_in), c.astype(jnp.int64).at[iex].set(c_in)
 
 
 def _pow2_at_least(k: int, floor: int = 8) -> int:
@@ -96,20 +93,20 @@ def exact_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,
                       real_rows: int | None = None):
     """THE exactness-routed kernel call, shared by ``index_query_batch``
     and the serving engine (through :func:`exact_query_split`): gather
-    once, check the per-row bound, run the fp32 kernel on every row that
-    is provably exact under it.
+    once, check the per-row bound, keep the fp32 kernel's answer for
+    every row that is provably exact under it.
 
     ``real_rows`` (optional) marks the tail beyond it as padding whose
     answers the caller discards -- the serving engine bucket-pads with
     dump-row pairs (bound 0, trivially exact), and those must not drag
-    an all-inexact real batch into a pointless split.  The route is
-    decided on the real rows only; padding rides with whichever
-    partition avoids an extra dispatch.
+    an all-inexact real batch onto the kernel.  The route is decided on
+    the real rows only; padding is never merged on a mixed batch.
 
     Returns (dist int32[B], count int64[B], route) with route one of
-    ``"pallas"`` (all rows exact), ``"pallas+merge"`` (batch partitioned
-    by the per-row bound) or ``"pallas->merge"`` (no row provably exact;
-    whole batch on the int64 fallback).
+    ``"pallas"`` (all rows exact), ``"pallas+merge"`` (kernel on the
+    whole batch, the rows over the bound patched from the int64 merge)
+    or ``"pallas->merge"`` (no row provably exact; whole batch on the
+    int64 fallback).
     """
     d, c, route, _ = exact_query_split(idx, s, t, block_b=block_b,
                                        interpret=interpret,
@@ -139,36 +136,19 @@ def exact_query_split(idx: SPCIndex, s, t, *, block_b: int = 128,
         with span("spc.read.merge"):
             d, c = merge_rows_jit(*rows)
         return d, c, "pallas->merge", real.size
-    # Mixed batch: partition on the per-row bound so exact rows keep the
-    # kernel route.  Partitions are padded to power-of-two row counts so
-    # the merge/kernel compile caches stay bounded regardless of how the
-    # split lands; results scatter back host-side into stream order.
-    with span("spc.read.split"):
-        ex = np.nonzero(~inexact)[0]
-        iex = np.nonzero(inexact)[0]
-        rows_ex = _pad_rows(tuple(r[ex] for r in rows),
-                            _pow2_at_least(len(ex)), idx.n)
-        rows_in = _pad_rows(tuple(r[iex] for r in rows),
-                            _pow2_at_least(len(iex)), idx.n)
+    # Mixed batch: the kernel answers the whole batch (each row on its
+    # own, so the exact rows' answers are final), then one dispatch
+    # re-answers the inexact rows in int64 and patches them in.  The
+    # index vector is padded to a power of two with a repeat of a real
+    # inexact row, so the merge compiles once per padded length.
     with span("spc.read.kernel"):
-        d_ex, c_ex = rows_query_pallas(*rows_ex, block_b=block_b,
-                                       interpret=interpret)
+        d, c = rows_query_pallas(*rows, block_b=block_b,
+                                 interpret=interpret)
     with span("spc.read.merge"):
-        d_in, c_in = merge_rows_jit(*rows_in)
-    with span("spc.read.fetch_wait"):
-        d_ex = np.asarray(d_ex)[: len(ex)]
-        c_ex = np.asarray(c_ex.astype(jnp.int64))[: len(ex)]
-        d_in = np.asarray(d_in)[: len(iex)]
-        c_in = np.asarray(c_in)[: len(iex)]
-    with span("spc.read.scatter"):
-        b = inexact.shape[0]
-        d = np.empty(b, np.int32)
-        c = np.empty(b, np.int64)
-        d[ex] = d_ex
-        c[ex] = c_ex
-        d[iex] = d_in
-        c[iex] = c_in
-        d, c = jnp.asarray(d), jnp.asarray(c)
+        iex = np.nonzero(inexact)[0].astype(np.int32)
+        iex = np.pad(iex, (0, _pow2_at_least(iex.size) - iex.size),
+                     mode="edge")
+        d, c = merge_patch(rows, iex, d, c)
     return d, c, "pallas+merge", int(real.sum())
 
 

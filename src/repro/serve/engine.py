@@ -24,9 +24,9 @@ that the *engineered* path instead of three diverging ones:
              O(L^2) arithmetic of the kernel, in jnp)
    ========  ==========================================  ===========
 
-   The exactness bound is enforced per row: a mixed batch is
-   partitioned host-side so provably-exact rows still take the kernel
-   while the rest merge in int64, recorded as ``pallas+merge``; a batch
+   The exactness bound is enforced per row: on a mixed batch the
+   kernel answers every row and the rows over the bound are re-answered
+   in int64 and patched in, recorded as ``pallas+merge``; a batch
    with no provably-exact row degrades whole to the merge path,
    recorded as ``pallas->merge`` -- the silent-overflow bug this engine
    exists to close.  ``interpret`` defaults from the backend at dispatch
@@ -307,10 +307,9 @@ class QueryEngine:
             paths = {"merge": b}
         else:
             # The shared exactness-routed kernel call: gathers once,
-            # syncs the per-row bound vector, and partitions the batch
-            # so only rows that could exceed 2^24 on the fp32 path pay
-            # the int64 merge ("pallas" / "pallas+merge" /
-            # "pallas->merge").
+            # syncs the per-row bound vector, and re-answers in int64
+            # only the rows that could exceed 2^24 on the fp32 path
+            # ("pallas" / "pallas+merge" / "pallas->merge").
             d, c, chosen, merged = exact_query_split(
                 idx, s, t, block_b=self.block_b, interpret=self.interpret,
                 real_rows=b)

@@ -14,7 +14,7 @@ auto      backend-dependent default (merge on CPU/GPU, kernel on TPU
 merge     jitted int64 sorted-merge -- exact everywhere
 table     explicit O(L^2) jnp table (eager-parity debugging)
 pallas    the Pallas kernel route, with its two knobs (``block_b``,
-          ``interpret``); still exactness-partitioned per row
+          ``interpret``); still exactness-checked per row
 sharded   multi-device replicas: index replicated, batch split over
           ``batch_axes`` of a serving mesh (merge core only)
 ========  ==============================================================

@@ -16,9 +16,10 @@ Rules for the spans of this package:
   every entry).
 
 The spans: ``spc.read`` (one reader batch) with ``spc.read.ryw_wait``,
-``.prep``, ``.gather``, ``.bound_wait``, ``.split``, ``.kernel``,
-``.merge``, ``.fetch_wait`` and ``.scatter`` inside it
-(``serve/service.py``, ``serve/engine.py``, ``kernels/spc_query/ops.py``);
+``.prep``, ``.gather``, ``.bound_wait``, ``.kernel`` and ``.merge``
+inside it (``serve/service.py``, ``serve/engine.py``,
+``kernels/spc_query/ops.py``); on a mixed-exactness batch ``.merge``
+holds the one merge-and-patch dispatch of the rows over the count bound;
 ``spc.update.validate``, ``spc.update.apply`` (one event chunk) and
 ``spc.update.publish`` (``core/dynamic.py``).
 """

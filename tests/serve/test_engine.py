@@ -151,6 +151,98 @@ def test_mixed_exactness_batch_splits_routes(dynamic_service):
     assert eng.stats.routes == {"pallas+merge": 1, "pallas->merge": 1}
 
 
+#: Hub-0 counts of the heavy vertices 1, 3, 5: every pair touching one has
+#: a count bound >= 2^24, and their products need int64.
+_HEAVY = {1: 2 ** 24 + 1, 3: 2 ** 24 + 3, 5: 3 * 2 ** 24 + 7}
+
+
+def _heavy_index(l_cap: int = 4):
+    """Eight vertices, all reaching hub 0; the heavy ones push every pair
+    that touches them over the fp32 bound."""
+    ref = R.RefSPCIndex(8)
+    ref.labels[0] = [(0, 0, 1)]
+    for v in range(1, 8):
+        ref.labels[v] = [(0, 1 + v % 3, _HEAVY.get(v, 1 + v % 2)),
+                         (v, 0, 1)]
+    return ref, from_ref(ref, l_cap=l_cap)
+
+
+def _mixed_pairs(k: int, b: int, seed: int):
+    """``b`` pairs of which exactly ``k`` touch a heavy vertex (never a
+    heavy self-pair, whose count is 1), shuffled."""
+    rng = np.random.default_rng(seed)
+    light = [v for v in range(8) if v not in _HEAVY]
+    heavy = list(_HEAVY)
+    pairs = [(h, int(rng.choice([v for v in range(8) if v != h])))
+             for h in rng.choice(heavy, size=k).tolist()]
+    pairs += [(int(rng.choice(light)), int(rng.choice(light)))
+              for _ in range(b - k)]
+    pairs = [p[::-1] if rng.random() < 0.5 else p for p in pairs]
+    rng.shuffle(pairs)
+    return [p[0] for p in pairs], [p[1] for p in pairs]
+
+
+@pytest.mark.parametrize("k,b", [(1, 64), (3, 64), (8, 64), (9, 64),
+                                 (63, 64), (5, 50)],
+                         ids=["k1", "k3", "k8", "k9", "k_b-1", "bucket_pad"])
+def test_mixed_exactness_batch_patches_inexact_rows(k, b, monkeypatch):
+    """A mixed batch runs the kernel on every row and patches the k rows
+    over the bound with their int64 merge answers in one dispatch: every
+    row equals the merge route and the reference, the batch counts as one
+    ``pallas+merge`` with k merged pairs, and bucket padding (b = 50 in a
+    64 bucket) is never patched and never returned."""
+    import repro.kernels.spc_query.ops as ops
+
+    ref, idx = _heavy_index()
+    s, t = _mixed_pairs(k, b, seed=k * 100 + b)
+    patched = []
+    real_patch = ops.merge_patch
+
+    def spy(rows, iex, d, c):
+        patched.append(np.asarray(iex))
+        return real_patch(rows, iex, d, c)
+
+    monkeypatch.setattr(ops, "merge_patch", spy)
+    eng = QueryEngine()
+    d, c = eng.query_batch(idx, s, t, route="pallas")
+    assert d.shape == c.shape == (b,) and c.dtype == jnp.int64
+    assert eng.stats.routes == {"pallas+merge": 1}
+    assert eng.stats.route_pairs == {"pallas": b - k, "merge": k}
+    (iex,) = patched
+    assert len(set(iex.tolist())) == k and iex.max() < b  # no pad row
+    dm, cm = QueryEngine().query_batch(idx, s, t, route="merge")
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(dm))
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cm))
+    want = [ref.query(a, z) for a, z in zip(s, t)]
+    assert [(int(x), int(y)) for x, y in zip(d, c)] == want
+    # every patched count is one fp32 cannot hold, so the patch is what
+    # makes it exact
+    assert all(int(np.float32(want[i][1])) != want[i][1] for i in iex)
+
+
+def test_mixed_exactness_compiles_per_pow2_not_per_k():
+    """The merge-and-patch program depends on k only through pow2(k):
+    k = 3, 5, 7 in one bucket add one merge program and, after the first
+    batch, no kernel program; k = 9 adds exactly one more merge program.
+    A unique l_cap keeps other tests' programs out of the counts."""
+    from repro.kernels.spc_query.kernel import _spc_query_jit
+    from repro.kernels.spc_query.ops import merge_patch
+
+    _, idx = _heavy_index(l_cap=7)
+    eng = QueryEngine()
+    merges0 = merge_patch._cache_size()
+    eng.query_batch(idx, *_mixed_pairs(3, 64, seed=3), route="pallas")
+    kernels = _spc_query_jit._cache_size()
+    for k in (5, 7):
+        eng.query_batch(idx, *_mixed_pairs(k, 64, seed=k), route="pallas")
+    assert merge_patch._cache_size() == merges0 + 1
+    assert _spc_query_jit._cache_size() == kernels
+    eng.query_batch(idx, *_mixed_pairs(9, 64, seed=9), route="pallas")
+    assert merge_patch._cache_size() == merges0 + 2
+    assert _spc_query_jit._cache_size() == kernels
+    assert eng.stats.routes == {"pallas+merge": 4}
+
+
 def test_pallas_route_works_on_cpu_backend(dynamic_service, monkeypatch):
     """Regression: ``route="pallas"`` with ``interpret=None`` must not
     dispatch the compiled Mosaic lowering off-TPU.  The env knob that
