@@ -2,7 +2,8 @@
 
 Every number compared counts answers of the timed path that differ from
 the plain reference (``reference.py``), or answers that never came; each
-is exact, so each limit is 0.
+is exact, so each limit is 0.  A count is held to the count contract: it
+is right if and only if it equals ``min(true count, 2^63 - 1)``.
 
 - ``writer``: every event's read-back against a BFS of the reference's
   graph right after that event, and the index as the update engine left
@@ -17,13 +18,18 @@ is exact, so each limit is 0.
 program's place on the same pairs and returns its readings beside the
 program's: the control (bfloat16 counts) and, beside it, float32 counts,
 the kernel's own precision without its int64 fallback.
+
+Beside the compared numbers, ``answers_saturated`` counts the checked
+pairs whose reference count is ``INT64_MAX``: how far a cell reaches the
+contract's edge.  It has no limit.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.chip.reference import Adjacency, EdgeSet, bfs_counts
+from benchmarks.chip.reference import (INT64_MAX, Adjacency, EdgeSet,
+                                       bfs_counts)
 
 
 def read_index(svc, rng, sources: int) -> dict:
@@ -73,8 +79,9 @@ CONTROL_COUNTS = ("bfloat16", "float32")
 def check_pairs(s, t, dist, cnt, adj: Adjacency, sample: np.ndarray,
                 control: bool = False) -> dict:
     """Mismatches among the pairs whose source or target is in
-    ``sample``; with ``control`` also those of each precision in
-    ``CONTROL_COUNTS``, and the largest exact count among the pairs."""
+    ``sample``, and how many of those pairs the reference counts at
+    ``INT64_MAX``; with ``control`` also the mismatches of each precision
+    in ``CONTROL_COUNTS``, and the largest count among the pairs."""
     pos = np.full(adj.n, -1)
     pos[sample] = np.arange(sample.size)
     ps, pt = pos[s], pos[t]
@@ -96,6 +103,7 @@ def check_pairs(s, t, dist, cnt, adj: Adjacency, sample: np.ndarray,
     got_d = np.concatenate([dist[use_s], dist[use_t]])
     got_c = np.concatenate([cnt[use_s], cnt[use_t]])
     out["wrong"] = int(np.sum((got_d != want_d) | (got_c != want_c)))
+    out["saturated"] = int(np.sum(want_c == INT64_MAX))
     for p, c in ctl_c.items():
         ctl = np.concatenate([c[ps[use_s], t[use_s]],
                               c[pt[use_t], s[use_t]]])
@@ -115,7 +123,7 @@ def compare(run, n: int, edges, traffic: dict, final, rng,
             control: bool = False) -> dict:
     """The compared numbers, each with its limit, and the verdict."""
     checks, attempted, failed = {}, 0, 0
-    controls = {}
+    answers, controls = {}, {}
     if run.writer is not None:
         w = check_writer(run.writer, n, edges, final)
         checks["readback_wrong"] = w["readback_wrong"]
@@ -148,19 +156,18 @@ def compare(run, n: int, edges, traffic: dict, final, rng,
                                  int(traffic["check"]["sample_sources"]), rng)
         p = check_pairs(s, t, d, c, Adjacency(n, edges), sample, control)
         checks["answers_wrong"] = p["wrong"]
-        checks["answers_checked"] = p["checked"]
+        answers = {"answers_checked": p["checked"],
+                   "answers_saturated": p["saturated"]}
         if control:
             controls = {f"answers_wrong.{q}": p[f"wrong.{q}"]
                         for q in CONTROL_COUNTS}
             controls["max_count"] = p["max_count"]
-    limits = {k: 0 for k in checks if k != "answers_checked"}
-    correct = all(checks[k] <= limits[k] for k in limits)
-    if "answers_checked" in checks and checks["answers_checked"] == 0:
+    correct = all(v <= 0 for v in checks.values())
+    if answers.get("answers_checked") == 0:
         correct = False
-    table = {k: {"value": checks[k], "limit": limits[k]} for k in limits}
+    table = {k: {"value": v, "limit": 0} for k, v in checks.items()}
     out = {"correct": bool(correct), "attempted": int(attempted),
-           "failed": int(failed), "checks": table,
-           "answers_checked": checks.get("answers_checked")}
+           "failed": int(failed), "checks": table, "answers": answers}
     if control:
         out["control"] = controls
     return out

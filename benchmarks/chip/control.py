@@ -52,7 +52,7 @@ def main() -> None:
                                control=True)
         line = {"seed": seed, "correct": res["correct"],
                 "program": readings(res), "control": res["control"],
-                "answers_checked": res.get("answers_checked")}
+                "answers_checked": res["reference"].get("answers_checked")}
         if "writer" in cell.traffic:
             pinned = copy.deepcopy(cell)
             pinned.traffic["writer"]["consistency"] = "pinned"

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import importlib.util
 import json
 import pathlib
@@ -240,7 +241,8 @@ def build_service(config: dict, n: int, edges):
 
 def counters(svc, door) -> dict:
     st = svc.stats()
-    return {"routes": dict(route_counts(svc)),
+    return {"routes": dict(served(svc, "routes")),
+            "route_pairs": dict(served(svc, "route_pairs")),
             "queries": st["queries"],
             "frontdoor": door.stats() if door is not None else None,
             "update": dataclasses.asdict(st["update"]),
@@ -273,12 +275,14 @@ def warm_reads(svc, pairs, sizes, rng) -> None:
         np.asarray(reader(s, t)[1])
 
 
-def route_counts(svc) -> collections.Counter:
-    """Batches served per route, over the service's serving engines."""
-    routes = collections.Counter()
+def served(svc, counter: str) -> collections.Counter:
+    """One ``ServeStats`` counter summed over the service's serving
+    engines: ``routes``, batches per route, or ``route_pairs``, real
+    pairs per evaluation path."""
+    out = collections.Counter()
     for view in svc.stats()["serve"]:
-        routes.update(view.routes)
-    return routes
+        out.update(getattr(view, counter))
+    return out
 
 
 def warm_split(svc, pairs, buckets, kmax: int, rng,
@@ -291,15 +295,18 @@ def warm_split(svc, pairs, buckets, kmax: int, rng,
     public route counter: batches of 8 pairs drawn as the traffic draws
     them are served through the reader, each pair of a batch that did
     not take the ``pallas`` route alone again.  Where no such pair turns
-    up in ``probes`` batches, nothing is served."""
+    up in ``probes`` batches, no split batch is served.  A batch of
+    pairs that all take the ``pallas`` route is served too, in each
+    bucket, since the traffic's draws are nearly all split at the
+    largest."""
     import numpy as np
 
     reader = svc.reader()
 
     def route(s, t) -> str:
-        before = route_counts(svc)
+        before = served(svc, "routes")
         np.asarray(reader(s, t)[1])
-        (name,) = (route_counts(svc) - before).keys()
+        (name,) = (served(svc, "routes") - before).keys()
         return name
 
     plain = max(buckets)
@@ -319,7 +326,12 @@ def warm_split(svc, pairs, buckets, kmax: int, rng,
         if heavy is not None and len(light_s) >= plain:
             break
     out = {"found": heavy is not None, "probed_batches": tried, "ks": {}}
-    if heavy is None or len(light_s) < plain:
+    if len(light_s) < plain:
+        return out
+    for bucket in sorted(buckets):
+        np.asarray(reader(np.asarray(light_s[:bucket]),
+                          np.asarray(light_t[:bucket]))[1])
+    if heavy is None:
         return out
     for bucket in sorted(buckets):
         top = min(kmax, bucket - 1)
@@ -538,6 +550,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
+        gc_spans = tr.GcSpans()
+        gc.callbacks.append(gc_spans)
         jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
     t_win = time.monotonic()
     t_end = t_win + seconds
@@ -560,6 +574,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     after = counters(svc, door)
     if trace:
         jax.profiler.stop_trace()
+        gc.callbacks.remove(gc_spans)
     in_window = compile_log.between(t_win, t_end)
     device = dict(system.device, memory_peak_bytes=memory_peak_bytes())
 
@@ -592,19 +607,21 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         run.trace = tr.load(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
+    t_check = time.monotonic()
     results = checks.compare(run, n, edge_list, traffic, final,
                              seed_rng(seed, "check"), control)
+    results["answers"]["reference_s"] = time.monotonic() - t_check
     out = finish(run, results, regrows, built, log)
     if control:
         out["control"] = results.get("control", {})
-        out["answers_checked"] = results["answers_checked"]
     return out
 
 
 def finish(run: Run, results: dict, regrows: dict, built: dict,
            log) -> dict:
     """Assemble the result line: metrics by their readers, the device,
-    the breakdown of a traced run, and the compared numbers last."""
+    the breakdown of a traced run, what the reference read, and the
+    compared numbers last."""
     from benchmarks.chip import trace as tr
 
     kind = "per_layer" if run.traced else "end_to_end"
@@ -646,6 +663,10 @@ def finish(run: Run, results: dict, regrows: dict, built: dict,
         log(f"load: {k} {v}")
     for k, v in metrics.items():
         log(f"metric: {k} {v['value']} {v['unit']}")
+    # what the reference read and how long it took: no limits
+    out["reference"] = results["answers"]
+    for k, v in out["reference"].items():
+        log(f"reference: {k} {v}")
     for name, c in results["checks"].items():
         log(f"check: {name} {c['value']} (limit {c['limit']})")
     out["checks"] = results["checks"]

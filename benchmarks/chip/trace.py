@@ -5,7 +5,9 @@ The JAX profiler writes ``<dir>/plugins/profile/<time>/*.xplane.pb``;
 ``/device:TPU:<i>``; their ``XLA Ops`` line holds one event per operation
 that ran, their ``XLA Modules`` line one per executable.  Host planes hold
 the benchmark's own spans (``jax.profiler.TraceAnnotation``), all named
-``bench.<what>``, on the same clock.
+``bench.<what>``, and the program's (``repro.spans``), all named
+``spc.<what>``, on the same clock; each is kept with the thread (profiler
+line) it ran on, so that nested spans can be told apart.
 
 The rules, kept here so that every PR computes them the same way:
 
@@ -13,8 +15,21 @@ The rules, kept here so that every PR computes them the same way:
   to the traced window (the ``bench.window`` span), averaged over the
   devices that ran anything;
 - a kernel's or an executable's time is the sum of its events' durations;
-- an idle gap is a stretch of the window in which no op ran; each gap is
-  named by the host span that covered most of it.
+- an idle gap is a stretch of the window in which no op ran on the first
+  device.  It is named, in this order, by:
+
+  0. ``bench.gc``, where a full collection of Python's collector covers
+     at least half the gap (it holds the interpreter lock, so no span
+     open on another thread runs meanwhile);
+  1. the ``spc.*`` span, not a wait, that covers most of the gap;
+  2. else the ``spc.*_wait`` span that covers most of it;
+  3. else the ``bench.*`` span that covers most of it (``bench.window``
+     aside);
+  4. else ``no span``.
+
+  A span covers the part of the gap that the spans nested in it, on its
+  thread, do not: a gap inside ``spc.read.gather`` is named by that span,
+  not by the ``spc.read`` or ``bench.reader`` around it.
 """
 
 from __future__ import annotations
@@ -27,6 +42,10 @@ import re
 
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "spc."
+WAIT_SUFFIX = "_wait"
+GC_SPAN = "bench.gc"
+NO_SPAN = "no span"
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -39,6 +58,7 @@ class Event:
     end_ns: float
     device: str = ""
     stats: tuple = ()
+    thread: str = ""
 
     @property
     def seconds(self) -> float:
@@ -49,7 +69,7 @@ class Event:
 class Trace:
     ops: list          # Event per device op, all devices
     modules: list      # Event per executable run, all devices
-    spans: list        # Event per bench.* host span
+    spans: list        # Event per bench.* and spc.* host span
 
     @property
     def devices(self) -> list:
@@ -91,11 +111,12 @@ def load(path: str) -> Trace:
                                      e.start_ns + e.duration_ns,
                                      plane.name, tuple(_stats(e))))
         elif plane.name.startswith("/host:"):
-            for line in plane.lines:
+            for k, line in enumerate(plane.lines):
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
                         spans.append(Event(e.name, e.start_ns,
-                                           e.start_ns + e.duration_ns))
+                                           e.start_ns + e.duration_ns,
+                                           thread=f"{plane.name}#{k}"))
     return Trace(ops, modules, spans)
 
 
@@ -157,16 +178,133 @@ def idle_gaps(trace: Trace, lo: float, hi: float) -> list:
     return gaps
 
 
-def name_gap(spans, start: float, end: float) -> str:
-    """The host span (other than the window) covering most of a gap."""
-    best, best_cover = "no span", 0.0
-    for sp in spans:
-        if sp.name == WINDOW_SPAN:
+def is_program(name: str) -> bool:
+    return name.startswith(PROGRAM_PREFIX)
+
+
+def is_wait(name: str) -> bool:
+    return name.endswith(WAIT_SUFFIX)
+
+
+def _own_intervals(spans) -> list:
+    """``(span, [(start, end), ...])`` per span: its interval less those
+    of the spans directly nested in it on its thread."""
+    by_thread: dict = {}
+    for s in spans:
+        by_thread.setdefault(s.thread, []).append(s)
+    out = []
+    for group in by_thread.values():
+        group.sort(key=lambda s: (s.start_ns, -s.end_ns))
+        kids = {id(s): [] for s in group}
+        stack = []
+        for s in group:
+            while stack and stack[-1].end_ns <= s.start_ns:
+                stack.pop()
+            if stack and s.end_ns <= stack[-1].end_ns:
+                kids[id(stack[-1])].append((s.start_ns, s.end_ns))
+            stack.append(s)
+        for s in group:
+            own, cur = [], s.start_ns
+            for a, b in merge_intervals(kids[id(s)], s.start_ns, s.end_ns):
+                if a > cur:
+                    own.append((cur, a))
+                cur = max(cur, b)
+            if cur < s.end_ns:
+                own.append((cur, s.end_ns))
+            out.append((s, own))
+    return out
+
+
+class GapNamer:
+    """Names idle gaps by the rules of the module docstring; built once
+    per trace, so that each gap looks only at the spans near it."""
+
+    def __init__(self, spans) -> None:
+        spans = [s for s in spans if s.name != WINDOW_SPAN]
+        self.gc = sorted((s.start_ns, s.end_ns) for s in spans
+                         if s.name == GC_SPAN)
+        self.items = sorted(_own_intervals(spans),
+                            key=lambda item: item[0].start_ns)
+        self.starts = [s.start_ns for s, _ in self.items]
+        self.longest = max((s.end_ns - s.start_ns for s, _ in self.items),
+                           default=0.0)
+
+    def name(self, start: float, end: float) -> str:
+        gc = sum(e - s for s, e in merge_intervals(self.gc, start, end))
+        if gc >= 0.5 * (end - start):
+            return GC_SPAN
+        best = {}  # rule -> (cover, name)
+        i = bisect.bisect_left(self.starts, end) - 1
+        while i >= 0 and self.starts[i] > start - self.longest:
+            sp, own = self.items[i]
+            i -= 1
+            cover = sum(min(end, b) - max(start, a) for a, b in own
+                        if b > start and a < end)
+            if cover <= 0:
+                continue
+            rule = ((1 if not is_wait(sp.name) else 2)
+                    if is_program(sp.name) else 3)
+            if cover > best.get(rule, (0.0, ""))[0]:
+                best[rule] = (cover, sp.name)
+        return best[min(best)][1] if best else NO_SPAN
+
+
+def named_gaps(trace: Trace, top: int | None = None) -> list:
+    """``[name, seconds, start]`` per idle gap of the window (the
+    ``top`` longest only, where given), ``start`` in seconds from the
+    window's start, longest first."""
+    lo, hi = trace.window()
+    namer = GapNamer([s for s in trace.spans
+                      if s.end_ns > lo and s.start_ns < hi])
+    gaps = sorted(idle_gaps(trace, lo, hi),
+                  key=lambda g: g[0] - g[1])[:top]
+    return [[namer.name(s, e), (e - s) * 1e-9, (s - lo) * 1e-9]
+            for s, e in gaps]
+
+
+def idle_by_span(trace: Trace) -> dict:
+    """Idle seconds of the window by the name of each gap, every gap
+    counted, largest first."""
+    out: dict = {}
+    for name, secs, _ in named_gaps(trace):
+        out[name] = out.get(name, 0.0) + secs
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
+
+
+def span_seconds(trace: Trace) -> dict:
+    """``[count, seconds]`` per span name among the spans wholly inside
+    the window (the window itself aside), summed over threads."""
+    lo, hi = trace.window()
+    out: dict = {}
+    for s in events_in(trace.spans, lo, hi):
+        if s.name == WINDOW_SPAN:
             continue
-        cover = min(end, sp.end_ns) - max(start, sp.start_ns)
-        if cover > best_cover:
-            best, best_cover = sp.name, cover
-    return best
+        count, secs = out.get(s.name, (0, 0.0))
+        out[s.name] = (count + 1, secs + s.seconds)
+    return {k: list(v) for k, v in sorted(out.items())}
+
+
+class GcSpans:
+    """``gc.callbacks`` entry: a ``bench.gc`` host span around each
+    generation-2 collection (the collector runs on the thread that
+    triggered it, so the span opens and closes on that thread)."""
+
+    def __init__(self) -> None:
+        import jax
+
+        # bound now: a collection can start in the middle of an import
+        self.annotation = jax.profiler.TraceAnnotation
+        self.open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self.open = self.annotation(GC_SPAN)
+            self.open.__enter__()
+        elif self.open is not None:
+            self.open.__exit__(None, None, None)
+            self.open = None
 
 
 def time_by_name(events, match=None) -> dict:
@@ -224,9 +362,7 @@ def summarize(trace: Trace, top: int = 10) -> dict:
     for name, e in zip(op_names(trace, inside), inside):
         ops[name] = ops.get(name, 0.0) + e.seconds
     top_ops = sorted(ops.items(), key=lambda kv: -kv[1])[:top]
-    spans = [s for s in trace.spans if s.end_ns > lo and s.start_ns < hi]
-    gaps = sorted(idle_gaps(trace, lo, hi), key=lambda g: g[0] - g[1])[:top]
-    named = [[name_gap(spans, s, e), (e - s) * 1e-9] for s, e in gaps]
+    named = [[name, secs] for name, secs, _ in named_gaps(trace, top)]
     return {
         "busy_s": busy,
         "window_s": (hi - lo) * 1e-9,

@@ -33,7 +33,7 @@ out = harness.run_cell(cell, int(sys.argv[4]), 2.0, False, True,
 print(json.dumps({"program": {k: v["value"]
                               for k, v in out["checks"].items()},
                   "control": out["control"], "correct": out["correct"],
-                  "checked": out["answers_checked"]}))
+                  "checked": out["reference"]["answers_checked"]}))
 '''
 
 

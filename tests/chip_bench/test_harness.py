@@ -155,6 +155,10 @@ def test_warm_split_serves_every_k_per_bucket_through_the_reader():
     out = harness.warm_split(svc, PairSampler(degree), (8, 64), 5,
                              np.random.default_rng(3), probes=4096)
     assert out["found"] and out["ks"] == {8: 5, 64: 5}
+    # one batch of each bucket takes the plain route, before the splits
+    plain = svc.batches[-12:-10]
+    assert [s.size for s, _ in plain] == [8, 64]
+    assert not any(np.any((s == 0) & (t == 1)) for s, t in plain)
     warm = svc.batches[-10:]
     for (s, t), (bucket, k) in zip(warm, [(8, k) for k in range(1, 6)]
                                    + [(64, k) for k in range(1, 6)]):

@@ -1,7 +1,8 @@
 """The program's spans in a trace and the readers of the program's
 counters: the idle gaps of a recorded trace named by the nested
 ``spc.*`` and ``bench.*`` spans over them (``data/spans_trace.textproto``),
-and each new metric reader on a fixture run."""
+each metric reader of them on a fixture run, and the harness's counters
+of a service."""
 
 import json
 import os
@@ -11,7 +12,7 @@ import sys
 
 import pytest
 
-from benchmarks.chip import harness, program_spans
+from benchmarks.chip import harness
 from benchmarks.chip import trace as tr
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -36,17 +37,19 @@ def spans_path(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def traced(spans_path):
-    """The trace as the breakdown run keeps it: every host span, each
-    with its thread."""
-    trace = tr.load(spans_path)
-    trace.spans = program_spans.load(spans_path)
-    return trace
+    """The trace as every traced run keeps it: the benchmark's and the
+    program's host spans, each with its thread."""
+    return tr.load(spans_path)
 
 
 def test_the_accepted_loader_still_keeps_only_bench_spans(spans_path):
-    trace = tr.load(spans_path)
-    assert sorted(s.name for s in trace.spans) == [
-        "bench.gc", "bench.reader", "bench.submit", "bench.window"]
+    """The loader keeps the benchmark's ``bench.*`` spans and the
+    program's ``spc.*`` spans, and no other host event."""
+    names = {s.name for s in tr.load(spans_path).spans}
+    assert {n for n in names if not tr.is_program(n)} == {
+        "bench.gc", "bench.reader", "bench.submit", "bench.window"}
+    assert all(n.startswith(("bench.", "spc.")) for n in names)
+    assert "spc.read" in names
 
 
 def test_program_spans_load_with_their_threads(traced):
@@ -59,7 +62,7 @@ def test_program_spans_load_with_their_threads(traced):
 
 
 def test_gaps_are_named_gc_then_work_then_wait_then_bench(traced):
-    assert program_spans.idle_by_span(traced) == pytest.approx({
+    assert tr.idle_by_span(traced) == pytest.approx({
         "spc.read.split": 300e-9, "spc.read.ryw_wait": 200e-9,
         "no span": 150e-9, "spc.read.gather": 100e-9,
         "bench.gc": 100e-9, "bench.submit": 100e-9})
@@ -68,7 +71,7 @@ def test_gaps_are_named_gc_then_work_then_wait_then_bench(traced):
 def test_every_gap_is_counted_and_the_names_sum_to_the_idle_time(traced):
     lo, hi = traced.window()
     idle = (hi - lo) * 1e-9 - tr.busy_seconds(traced, lo, hi)
-    assert sum(program_spans.idle_by_span(traced).values()) == \
+    assert sum(tr.idle_by_span(traced).values()) == \
         pytest.approx(idle)
 
 
@@ -80,11 +83,11 @@ def test_without_program_spans_gaps_are_named_as_before(tmp_path_factory):
     want: dict = {}
     for name, secs in named:
         want[name] = want.get(name, 0.0) + secs
-    assert program_spans.idle_by_span(trace) == pytest.approx(want)
+    assert tr.idle_by_span(trace) == pytest.approx(want)
 
 
 def test_named_gaps_are_longest_first_with_their_start(traced):
-    got = program_spans.named_gaps(traced)
+    got = tr.named_gaps(traced)
     assert [g[0] for g in got[:2]] == ["spc.read.split", "spc.read.ryw_wait"]
     assert got[0][1:] == pytest.approx([300e-9, 200e-9])
     assert len(got) == 6
@@ -95,9 +98,7 @@ def test_a_full_collection_is_a_gc_span(tmp_path):
 
     import jax
 
-    from benchmarks.chip.breakdown import GcSpans
-
-    spans = GcSpans()
+    spans = tr.GcSpans()
     gc.callbacks.append(spans)
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -106,13 +107,13 @@ def test_a_full_collection_is_a_gc_span(tmp_path):
     finally:
         jax.profiler.stop_trace()
         gc.callbacks.remove(spans)
-    names = [s.name for s in program_spans.load(str(tmp_path))]
-    assert names.count(program_spans.GC_SPAN) >= 1
-    assert set(names) == {program_spans.GC_SPAN}
+    names = [s.name for s in tr.load(str(tmp_path)).spans]
+    assert names.count(tr.GC_SPAN) >= 1
+    assert set(names) == {tr.GC_SPAN}
 
 
 def test_span_seconds_count_and_sum_each_name(traced):
-    got = program_spans.span_seconds(traced)
+    got = tr.span_seconds(traced)
     assert got["spc.read.bound_wait"] == [2, pytest.approx(360e-9)]
     assert got["spc.read"] == [1, pytest.approx(670e-9)]
     assert "bench.window" not in got
@@ -124,14 +125,47 @@ def _run(window=None, trace=None):
                        window=window or {}, traced=True, trace=trace)
 
 
-def test_read_host_ms_per_batch_sums_the_host_steps_per_batch(
-        traced, spans_path):
+def test_read_host_ms_per_batch_sums_the_host_steps_per_batch(traced):
     read = harness.reader_for("read_host_ms_per_batch")
     # one spc.read; gather 70 + split 250 + kernel 30 + scatter 100 ns
     assert read(_run(trace=traced)) == pytest.approx(450e-6)
-    # the accepted loader keeps no spc.* span: nothing to read
-    assert read(_run(trace=tr.load(spans_path))) is None
+    # a trace with no spc.read span, or no trace: nothing to read
+    bench_only = tr.Trace(traced.ops, traced.modules,
+                          [s for s in traced.spans
+                           if not tr.is_program(s.name)])
+    assert read(_run(trace=bench_only)) is None
     assert read(_run()) is None
+
+
+class _Service:
+    """What ``harness.counters`` reads of a service with two serving
+    engines."""
+
+    def stats(self):
+        import dataclasses
+        import types
+
+        @dataclasses.dataclass
+        class Update:
+            batched_events: int = 0
+
+        views = [types.SimpleNamespace(routes={"pallas": 3},
+                                       route_pairs={"pallas": 990}),
+                 types.SimpleNamespace(routes={"pallas+merge": 1},
+                                       route_pairs={"pallas": 50,
+                                                    "merge": 10})]
+        return {"serve": views, "queries": 4, "update": Update(),
+                "version": 0}
+
+
+def test_the_window_counters_carry_pairs_per_path():
+    out = harness.counters(_Service(), None)
+    assert out["routes"] == {"pallas": 3, "pallas+merge": 1}
+    assert out["route_pairs"] == {"pallas": 1040, "merge": 10}
+    window = harness.delta(out, harness.counters(_Service(), None))
+    assert window["route_pairs"] == {"pallas": 0, "merge": 0}
+    read = harness.reader_for("pallas_pair_share")
+    assert read(_run(out)) == pytest.approx(100 * 1040 / 1050)
 
 
 def test_pallas_pair_share_reads_pairs_per_path():

@@ -54,7 +54,8 @@ def test_rehearsal_result_line(cell, tmp_path):
     assert out["correct"] is True
     assert list(out)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
-    assert list(out)[-1] == "checks"
+    assert list(out)[-2:] == ["reference", "checks"]
+    assert out["reference"]["reference_s"] > 0
     assert out["attempted"] > 0 and out["failed"] == 0
     assert out["device"]["platform"] == "cpu" and out["rehearsal"] is True
     want = {m["name"] for m in spec["end_to_end"]
@@ -73,4 +74,8 @@ def test_traced_rehearsal_reports_no_device_metric(tmp_path):
     sources = {m["name"]: m["source"] for m in BENCH["per_layer"]}
     assert out["metrics"], "counter metrics are still read"
     assert all(sources[k] != "device_trace" for k in out["metrics"])
+    # the read path's counter and spans reach their readers in the
+    # harness's own traced run
+    assert 0 < out["metrics"]["pallas_pair_share"]["value"] <= 100
+    assert out["metrics"]["read_host_ms_per_batch"]["value"] > 0
     assert "breakdown" not in out and "busy_s" not in out["device"]
