@@ -44,7 +44,8 @@ def _round_with(dbar_fn):
         roots = h0 + jnp.arange(hub_batch, dtype=jnp.int32)
         dbar = dbar_fn(idx, jnp.minimum(roots, jnp.int32(g.n)), h0)
         res = multi_pruned_spc_bfs(g, roots, dbar)
-        return bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep)
+        return (bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep),
+                res.levels)
     return round_
 
 
@@ -69,7 +70,7 @@ def round_seconds(round_fn, g, l_cap: int, hubs: int, hub_batch: int):
     warm_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     for h0 in range(0, hubs, hub_batch):
-        idx = round_fn(g, idx, h0, hub_batch)
+        idx, _ = round_fn(g, idx, h0, hub_batch)
     jax.block_until_ready(idx)
     return time.perf_counter() - t0, warm_s, idx
 

@@ -25,6 +25,12 @@ are independent -- so the distributed engines
 (local segment-sum per edge shard + one ``psum`` per level) and every
 algorithm layer above (construction, IncSPC, DecSPC, HybSPC) is written
 once against the abstract relaxation.
+
+Level sums keep the count contract (``repro.core.counts``): a sum that
+could pass 2^63 - 1 saturates there instead of wrapping, since a
+wrapped sum <= 0 would also leave its vertex undiscovered.  While the
+frontier's largest count times the number of edge slots stays under
+2^63 a level is today's single ``segment_sum``.
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.counts import relax_sums
 from repro.core.graph import INF, Graph
 
 #: ``relax_fn(src, dst, cnt, frontier) -> int64[n + 1]`` per-destination
@@ -93,10 +100,12 @@ def edge_relax(src: jax.Array, dst: jax.Array, cnt: jax.Array,
     """One edge relaxation: per-destination sums of frontier counts.
 
     The single-device default ``RelaxFn``; ``n + 1`` is recovered from
-    ``cnt`` so the same signature serves sharded edge blocks.
+    ``cnt`` so the same signature serves sharded edge blocks.  Sums
+    saturate at 2^63 - 1 (the count contract).
     """
-    contrib = jnp.where(frontier[src], cnt[src], jnp.int64(0))
-    return jax.ops.segment_sum(contrib, dst, num_segments=cnt.shape[0])
+    masked = compress_frontier(cnt, frontier)
+    return relax_sums(masked[src], dst, cnt.shape[0], jnp.max(masked),
+                      src.shape[0])
 
 
 def relax(g: Graph, cnt: jax.Array, frontier: jax.Array) -> jax.Array:
@@ -125,13 +134,13 @@ def multi_edge_relax(src: jax.Array, dst: jax.Array, cnt: jax.Array,
     The single-device default :data:`MultiRelaxFn`: per-destination
     segment-sums of compressed frontier counts, vectorized over the
     hub-batch axis.  ``n + 1`` is recovered from ``cnt`` so the same
-    signature serves sharded edge blocks.
+    signature serves sharded edge blocks.  Sums saturate at 2^63 - 1
+    (the count contract).
     """
     masked = compress_frontier(cnt, frontier)
     contrib = masked[:, src]  # [B, E] -- the single per-level edge gather
-    return jax.vmap(
-        lambda c: jax.ops.segment_sum(c, dst, num_segments=cnt.shape[1])
-    )(contrib)
+    return relax_sums(contrib, dst, cnt.shape[1], jnp.max(masked),
+                      src.shape[0])
 
 
 def pruned_spc_bfs(

@@ -45,6 +45,7 @@ from repro.core.labels import (SPCIndex, bulk_append, bulk_append_batch,
                                empty_index, repad)
 from repro.core.order import graph_ordering, relabel_graph
 from repro.core.query import one_to_all, one_to_all_dists
+from repro.spans import span
 
 
 def _hub_round(g: Graph, idx: SPCIndex, v,
@@ -97,8 +98,10 @@ def provision_l_cap(g: Graph, floor: int = 4) -> int:
 
 @partial(jax.jit, static_argnames=("hub_batch", "multi_relax_fn"))
 def _hub_batch_round(g: Graph, idx: SPCIndex, h0, hub_batch: int,
-                     multi_relax_fn: MultiRelaxFn | None = None) -> SPCIndex:
-    """One batch of ``hub_batch`` consecutive hubs [h0, h0 + B).
+                     multi_relax_fn: MultiRelaxFn | None = None
+                     ) -> tuple[SPCIndex, jax.Array]:
+    """One batch of ``hub_batch`` consecutive hubs [h0, h0 + B), and the
+    relaxation rounds (BFS levels) its lockstep BFS ran.
 
     Committed pruning distances are PreQuery of each root against the
     index *as of h0* (``limit=h0`` equals the sequential ``limit=h_b``
@@ -112,7 +115,8 @@ def _hub_batch_round(g: Graph, idx: SPCIndex, h0, hub_batch: int,
     dbar = one_to_all_dists(idx, roots_c, h0)
     res = multi_pruned_spc_bfs(g, roots, dbar,
                                multi_relax_fn=multi_relax_fn)
-    return bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep)
+    return (bulk_append_batch(idx, h0, res.dist, res.cnt, res.keep),
+            res.levels)
 
 
 def build_index_batched(
@@ -123,6 +127,7 @@ def build_index_batched(
     order: str = "id",
     multi_relax_fn: MultiRelaxFn | None = None,
     on_regrow: Callable[[int], None] | None = None,
+    on_round: Callable[[int], None] | None = None,
 ) -> SPCIndex:
     """Batched SPC-Index construction; order-identical to
     :func:`build_index` on the same (relabeled) graph.
@@ -152,6 +157,12 @@ def build_index_batched(
         graph padded via ``pad_graph_for``).
       on_regrow: optional callback invoked with the new capacity on
         every overflow-retry (stats hook for the drivers).
+      on_round: optional callback invoked with the relaxation rounds
+        (``MultiBFSResult.levels``) of every round run, retried ones
+        included, read with the overflow counter.
+
+    Each round's dispatch and overflow check is a ``spc.build.round``
+    span (``repro.spans``).
     """
     if hub_batch < 1:
         raise ValueError(f"hub_batch must be >= 1, got {hub_batch}")
@@ -163,8 +174,13 @@ def build_index_batched(
     for h0 in range(0, g.n, hub_batch):
         snap = idx
         while True:
-            idx = _hub_batch_round(g, snap, h0, hub_batch, multi_relax_fn)
-            if int(idx.overflow) == 0:
+            with span("spc.build.round"):
+                idx, levels = _hub_batch_round(g, snap, h0, hub_batch,
+                                               multi_relax_fn)
+                overflow, levels = jax.device_get((idx.overflow, levels))
+            if on_round is not None:
+                on_round(int(levels))
+            if int(overflow) == 0:
                 break
             snap = repad(snap, snap.l_cap * 2)
             if on_regrow is not None:

@@ -45,6 +45,7 @@ from repro.core import hybrid as H
 from repro.core import incremental as I
 from repro.core.bfs import compress_frontier
 from repro.core.construct import build_index, build_index_batched
+from repro.core.counts import relax_sums
 from repro.core.graph import Graph
 from repro.core.query import gather_rows, merge_rows
 
@@ -68,10 +69,13 @@ def make_sharded_relax(mesh: Mesh, edge_axis: str):
     ``edge_axis`` (see :func:`pad_graph_for`).
     """
 
+    shards = int(mesh.shape[edge_axis])
+
     def local_relax(src_blk, dst_blk, cnt, frontier):
-        contrib = jnp.where(frontier[src_blk], cnt[src_blk], jnp.int64(0))
-        part = jax.ops.segment_sum(contrib, dst_blk, num_segments=cnt.shape[0])
-        return jax.lax.psum(part, edge_axis)
+        masked = compress_frontier(cnt, frontier)
+        return relax_sums(masked[src_blk], dst_blk, cnt.shape[0],
+                          jnp.max(masked), src_blk.shape[0] * shards,
+                          total=lambda x: jax.lax.psum(x, edge_axis))
 
     return shard_map(
         local_relax,
@@ -96,14 +100,14 @@ def make_sharded_multi_relax(mesh: Mesh, edge_axis: str):
     moves one operand, not two.
     """
 
+    shards = int(mesh.shape[edge_axis])
+
     def local_multi_relax(src_blk, dst_blk, cnt, frontier):
         masked = compress_frontier(cnt, frontier)
         contrib = masked[:, src_blk]  # [B, E/shards]
-        part = jax.vmap(
-            lambda c: jax.ops.segment_sum(c, dst_blk,
-                                          num_segments=cnt.shape[1])
-        )(contrib)
-        return jax.lax.psum(part, edge_axis)
+        return relax_sums(contrib, dst_blk, cnt.shape[1], jnp.max(masked),
+                          src_blk.shape[0] * shards,
+                          total=lambda x: jax.lax.psum(x, edge_axis))
 
     return shard_map(
         local_multi_relax,

@@ -75,6 +75,7 @@ class UpdateStatsView:
     batched_events: int
     hub_repairs: int
     relax_rounds: int
+    build_relax_rounds: int
 
     @property
     def events_per_batch(self) -> float:
@@ -97,6 +98,9 @@ class UpdateStats:
     #: and of SRRSearch
     hub_repairs: int = 0
     relax_rounds: int = 0
+    #: relaxation rounds of the batched build (``MultiBFSResult.levels``
+    #: summed over its hub rounds, retried ones included)
+    build_relax_rounds: int = 0
 
     def __post_init__(self):
         # one updater thread writes, but serving/monitoring threads read
@@ -175,7 +179,8 @@ class DynamicSPC:
                        if self._updater is not None else build_index_batched)
             return build_b(
                 self.graph, l_cap, hub_batch=self._construct_batch,
-                on_regrow=lambda _cap: self.stats.bump(label_regrows=1))
+                on_regrow=lambda _cap: self.stats.bump(label_regrows=1),
+                on_round=lambda r: self.stats.bump(build_relax_rounds=r))
         if l_cap is None:
             l_cap = provision_l_cap(self.graph)
         build = (self._updater.build_index if self._updater is not None

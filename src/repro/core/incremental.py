@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import graph as G
 from repro.core.bfs import RelaxFn, RepairWork, pruned_spc_bfs
+from repro.core.counts import sat_add
 from repro.core.graph import Graph
 from repro.core.labels import SPCIndex, bulk_upsert
 from repro.core.query import one_to_all
@@ -51,8 +52,9 @@ def _inc_update(g: Graph, idx: SPCIndex, h, va, vb,
     rows = jnp.arange(idx.n + 1)
     d_i = idx.dist[rows, at]
     c_i = idx.cnt[rows, at]
-    # "if d = d_i then c <- c + c_i": accumulate equal-length counts.
-    c_new = res.cnt + jnp.where(has & (res.dist == d_i), c_i, 0)
+    # "if d = d_i then c <- c + c_i": accumulate equal-length counts
+    # (saturating: the count contract).
+    c_new = sat_add(res.cnt, jnp.where(has & (res.dist == d_i), c_i, 0))
     return bulk_upsert(idx, h, res.dist, c_new, res.keep), res.levels
 
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.counts import COUNT_MAX, sat_add, sat_sum
 from repro.core.graph import INF
 
 
@@ -47,7 +48,8 @@ class SPCIndex:
         return jnp.sum(self.size)
 
 
-#: ``cnt_sum`` invariant -- ``cnt_sum[v] == sum(cnt[v])`` at all times.
+#: ``cnt_sum`` invariant -- ``cnt_sum[v] == min(sum(cnt[v]), 2^63 - 1)``
+#: at all times (the count contract, ``repro.core.counts``).
 #: ``sum(cnt[s]) * sum(cnt[t])`` is the serving engine's per-row fp32
 #: exactness bound (``repro.core.query.count_upper_bound_rows``); caching
 #: the per-vertex factor on the index turns the per-batch O(B L)
@@ -57,11 +59,23 @@ class SPCIndex:
 #: update engine (IncSPC/DecSPC/HybSPC, replicated or edge-sharded) goes
 #: through them -- so maintaining the delta here keeps the cache exact
 #: everywhere (differential-tested against :func:`recompute_cnt_sum`).
+#: Adds saturate; a saturated sum cannot be kept by subtraction, so a
+#: row at 2^63 - 1 that loses or replaces a label is summed again from
+#: its row (:func:`_resum`), which only ever runs where one is.
 
 
 def recompute_cnt_sum(cnt: jax.Array) -> jax.Array:
     """The cached field from scratch (validation / legacy state dicts)."""
-    return jnp.sum(cnt, axis=1, dtype=jnp.int64)
+    return sat_sum(cnt, axis=1)
+
+
+def _resum(cnt_sum: jax.Array, cnt: jax.Array, stale: jax.Array):
+    """``cnt_sum`` with the rows of ``stale`` summed again from ``cnt``;
+    the row sums run only when some row is stale."""
+    return jax.lax.cond(
+        jnp.any(stale),
+        lambda: jnp.where(stale, recompute_cnt_sum(cnt), cnt_sum),
+        lambda: cnt_sum)
 
 
 def empty_index(n: int, l_cap: int) -> SPCIndex:
@@ -147,7 +161,7 @@ def bulk_append(idx: SPCIndex, h, d_new, c_new, mask) -> SPCIndex:
     cnt = idx.cnt.at[rows, col].set(
         jnp.where(fits, c_new.astype(jnp.int64), idx.cnt[rows, col]))
     size = idx.size + fits.astype(jnp.int32)
-    cnt_sum = idx.cnt_sum + jnp.where(fits, c_new.astype(jnp.int64), 0)
+    cnt_sum = sat_add(idx.cnt_sum, jnp.where(fits, c_new, 0))
     return dataclasses.replace(
         idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
@@ -187,7 +201,7 @@ def bulk_append_batch(idx: SPCIndex, h0, d_new, c_new, mask) -> SPCIndex:
     dist = idx.dist.at[rows, cols].set(d_new.astype(jnp.int32), mode="drop")
     cnt = idx.cnt.at[rows, cols].set(c64, mode="drop")
     size = idx.size + jnp.sum(fits, axis=0, dtype=jnp.int32)
-    cnt_sum = idx.cnt_sum + jnp.sum(jnp.where(fits, c64, 0), axis=0)
+    cnt_sum = sat_add(idx.cnt_sum, sat_sum(jnp.where(fits, c64, 0), axis=0))
     return dataclasses.replace(
         idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
@@ -240,9 +254,12 @@ def bulk_upsert(idx: SPCIndex, h, d_new, c_new, mask) -> SPCIndex:
         cnt)
     size = idx.size + fits.astype(jnp.int32)
     c64 = c_new.astype(jnp.int64)
-    cnt_sum = (idx.cnt_sum
-               + jnp.where(mask & has, c64 - old_c, 0)   # replaced in place
-               + jnp.where(fits, c64, 0))                # freshly inserted
+    replaced = mask & has
+    # replaced in place: the old value comes off an exact sum; freshly
+    # inserted: a saturated sum stays saturated
+    cnt_sum = sat_add(jnp.where(replaced, idx.cnt_sum - old_c, idx.cnt_sum),
+                      jnp.where(replaced | fits, c64, 0))
+    cnt_sum = _resum(cnt_sum, cnt, replaced & (idx.cnt_sum == COUNT_MAX))
     return dataclasses.replace(
         idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
@@ -274,6 +291,7 @@ def bulk_remove(idx: SPCIndex, h, mask) -> SPCIndex:
     size = idx.size - act.astype(jnp.int32)
     rows = jnp.arange(idx.n + 1)
     cnt_sum = idx.cnt_sum - jnp.where(act, idx.cnt[rows, pos], 0)
+    cnt_sum = _resum(cnt_sum, cnt, act & (idx.cnt_sum == COUNT_MAX))
     return dataclasses.replace(idx, hub=hub, dist=dist, cnt=cnt, size=size,
                                cnt_sum=cnt_sum)
 
@@ -341,7 +359,7 @@ def from_ref(ref, l_cap: int | None = None) -> SPCIndex:
     size = np.asarray(idx.size).copy()
     for v, row in enumerate(ref.labels):
         for j, (h, d, c) in enumerate(row):
-            hub[v, j], dist[v, j], cnt[v, j] = h, d, c
+            hub[v, j], dist[v, j], cnt[v, j] = h, d, min(c, COUNT_MAX)
         size[v] = len(row)
     return SPCIndex(hub=jnp.asarray(hub), dist=jnp.asarray(dist),
                     cnt=jnp.asarray(cnt), size=jnp.asarray(size),

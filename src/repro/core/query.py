@@ -19,6 +19,12 @@ that hold B (s, t) pairs gather each side exactly once and reuse the rows
 across routing decisions and evaluation -- this is the contract of the
 serving engine (``repro.serve``) and the sharded query path
 (``repro.core.distributed``).
+
+Counts keep the count contract (``repro.core.counts``): products and
+sums over common hubs saturate at 2^63 - 1.  Each evaluation keeps
+today's int64 arithmetic where the largest counts it reads prove no
+product or sum can reach 2^63 (``counts.products_fit``), and takes the
+saturating form under a ``lax.cond`` otherwise.
 """
 
 from __future__ import annotations
@@ -28,36 +34,37 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.counts import products_fit, sat_mul, sat_sum
 from repro.core.graph import INF
 from repro.core.labels import SPCIndex
 
 _BIG = INF * 2  # > any real distance sum; int32-safe
 
 
-def _intersect(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, limit):
+def _hub_count(hit, a, b, wide: bool, axis=None):
+    """Sum of ``a * b`` where ``hit``: int64, or saturating (``wide``)."""
+    if wide:
+        return sat_sum(jnp.where(hit, sat_mul(a, b), 0), axis=axis)
+    return jnp.sum(jnp.where(hit, a * b, 0), axis=axis, dtype=jnp.int64)
+
+
+def _intersect(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, limit,
+               wide: bool = True):
     """Shared pair-intersection core; ``limit`` masks hubs >= limit
-    (PreQuery); pass limit = n+1 for the full query."""
+    (PreQuery); pass limit = n+1 for the full query.  ``wide=False``
+    drops the saturation where the caller has proved it cannot bite."""
     eq = (hub_s[:, None] == hub_t[None, :]) & (hub_s[:, None] < limit)
     dsum = dist_s[:, None] + dist_t[None, :]
     dsum = jnp.where(eq, dsum, _BIG)
     d = jnp.min(dsum)
-    prod = cnt_s[:, None] * cnt_t[None, :]
-    c = jnp.sum(jnp.where(dsum == d, prod, 0), dtype=jnp.int64)
+    c = _hub_count(dsum == d, cnt_s[:, None], cnt_t[None, :], wide)
     disconnected = d >= INF
     return (jnp.where(disconnected, INF, d).astype(jnp.int32),
             jnp.where(disconnected, 0, c))
 
 
-def pair_query(idx: SPCIndex, s, t):
-    """Algorithm 1: (dist, count) between s and t. Returns (INF, 0) if
-    disconnected."""
-    return _intersect(
-        idx.hub[s], idx.dist[s], idx.cnt[s],
-        idx.hub[t], idx.dist[t], idx.cnt[t],
-        jnp.int32(idx.n + 1))
-
-
-def _intersect_merge(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t):
+def _intersect_merge(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t,
+                     wide: bool = True):
     """Sorted-merge intersection via searchsorted: O(L log L) ops and
     O(L) intermediates (vs the L x L table's O(L^2)).  Rows are sorted
     by hub id with pad = n (sorts last), so a binary probe of L(t) per
@@ -69,21 +76,61 @@ def _intersect_merge(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t):
     match = hub_t[pos_c] == hub_s
     dsum = jnp.where(match, dist_s + dist_t[pos_c], _BIG)
     d = jnp.min(dsum)
-    c = jnp.sum(jnp.where(dsum == d, cnt_s * cnt_t[pos_c], 0),
-                dtype=jnp.int64)
+    c = _hub_count(dsum == d, cnt_s, cnt_t[pos_c], wide)
     disconnected = d >= INF
     return (jnp.where(disconnected, INF, d).astype(jnp.int32),
             jnp.where(disconnected, 0, c))
 
 
+def _keeps_contract(core, in_axes=None, name: str | None = None):
+    """``core(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, *rest, wide)``
+    (vmapped over rows with ``in_axes`` if given) as one evaluation that
+    keeps the count contract: today's int64 arithmetic where the largest
+    counts of its rows prove no product or sum of at most l_cap terms
+    reaches 2^63 (``counts.products_fit``), the saturating form
+    otherwise.  The test is one scalar over the whole batch, so a
+    batched evaluation runs one of the two, never both.  ``name`` names
+    the evaluation (and so its jitted executable)."""
+    plain = partial(core, wide=False)
+    wide = partial(core, wide=True)
+    if in_axes is not None:
+        plain = jax.vmap(plain, in_axes=in_axes)
+        wide = jax.vmap(wide, in_axes=in_axes)
+
+    def evaluate(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, *rest):
+        fit = products_fit(jnp.max(cnt_s, initial=0),
+                           jnp.max(cnt_t, initial=0), cnt_s.shape[-1])
+        return jax.lax.cond(fit, plain, wide, hub_s, dist_s, cnt_s,
+                            hub_t, dist_t, cnt_t, *rest)
+
+    evaluate.__name__ = evaluate.__qualname__ = name or core.__name__
+    return evaluate
+
+
+_pair = _keeps_contract(_intersect)
+_pair_merge = _keeps_contract(_intersect_merge)
+
+
+def pair_query(idx: SPCIndex, s, t):
+    """Algorithm 1: (dist, count) between s and t. Returns (INF, 0) if
+    disconnected."""
+    return _pair(
+        idx.hub[s], idx.dist[s], idx.cnt[s],
+        idx.hub[t], idx.dist[t], idx.cnt[t],
+        jnp.int32(idx.n + 1))
+
+
 def pair_query_merge(idx: SPCIndex, s, t):
     """Algorithm 1 by sorted merge (memory-optimal serving path)."""
-    return _intersect_merge(
+    return _pair_merge(
         idx.hub[s], idx.dist[s], idx.cnt[s],
         idx.hub[t], idx.dist[t], idx.cnt[t])
 
 
-batched_query_merge = jax.vmap(pair_query_merge, in_axes=(None, 0, 0))
+def batched_query_merge(idx: SPCIndex, s, t):
+    """:func:`pair_query_merge` over pair batches: one count-contract test
+    for the whole batch (:func:`merge_rows`)."""
+    return merge_rows(*gather_rows(idx, s), *gather_rows(idx, t))
 
 
 # --------------------------------------------------------------------------
@@ -102,15 +149,16 @@ def gather_rows(idx: SPCIndex, v):
 #: operands -> (dist int32[B], cnt int64[B])).  The serving default.
 #: Tolerates a t side whose pad sentinel was re-padded to n + 1 for the
 #: Pallas kernel (real hub ids are < n, and n + 1 still sorts last).
-merge_rows = jax.vmap(_intersect_merge)
+merge_rows = _keeps_contract(_intersect_merge, in_axes=0, name="merge_rows")
 
 #: One-dispatch variant for callers that already hold gathered rows.
 merge_rows_jit = jax.jit(merge_rows)
 
 #: Batched L x L comparison-table intersection over gathered rows; the
 #: trailing ``limit`` is shared (pass n + 1 for the full query).  Same
-#: arithmetic as the Pallas kernel but int64-exact.
-table_rows = jax.vmap(_intersect, in_axes=(0, 0, 0, 0, 0, 0, None))
+#: arithmetic as the Pallas kernel but int64 under the count contract.
+table_rows = _keeps_contract(_intersect, in_axes=(0, 0, 0, 0, 0, 0, None),
+                             name="table_rows")
 
 
 def count_upper_bound_rows(cnt_s, cnt_t):
@@ -121,10 +169,11 @@ def count_upper_bound_rows(cnt_s, cnt_t):
     count AND every partial sum/product the fp32 kernel forms.  Rows whose
     bound stays below 2^24 are therefore provably exact on the fp32 path
     (pad entries carry cnt = 0 and do not inflate the bound).  float64 so
-    the bound itself cannot overflow (exact to 2^53).
+    the bound itself cannot overflow (exact to 2^53); each row's sum
+    saturates at 2^63 - 1 rather than wrap.
     """
-    tot_s = jnp.sum(cnt_s, axis=1).astype(jnp.float64)
-    tot_t = jnp.sum(cnt_t, axis=1).astype(jnp.float64)
+    tot_s = sat_sum(cnt_s, axis=1).astype(jnp.float64)
+    tot_t = sat_sum(cnt_t, axis=1).astype(jnp.float64)
     return tot_s * tot_t
 
 
@@ -143,7 +192,7 @@ def cached_count_bound(idx: SPCIndex, s, t):
 
 def pre_pair_query(idx: SPCIndex, s, t):
     """PreQuery(s, t): only hubs ranked strictly higher than s."""
-    return _intersect(
+    return _pair(
         idx.hub[s], idx.dist[s], idx.cnt[s],
         idx.hub[t], idx.dist[t], idx.cnt[t],
         jnp.asarray(s, jnp.int32))
@@ -192,9 +241,12 @@ def one_to_all(idx: SPCIndex, h, limit=None):
         cand = jnp.where(hubs < limit, cand, _BIG)
     cand = jnp.where(hubs < idx.n, cand, _BIG)   # drop pads
     d = jnp.min(cand, axis=1)
-    prod = idx.cnt * dense_c[hubs]
-    c = jnp.sum(jnp.where(cand == d[:, None], prod, 0), axis=1,
-                dtype=jnp.int64)
+    # saturating only where the largest counts could reach 2^63
+    c = jax.lax.cond(
+        products_fit(jnp.max(idx.cnt), jnp.max(dense_c), idx.l_cap),
+        partial(_hub_count, wide=False, axis=1),
+        partial(_hub_count, wide=True, axis=1),
+        cand == d[:, None], idx.cnt, dense_c[hubs])
     disconnected = d >= INF
     return (jnp.where(disconnected, INF, d).astype(jnp.int32),
             jnp.where(disconnected, 0, c))

@@ -31,6 +31,11 @@ import numpy as np
 
 INF = np.iinfo(np.int32).max // 4  # large sentinel, safe to add small ints
 
+#: The count contract: a count is right iff it equals
+#: ``min(true count, COUNT_MAX)``.  This module counts in Python ints and
+#: clamps only what it answers (``bfs_spc``, ``RefSPCIndex.query``).
+COUNT_MAX = 2 ** 63 - 1
+
 
 # --------------------------------------------------------------------------
 # Graph: adjacency as list of sorted sets (undirected, unweighted).
@@ -84,11 +89,11 @@ class RefGraph:
 def bfs_spc(g: RefGraph, s: int) -> Tuple[np.ndarray, np.ndarray]:
     """Single-source BFS computing (dist, count) to every vertex.
 
-    Counts use Python ints promoted into an object array when they could
-    exceed int64; in practice our test graphs stay well within int64.
+    Counts are Python ints, exact at any size, returned as int64 under
+    the count contract: ``min(count, COUNT_MAX)``.
     """
     dist = np.full(g.n, INF, dtype=np.int64)
-    cnt = np.zeros(g.n, dtype=np.int64)
+    cnt = [0] * g.n
     dist[s] = 0
     cnt[s] = 1
     q = collections.deque([s])
@@ -101,7 +106,7 @@ def bfs_spc(g: RefGraph, s: int) -> Tuple[np.ndarray, np.ndarray]:
                 q.append(w)
             elif dist[w] == dist[v] + 1:
                 cnt[w] += cnt[v]
-    return dist, cnt
+    return dist, np.array([min(c, COUNT_MAX) for c in cnt], dtype=np.int64)
 
 
 def bibfs_spc(g: RefGraph, s: int, t: int) -> Tuple[int, int]:
@@ -215,7 +220,7 @@ class RefSPCIndex:
                     c += cs_ * ct_
                 i += 1
                 j += 1
-        return d, c
+        return d, min(c, COUNT_MAX)
 
     # -- PreQuery(s, t): query restricted to hubs ranked higher than s ----
     def prequery(self, s: int, t: int) -> Tuple[int, int]:

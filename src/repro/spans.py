@@ -21,7 +21,9 @@ inside it (``serve/service.py``, ``serve/engine.py``,
 ``kernels/spc_query/ops.py``); on a mixed-exactness batch ``.merge``
 holds the one merge-and-patch dispatch of the rows over the count bound;
 ``spc.update.validate``, ``spc.update.apply`` (one event chunk) and
-``spc.update.publish`` (``core/dynamic.py``).
+``spc.update.publish`` (``core/dynamic.py``); ``spc.build.round`` (one
+hub round of the batched build: its dispatch and overflow check,
+``core/construct.py``).
 """
 
 from __future__ import annotations
