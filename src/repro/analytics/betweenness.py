@@ -32,7 +32,7 @@ are dump-row pairs ``(n, n)`` -- they evaluate disconnected and
 contribute zero; pad vertices are the dump row ``n`` and are masked.
 
 Dependencies are accumulated in float64: sigma products are exact to
-2^53, far beyond anything the fp32 serving bound (2^24) admits, and the
+2^53, far beyond the fp32 kernel's exact range (2^24), and the
 Brandes ratio is fractional anyway.
 
 :func:`betweenness_numpy` is the Brandes-style oracle (pure

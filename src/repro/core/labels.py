@@ -50,11 +50,12 @@ class SPCIndex:
 
 #: ``cnt_sum`` invariant -- ``cnt_sum[v] == min(sum(cnt[v]), 2^63 - 1)``
 #: at all times (the count contract, ``repro.core.counts``).
-#: ``sum(cnt[s]) * sum(cnt[t])`` is the serving engine's per-row fp32
-#: exactness bound (``repro.core.query.count_upper_bound_rows``); caching
-#: the per-vertex factor on the index turns the per-batch O(B L)
-#: reduction into an O(1) lookup per row, and lets the bound travel with
-#: a published snapshot so replicas route consistently mid-refresh.  The
+#: ``sum(cnt[s]) * sum(cnt[t])`` is a per-row upper bound on a pair's
+#: count (``repro.core.query.count_upper_bound_rows``); caching the
+#: per-vertex factor on the index turns the per-batch O(B L) reduction
+#: into an O(1) lookup per row (``cached_count_bound``).  The serving
+#: read path no longer routes on it: it decides exactness from the
+#: kernel's own fp32 answer (``kernels/spc_query/ops.py``).  The
 #: four bulk mutation helpers below are the ONLY label writers -- every
 #: update engine (IncSPC/DecSPC/HybSPC, replicated or edge-sharded) goes
 #: through them -- so maintaining the delta here keeps the cache exact

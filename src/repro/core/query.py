@@ -166,9 +166,8 @@ def count_upper_bound_rows(cnt_s, cnt_t):
 
     ``SpcQuery(s, t).cnt = sum over common hubs of cnt_s * cnt_t`` and
     every term is non-negative, so ``sum(cnt_s) * sum(cnt_t)`` bounds the
-    count AND every partial sum/product the fp32 kernel forms.  Rows whose
-    bound stays below 2^24 are therefore provably exact on the fp32 path
-    (pad entries carry cnt = 0 and do not inflate the bound).  float64 so
+    count AND every partial sum/product the fp32 kernel forms (pad
+    entries carry cnt = 0 and do not inflate the bound).  float64 so
     the bound itself cannot overflow (exact to 2^53); each row's sum
     saturates at 2^63 - 1 rather than wrap.
     """

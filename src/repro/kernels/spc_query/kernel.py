@@ -16,12 +16,21 @@ operand blocks (6 * L * block_b * 4 bytes, 768 KiB at L = 256, double
 buffered) plus a few [L, block_b] temporaries.
 
 Counts are fp32 *in the kernel only* (TPU VPU has no int64): exact up to
-2^24.  Callers must not invoke this kernel blind on dense/high-
-multiplicity graphs -- ``ops.index_query_batch`` (and the serving engine
-``repro.serve``) guard it with the per-row count bound and fall back to
-the int64 sorted-merge path when a row could exceed 2^24.  Under that
-bound every product and partial sum is an exact integer, so the order in
-which the column loop adds them does not change the result.
+2^24.  Callers must not serve this kernel's counts blind on dense/high-
+multiplicity graphs -- ``ops.exact_query_split`` (behind
+``ops.index_query_batch`` and the serving engine ``repro.serve``) reads
+each row's fp32 count and re-answers on the int64 sorted-merge path
+every row that reads 2^23 or more.  That is sound because of three facts
+of this body: hubs in a row are unique, so a column adds at most one
+term ``fl(fl(cs) * fl(ct))`` to its row's running sum; the minimum
+distance is exact int32 arithmetic, so the set of hubs at the minimum
+is exact; and the body uses the VPU only (no MXU, no bf16 passes).
+Hence a true count C below 2^24 makes every label count, product and
+partial sum an integer below 2^24 (real label counts are >= 1), so the
+kernel reads exactly C whatever the order of the adds; and C >= 2^24
+reads at least C (1 - 2^-24)^(L+3) >= 2^24 - (L + 3) > 2^23 for any L
+below 2^22.  A product is at most 2^126, finite: only a sum can reach
++inf, and inf reads "2^23 or more" too.
 
 All scalars in the body are explicit int32/fp32: the package enables
 x64, and a weakly typed Python int would trace as int64, which Mosaic

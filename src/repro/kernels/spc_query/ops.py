@@ -2,17 +2,15 @@
 
 Exactness contract: the kernel accumulates counts in fp32 (the TPU VPU
 has no int64), which represents integers exactly only up to
-``EXACT_COUNT_MAX = 2^24``.  ``index_query_batch`` therefore checks a
-cheap per-row bound (``sum(cnt_s) * sum(cnt_t)``, which dominates the
-true count and every fp32 partial sum -- see
-``repro.core.query.count_upper_bound_rows``) and answers every row that
-might exceed it on the int64 sorted-merge path instead of returning
-silently wrong counts.  The bound is enforced *per row*: in a mixed
-batch the kernel answers every row and one jitted dispatch re-answers
-the unprovable rows in int64 and patches them in (route
-``"pallas+merge"``); a batch where no row is provably exact degrades to
-the all-merge fallback (route ``"pallas->merge"``).  ``exact=False``
-restores the raw fp32 kernel contract for benchmarking.
+``EXACT_COUNT_MAX = 2^24``.  ``index_query_batch`` therefore runs the
+kernel on the whole batch and decides each row's exactness from the
+kernel's own fp32 answer: a real row that reads ``PATCH_AT = 2^23`` or
+more (or inf) is re-answered on the int64 sorted-merge path and patched
+in by one jitted dispatch (route ``"pallas+merge"``); a batch with no
+such row keeps the kernel's answers (route ``"pallas"``).  A row that
+reads under 2^23 is exact (see :func:`exact_query_split` for why), so
+no count is ever served rounded.  ``exact=False`` restores the raw fp32
+kernel contract for benchmarking.
 """
 
 from __future__ import annotations
@@ -22,13 +20,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.labels import SPCIndex
-from repro.core.query import (cached_count_bound, gather_rows, merge_rows,
-                              merge_rows_jit)
+from repro.core.query import gather_rows, merge_rows
 from repro.kernels.spc_query.kernel import spc_query_pallas
 from repro.spans import span
 
 #: Largest integer count the fp32 kernel is guaranteed to report exactly.
 EXACT_COUNT_MAX = 2 ** 24
+#: A real row whose fp32 kernel count is not below this is re-answered in
+#: int64; a count under it is exact (:func:`exact_query_split`).
+PATCH_AT = 2 ** 23
 
 
 def prep_rows(idx: SPCIndex, s, t):
@@ -44,18 +44,10 @@ def prep_rows(idx: SPCIndex, s, t):
     return hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t
 
 
-@jax.jit
-def gather_rows_with_bounds(idx: SPCIndex, s, t):
-    """One dispatch: kernel-ready rows + the per-row exactness bounds.
-
-    The rows feed *either* the Pallas kernel or the int64 merge fallback
-    (``merge_rows`` tolerates the re-padded t side), so the host-side
-    per-row route decision costs one gather and one [B]-vector sync.
-    The bound comes from the index's cached per-vertex ``cnt_sum`` field
-    (O(1) per row; equal to ``count_upper_bound_rows`` on the gathered
-    rows because the cache is maintained by every update engine).
-    """
-    return prep_rows(idx, s, t), cached_count_bound(idx, s, t)
+#: One dispatch of :func:`prep_rows`: the rows feed the kernel and, for
+#: the rows it patches, the int64 merge (``merge_rows`` tolerates the
+#: re-padded t side).
+prep_rows_jit = jax.jit(prep_rows)
 
 
 def rows_query_pallas(hub_s, dist_s, cnt_s, hub_t, dist_t, cnt_t, *,
@@ -92,21 +84,16 @@ def exact_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,
                       interpret: bool | None = None,
                       real_rows: int | None = None):
     """THE exactness-routed kernel call, shared by ``index_query_batch``
-    and the serving engine (through :func:`exact_query_split`): gather
-    once, check the per-row bound, keep the fp32 kernel's answer for
-    every row that is provably exact under it.
+    and the serving engine (through :func:`exact_query_split`).
 
     ``real_rows`` (optional) marks the tail beyond it as padding whose
     answers the caller discards -- the serving engine bucket-pads with
-    dump-row pairs (bound 0, trivially exact), and those must not drag
-    an all-inexact real batch onto the kernel.  The route is decided on
-    the real rows only; padding is never merged on a mixed batch.
+    dump-row pairs -- and padding is never patched.
 
-    Returns (dist int32[B], count int64[B], route) with route one of
-    ``"pallas"`` (all rows exact), ``"pallas+merge"`` (kernel on the
-    whole batch, the rows over the bound patched from the int64 merge)
-    or ``"pallas->merge"`` (no row provably exact; whole batch on the
-    int64 fallback).
+    Returns (dist int32[B], count int64[B], route) with route
+    ``"pallas"`` (the kernel's answers, no real row read 2^23 or more)
+    or ``"pallas+merge"`` (kernel on the whole batch, the real rows that
+    read 2^23 or more patched from the int64 merge).
     """
     d, c, route, _ = exact_query_split(idx, s, t, block_b=block_b,
                                        interpret=interpret,
@@ -120,47 +107,62 @@ def exact_query_split(idx: SPCIndex, s, t, *, block_b: int = 128,
     """:func:`exact_query_batch`, also returning how many real rows the
     int64 merge answered: ``(dist, count, route, merged)``.  The host
     steps between its dispatches are ``spc.read.*`` spans
-    (``repro.spans``)."""
+    (``repro.spans``).
+
+    The gather and the kernel are dispatched back to back; the one host
+    sync is on the kernel's fp32 counts (``spc.read.count_wait``).  A
+    real row is patched from the int64 merge iff its count ``c`` fails
+    ``c < PATCH_AT`` (so inf and NaN are patched too).  Every row left
+    unpatched is exact under the count contract, because the kernel
+    (VPU only: no MXU, no bf16 passes) adds, within a row, at most one
+    term ``fl(fl(cs) * fl(ct))`` per column (hubs in a row are unique),
+    and picks the hubs at the minimum distance in exact int32:
+
+    - a true count C < 2^24: every label count is >= 1, so every label
+      count, product and partial sum of the row is an integer below 2^24
+      and exact in fp32; the kernel reads R = C;
+    - C >= 2^24: each cast, product and add loses at most a factor
+      (1 - 2^-24), over at most L + 3 of them in the row's chain, so
+      R >= C (1 - 2^-24)^(L+3) >= 2^24 - (L + 3) > 2^23 for L < 2^22;
+    - a product is at most 2^126, finite; only a sum reaches +inf, and
+      inf fails ``c < PATCH_AT``.
+
+    So R < 2^23 implies C < 2^24, which implies R = C.  No count bound
+    is consulted.
+    """
     with span("spc.read.gather"):
-        rows, bounds = gather_rows_with_bounds(idx, s, t)
-    with span("spc.read.bound_wait"):
-        inexact = np.asarray(bounds) >= EXACT_COUNT_MAX  # one host sync
-    real = inexact if real_rows is None else inexact[:real_rows]
-    if not real.any():
-        with span("spc.read.kernel"):
-            d, c = rows_query_pallas(*rows, block_b=block_b,
-                                     interpret=interpret)
-            c = c.astype(jnp.int64)
-        return d, c, "pallas", 0
-    if real.all():
-        with span("spc.read.merge"):
-            d, c = merge_rows_jit(*rows)
-        return d, c, "pallas->merge", real.size
-    # Mixed batch: the kernel answers the whole batch (each row on its
-    # own, so the exact rows' answers are final), then one dispatch
-    # re-answers the inexact rows in int64 and patches them in.  The
-    # index vector is padded to a power of two with a repeat of a real
-    # inexact row, so the merge compiles once per padded length.
+        rows = prep_rows_jit(idx, s, t)
     with span("spc.read.kernel"):
         d, c = rows_query_pallas(*rows, block_b=block_b,
                                  interpret=interpret)
+    with span("spc.read.count_wait"):
+        counts = np.asarray(c)                       # one host sync
+    real = counts if real_rows is None else counts[:real_rows]
+    iex = np.flatnonzero(~(real < PATCH_AT)).astype(np.int32)
+    if not iex.size:
+        with span("spc.read.kernel"):
+            c = c.astype(jnp.int64)
+        return d, c, "pallas", 0
+    # One dispatch re-answers the patched rows in int64 and scatters them
+    # into the kernel's answers.  The index vector is padded to a power
+    # of two with a repeat of a real patched row, so the merge compiles
+    # once per padded length.
     with span("spc.read.merge"):
-        iex = np.nonzero(inexact)[0].astype(np.int32)
-        iex = np.pad(iex, (0, _pow2_at_least(iex.size) - iex.size),
-                     mode="edge")
+        k = iex.size
+        iex = np.pad(iex, (0, _pow2_at_least(k) - k), mode="edge")
         d, c = merge_patch(rows, iex, d, c)
-    return d, c, "pallas+merge", int(real.sum())
+    return d, c, "pallas+merge", k
 
 
 def index_query_batch(idx: SPCIndex, s, t, *, block_b: int = 128,
                       interpret: bool | None = None, exact: bool = True):
     """Batched (s, t) queries against the label matrices.
 
-    With ``exact=True`` (default) the per-row count bound is checked
-    host-side: rows provably < 2^24 run through the fp32 kernel, the
-    rest fall back to the int64 sorted-merge path; either way the result
-    is (dist int32[B], count int64[B]).  ``exact=False`` skips the check
-    and returns the kernel's raw (int32[B], float32[B]).
+    With ``exact=True`` (default) the kernel answers every row and the
+    rows whose fp32 count reads 2^23 or more are re-answered in int64
+    (:func:`exact_query_split`); the result is (dist int32[B], count
+    int64[B]).  ``exact=False`` skips the patch and returns the kernel's
+    raw (int32[B], float32[B]).
     """
     s = jnp.asarray(s)
     t = jnp.asarray(t)

@@ -17,21 +17,23 @@ that the *engineered* path instead of three diverging ones:
    ========  ==========================================  ===========
    route     when                                        counts
    ========  ==========================================  ===========
-   merge     default (CPU, or any row's bound >= 2^24)   int64 exact
-   pallas    TPU/kernel backend AND every per-row count  fp32, exact
-             bound ``sum(cnt_s) * sum(cnt_t)`` < 2^24    by the bound
+   merge     default off the kernel backend (CPU)        int64 exact
+   pallas    TPU/kernel backend AND no real row's fp32   fp32, exact
+             kernel count reads 2^23 or more             (see below)
    table     explicit only (eager-parity debugging; the  int64 exact
              O(L^2) arithmetic of the kernel, in jnp)
    ========  ==========================================  ===========
 
-   The exactness bound is enforced per row: on a mixed batch the
-   kernel answers every row and the rows over the bound are re-answered
-   in int64 and patched in, recorded as ``pallas+merge``; a batch
-   with no provably-exact row degrades whole to the merge path,
-   recorded as ``pallas->merge`` -- the silent-overflow bug this engine
-   exists to close.  ``interpret`` defaults from the backend at dispatch
-   time (compiled only on TPU), so an explicit ``route="pallas"`` works
-   on CPU/GPU hosts too.
+   Exactness is decided per row from the kernel's own answer: the
+   kernel answers every row, and the real rows whose fp32 count reads
+   2^23 or more (or inf) are re-answered in int64 and patched in,
+   recorded as ``pallas+merge``.  A row that reads under 2^23 is exact:
+   a true count under 2^24 reads exactly, and one at 2^24 or more cannot
+   read under 2^23 (``kernels/spc_query/ops.exact_query_split``), so a
+   rounded count -- the silent-overflow bug this engine exists to close
+   -- is never served.  ``interpret`` defaults from the backend at
+   dispatch time (compiled only on TPU), so an explicit
+   ``route="pallas"`` works on CPU/GPU hosts too.
 4. **Shard.**  ``QueryEngine.sharded`` wraps
    ``repro.core.distributed.make_sharded_query`` (index replicated,
    batch split over mesh axes) with the same pad-and-slice handling so
@@ -40,10 +42,7 @@ that the *engineered* path instead of three diverging ones:
 5. **Refresh.**  ``QueryEngine.serve_from(store)`` serves from a
    ``repro.serve.publish.SnapshotStore``: each batch pins one published
    (version, index) snapshot, the updater swaps new versions in
-   underneath without ever touching an in-flight batch, and the 2^24
-   routing bound is read off the snapshot's cached per-vertex
-   ``cnt_sum`` field -- O(1) per row, consistent across replicas
-   mid-refresh.
+   underneath without ever touching an in-flight batch.
 """
 
 from __future__ import annotations
@@ -307,9 +306,9 @@ class QueryEngine:
             paths = {"merge": b}
         else:
             # The shared exactness-routed kernel call: gathers once,
-            # syncs the per-row bound vector, and re-answers in int64
-            # only the rows that could exceed 2^24 on the fp32 path
-            # ("pallas" / "pallas+merge" / "pallas->merge").
+            # runs the kernel on the whole bucket, syncs its fp32 counts
+            # and re-answers in int64 only the real rows that read 2^23
+            # or more ("pallas" / "pallas+merge").
             d, c, chosen, merged = exact_query_split(
                 idx, s, t, block_b=self.block_b, interpret=self.interpret,
                 real_rows=b)

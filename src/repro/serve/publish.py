@@ -24,11 +24,10 @@ version-counted snapshot store between the updater and the replicas:
   had happened.  Version regressions (a stale updater republishing an
   old state) raise instead of silently rolling replicas back.
 
-* **The bound travels with the version.**  The per-vertex cached
-  ``cnt_sum`` field (``repro.core.labels``) rides inside the snapshot,
-  so the serving engine's 2^24 exactness routing decision is an O(1)
-  lookup on the *published* index -- every replica pinned on version k
-  routes from k's bound, consistent mid-refresh.
+* **The whole index travels with the version.**  Every field of the
+  published index, the per-vertex cached ``cnt_sum`` included
+  (``repro.core.labels``), rides inside the snapshot, so every replica
+  pinned on version k answers from k alone, consistent mid-refresh.
 
 * **The medium is pluggable.**  This store only *versions* snapshots;
   *moving* them between processes/hosts is a

@@ -9,8 +9,8 @@ surfaced at dispatch time, deep inside a serving closure.  A
 ========  ==============================================================
 kind      meaning
 ========  ==============================================================
-auto      backend-dependent default (merge on CPU/GPU, kernel on TPU
-          when every row's count bound allows it)
+auto      backend-dependent default (merge on CPU/GPU, kernel on TPU,
+          with int64 patches of the rows whose fp32 count reads >= 2^23)
 merge     jitted int64 sorted-merge -- exact everywhere
 table     explicit O(L^2) jnp table (eager-parity debugging)
 pallas    the Pallas kernel route, with its two knobs (``block_b``,

@@ -16,10 +16,11 @@ Rules for the spans of this package:
   every entry).
 
 The spans: ``spc.read`` (one reader batch) with ``spc.read.ryw_wait``,
-``.prep``, ``.gather``, ``.bound_wait``, ``.kernel`` and ``.merge``
+``.prep``, ``.gather``, ``.kernel``, ``.count_wait`` and ``.merge``
 inside it (``serve/service.py``, ``serve/engine.py``,
-``kernels/spc_query/ops.py``); on a mixed-exactness batch ``.merge``
-holds the one merge-and-patch dispatch of the rows over the count bound;
+``kernels/spc_query/ops.py``); ``.count_wait`` is the one host sync on
+the kernel's fp32 counts, and on a kernel batch ``.merge`` holds the one
+merge-and-patch dispatch of the rows that read 2^23 or more;
 ``spc.update.validate``, ``spc.update.apply`` (one event chunk) and
 ``spc.update.publish`` (``core/dynamic.py``); ``spc.build.round`` (one
 hub round of the batched build: its dispatch and overflow check,

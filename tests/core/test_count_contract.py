@@ -3,11 +3,12 @@
 A count is right if and only if it equals ``min(true count, 2^63 - 1)``
 (``repro.core.counts``).  On a full 36 x 36 grid the corner-to-corner
 count is C(70, 35) ~ 2^66.6; on a seeded perturbed grid counts stay
-small but exactness bounds mix.  Every read route of the built index --
-the int64 merge, the Pallas kernel (interpret mode) on the rows its
-bound proves exact, and the mixed split -- and the index after inserts
-and deletes through ``hyb_spc_batch`` must equal a Python-int BFS
-clamped at 2^63 - 1.
+small, with a few past 2^23.  Every read route of the built index --
+the int64 merge, the Pallas kernel (interpret mode) on the rows whose
+true count is under 2^23, and the mixed split, which patches the rows
+the kernel reads at 2^23 or more -- and the index after inserts and
+deletes through ``hyb_spc_batch`` must equal a Python-int BFS clamped
+at 2^63 - 1.
 """
 
 import numpy as np
@@ -24,9 +25,8 @@ from repro.core.graph import INF
 from repro.core.labels import (SPCIndex, bulk_remove, bulk_upsert,
                                empty_index, from_ref, recompute_cnt_sum)
 from repro.core.query import batched_query_merge
-from repro.kernels.spc_query.ops import (EXACT_COUNT_MAX, exact_query_split,
-                                         gather_rows_with_bounds,
-                                         rows_query_pallas)
+from repro.kernels.spc_query.ops import (PATCH_AT, exact_query_split,
+                                         prep_rows, rows_query_pallas)
 
 SIDE = 36
 FAR = 2 * (SIDE - 1)          # corner-to-corner hops
@@ -98,14 +98,15 @@ def _internal(dyn, v):
     return jnp.asarray(dyn.order.to_internal(np.asarray(v)), jnp.int32)
 
 
-def _pairs_with_bounds(b):
-    """(s, t, bound) over all targets of the graph's sources."""
-    dyn, n = b["dyn"], b["dyn"].n
+def _pairs_with_counts(b):
+    """(s, t, true count clamped at 2^63 - 1) over all targets of the
+    graph's sources."""
+    n = b["dyn"].n
     s = np.repeat(np.asarray(list(b["truth"])), n)
     t = np.tile(np.arange(n), len(b["truth"]))
-    _, bounds = gather_rows_with_bounds(dyn.index, _internal(dyn, s),
-                                        _internal(dyn, t))
-    return s, t, np.asarray(bounds)
+    counts = np.concatenate([np.asarray(b["truth"][int(a)][1], np.int64)
+                             for a in b["truth"]])
+    return s, t, counts
 
 
 def _check(b, s, t, d, c):
@@ -133,9 +134,9 @@ def test_far_corner_reads_count_max():
 @pytest.mark.parametrize("route", ["merge", "pallas", "mixed"])
 def test_read_routes_match_clamped_reference(built, route):
     dyn = built["dyn"]
-    s, t, bounds = _pairs_with_bounds(built)
-    exact = np.flatnonzero(bounds < EXACT_COUNT_MAX)
-    inexact = np.flatnonzero(bounds >= EXACT_COUNT_MAX)
+    s, t, counts = _pairs_with_counts(built)
+    exact = np.flatnonzero(counts < PATCH_AT)
+    inexact = np.flatnonzero(counts >= PATCH_AT)
     if route == "merge":
         d, c = dyn.query_batch(s, t, route="merge")
         _check(built, s, t, d, c)
@@ -145,8 +146,8 @@ def test_read_routes_match_clamped_reference(built, route):
     assert exact.size and inexact.size
     if route == "pallas":
         sel = exact[:: max(1, exact.size // 128)][:128]
-        rows, _ = gather_rows_with_bounds(
-            dyn.index, _internal(dyn, s[sel]), _internal(dyn, t[sel]))
+        rows = prep_rows(dyn.index, _internal(dyn, s[sel]),
+                         _internal(dyn, t[sel]))
         d, c = rows_query_pallas(*rows, interpret=True)
         _check(built, s[sel], t[sel], d, c)
         return

@@ -59,8 +59,8 @@ class TestSpcQueryKernel:
 
     def test_counts_above_2_24_fall_back_to_int64(self):
         """Regression: fp32 kernel counts are exact only to 2^24; the
-        wrapper's per-row bound must detect this and serve the batch on
-        the int64 merge path instead of silently rounding."""
+        wrapper must detect this from the kernel's own answer and serve
+        the row from the int64 merge path instead of silently rounding."""
         from repro.core.labels import from_ref
         from repro.core.refimpl import RefSPCIndex
         from repro.kernels.spc_query.ops import EXACT_COUNT_MAX

@@ -121,17 +121,19 @@ def test_pallas_overflow_falls_back_to_int64(dynamic_service):
     eng = QueryEngine()
     d, c = eng.query_batch(idx, [0], [1], route="pallas")
     assert (int(d[0]), int(c[0])) == (1, big)
-    assert eng.stats.routes == {"pallas->merge": 1}
+    # the kernel reads 2^24 for the row, so the row is patched from int64
+    assert eng.stats.routes == {"pallas+merge": 1}
+    assert eng.stats.route_pairs == {"merge": 1}
     # a small-count batch on the same engine still takes the kernel
     d, c = eng.query_batch(dynamic_service.index, [0], [1], route="pallas")
     assert "pallas" in eng.stats.routes
 
 
 def test_mixed_exactness_batch_splits_routes(dynamic_service):
-    """A batch mixing provably-exact and possibly-inexact rows must be
-    partitioned on the per-row bound -- exact rows keep the kernel, the
-    rest merge in int64 -- instead of dropping the whole batch to the
-    merge fallback (ROADMAP "mixed-exactness batches")."""
+    """A batch mixing exact rows and rows whose kernel count reads 2^23
+    or more must keep the kernel's answers for the exact rows and patch
+    only the rest from int64, instead of dropping the whole batch to the
+    merge (ROADMAP "mixed-exactness batches")."""
     big = 2 ** 24 + 1  # not representable in fp32
     ref = R.RefSPCIndex(3)
     ref.labels[0] = [(0, 0, 1)]
@@ -139,16 +141,18 @@ def test_mixed_exactness_batch_splits_routes(dynamic_service):
     ref.labels[2] = [(0, 1, 1), (2, 0, 1)]
     idx = from_ref(ref, l_cap=4)
     eng = QueryEngine()
-    # rows: (0,2) exact, (0,1) inexact (bound big+..), (2,2) exact self
+    # rows: (0,2) exact, (0,1) inexact (reads 2^24), (2,2) exact self
     d, c = eng.query_batch(idx, [0, 0, 2], [2, 1, 2], route="pallas")
     assert [int(x) for x in d] == [1, 1, 0]
     assert [int(x) for x in c] == [1, big, 1]  # inexact row still exact int64
     assert eng.stats.routes == {"pallas+merge": 1}
-    # the bucket's dump-row padding (bound 0) must NOT turn an
-    # all-inexact real batch into a split: stays the whole-batch fallback
+    assert eng.stats.route_pairs == {"pallas": 2, "merge": 1}
+    # an all-inexact real batch is a patch of every real row; the
+    # bucket's dump-row padding (count 0) is never patched
     d, c = eng.query_batch(idx, [0], [1], route="pallas")
     assert (int(d[0]), int(c[0])) == (1, big)
-    assert eng.stats.routes == {"pallas+merge": 1, "pallas->merge": 1}
+    assert eng.stats.routes == {"pallas+merge": 2}
+    assert eng.stats.route_pairs == {"pallas": 2, "merge": 2}
 
 
 #: Hub-0 counts of the heavy vertices 1, 3, 5: every pair touching one has

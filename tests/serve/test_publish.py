@@ -16,7 +16,8 @@ from repro.core import refimpl as R
 from repro.core.dynamic import DynamicSPC
 from repro.core.graph import INF
 from repro.core.labels import from_ref, recompute_cnt_sum
-from repro.core.query import cached_count_bound, count_upper_bound_rows
+from repro.core.query import (batched_query_merge, cached_count_bound,
+                              count_upper_bound_rows)
 from repro.data import graph_stream, random_graph_edges
 from repro.kernels.spc_query.ops import exact_query_batch, prep_rows
 from repro.serve import QueryEngine, SnapshotStore, load_snapshot
@@ -238,19 +239,22 @@ def _big_count_index():
 
 
 def _assert_bound_consistent(idx, s, t):
-    """The acceptance criterion: the cached bound equals the recomputed
-    per-batch bound, and exact_query_batch's routing decision made from
-    it matches what the recomputed bound would choose."""
+    """The cached bound equals the recomputed per-batch bound and bounds
+    every true count, and exact_query_batch patches a batch iff one of
+    its true counts reaches 2^23 (the kernel reads such a count as 2^23
+    or more, and any smaller one exactly)."""
     s = jnp.asarray(np.asarray(s, np.int32))
     t = jnp.asarray(np.asarray(t, np.int32))
     rows = prep_rows(idx, s, t)
     recomputed = np.asarray(count_upper_bound_rows(rows[2], rows[5]))
     cached = np.asarray(cached_count_bound(idx, s, t))
     np.testing.assert_array_equal(cached, recomputed)
-    _, _, route = exact_query_batch(idx, s, t)
-    inexact = recomputed >= 2 ** 24
-    want = ("pallas" if not inexact.any() else
-            "pallas->merge" if inexact.all() else "pallas+merge")
+    d, c, route = exact_query_batch(idx, s, t)
+    _, c_merge = batched_query_merge(idx, s, t)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(c_merge))
+    assert (recomputed >= np.asarray(c_merge)).all()
+    want = ("pallas+merge" if (np.asarray(c_merge) >= 2 ** 23).any()
+            else "pallas")
     assert route == want
 
 
