@@ -306,31 +306,46 @@ def hybrid_table(n=300, m=800, n_insert=48, n_delete=16, batch_size=16,
                  seed=6) -> List[Dict]:
     """Hybrid update replay (Section 4.4 workload): compares wall time
     and number of jitted dispatches for three strategies on the SAME
-    mixed stream.  ``rebuild_per_event`` is the paper's reconstruction
-    baseline, extrapolated from one measured rebuild on the final
-    graph."""
+    mixed stream.  ``per_event`` replays it one dispatch of the paper's
+    ``inc_spc`` / ``dec_spc`` per event (overflow read after each) at
+    the label capacity the batched replay ends at, which holds every
+    state of the stream; ``rebuild_per_event`` is
+    the paper's reconstruction baseline, extrapolated from one measured
+    rebuild on the final graph."""
+    from repro.core import graph as G
+    from repro.core.decremental import dec_spc
+    from repro.core.incremental import inc_spc
     from repro.core.labels import to_ref
 
     edges = random_graph_edges(n, m, seed=seed)
     events = graph_stream(edges, n, n_insert, n_delete, seed=seed)
     E = len(events)
 
+    def per_event(svc, stream):
+        g = G.ensure_capacity(svc.graph, 2 * E)  # one shape for all
+        idx = svc.index
+        for op, a, b in stream:
+            g, idx = (inc_spc if op == "+" else dec_spc)(g, idx, a, b)
+            if int(idx.overflow):
+                raise RuntimeError("per-event replay outgrew its l_cap")
+        return idx
+
     # warm both jit paths on scratch replicas so the timed runs measure
     # steady-state dispatch cost, not compilation
     warm = DynamicSPC(n, edges, l_cap=32)
     warm.apply_events(events, batch_size=batch_size)
-    warm2 = DynamicSPC(n, edges, l_cap=32)
     k = E - 1  # shortest prefix containing both op kinds, so the
-    seen = set()  # per-event path compiles inc_spc AND dec_spc here
+    seen = set()  # per-event replay compiles inc_spc AND dec_spc here
     for k, (op, _, _) in enumerate(events):
         seen.add(op)
         if len(seen) == 2:
             break
-    warm2.apply_events(events[: k + 1], batch_size=None)
+    l_cap = warm.index.l_cap
+    per_event(DynamicSPC(n, edges, l_cap=l_cap), events[: k + 1])
 
-    svc_seq = DynamicSPC(n, edges, l_cap=32)
+    svc_seq = DynamicSPC(n, edges, l_cap=l_cap)
     t0 = _timer()
-    svc_seq.apply_events(events, batch_size=None)
+    idx_seq = per_event(svc_seq, events)
     t_seq = _timer() - t0
 
     svc_bat = DynamicSPC(n, edges, l_cap=32)
@@ -339,7 +354,7 @@ def hybrid_table(n=300, m=800, n_insert=48, n_delete=16, batch_size=16,
     t_bat = _timer() - t0
 
     maintained = to_ref(svc_bat.index).labels
-    identical = to_ref(svc_seq.index).labels == maintained
+    identical = to_ref(idx_seq).labels == maintained
 
     t0 = _timer()
     svc_bat.rebuild()

@@ -11,11 +11,11 @@ signal at once; no adjacency structure is consulted.
 Beyond the classic heuristic, :func:`recommendation_features` exposes a
 per-candidate feature row built entirely from snapshot state --
 
-    [d(u, x), sigma(u, x), size[x], cnt_sum[x]]
+    [d(u, x), sigma(u, x), size[x], min(sum(cnt[x]), 2^63 - 1)]
 
-(distance, path count, label-row occupancy and the cached count mass,
-the latter two cheap popularity/coverage proxies the serving layer
-already maintains) -- which ``examples/analytics_spc.py`` feeds through
+(distance, path count, label-row occupancy and the row's count mass,
+the latter two cheap popularity/coverage proxies read off the label
+rows) -- which ``examples/analytics_spc.py`` feeds through
 the repo's GNN + embedding-bag stack: the first end-to-end "model
 consumes the dynamic index" scenario.  :func:`common_neighbor_ids`
 recovers the actual common-friend id list (two mask rows ANDed) for
@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.core import query as Q
+from repro.core.counts import COUNT_MAX
 from repro.core.graph import INF
 from repro.core.labels import SPCIndex
 
@@ -69,9 +70,10 @@ def recommend(idx: SPCIndex, u: int, *, k: int = 16) -> List[Recommendation]:
 
 def recommendation_features(idx: SPCIndex, u: int,
                             candidates: np.ndarray) -> np.ndarray:
-    """float32 [C, 4] feature rows ``[dist, sigma, size, cnt_sum]``
+    """float32 [C, 4] feature rows ``[dist, sigma, size, count mass]``
     for ``candidates``, all off the pinned snapshot (disconnected
-    candidates get dist = -1, sigma = 0)."""
+    candidates get dist = -1, sigma = 0; the count mass is the row's
+    count sum clamped at 2^63 - 1, summed on the host in Python ints)."""
     dist, cnt = _one_to_all(idx, u)
     dist = np.asarray(dist)
     cnt = np.asarray(cnt)
@@ -82,7 +84,8 @@ def recommendation_features(idx: SPCIndex, u: int,
         [d,
          cnt[c].astype(np.float32),
          np.asarray(idx.size)[c].astype(np.float32),
-         np.asarray(idx.cnt_sum)[c].astype(np.float32)],
+         np.minimum(np.asarray(idx.cnt)[c].astype(object).sum(axis=1),
+                    COUNT_MAX).astype(np.int64).astype(np.float32)],
         axis=1)
 
 

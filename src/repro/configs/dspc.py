@@ -21,8 +21,7 @@ class DSPCArchConfig:
     l_cap: int = 64           # label capacity per vertex
     query_batch: int = 1_048_576
     # -- construction knobs (repro.core.construct) ----------------------
-    construct_batch: int = 32   # hubs per batched-build round (PSPC);
-    # None / < 2 falls back to the sequential one-hub-per-round builder
+    construct_batch: int = 32   # hubs per batched-build round (PSPC)
     vertex_order: str = "id"    # "id" | "degree" hub-ordering strategy
     # -- SPCService knobs (repro.serve.service) -------------------------
     update_batch: int = 64    # events per jitted apply_events chunk

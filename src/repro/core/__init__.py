@@ -6,11 +6,11 @@ Layers (bottom-up):
 * ``labels``      -- the SPC-Index as padded label matrices + bulk ops.
 * ``query``       -- Algorithm 1 (pair queries) and dense one-vs-all.
 * ``bfs``         -- level-synchronous counting BFS (the TPU adaptation).
-* ``construct``   -- HP-SPC construction.
-* ``incremental`` -- IncSPC (Algorithms 2-3) + batched insertion.
-* ``decremental`` -- DecSPC (Algorithms 4-6) + batched deletion.
-* ``hybrid``      -- batched mixed insert/delete engine (one dispatch
-  per event chunk; Section 4.4 workloads).
+* ``construct``   -- HP-SPC construction (sequential oracle + batched).
+* ``incremental`` -- IncSPC (Algorithms 2-3), one event.
+* ``decremental`` -- DecSPC (Algorithms 4-6), one event.
+* ``hybrid``      -- the update engine: mixed insert/delete chunks in
+  one dispatch each (Section 4.4 workloads).
 * ``dynamic``     -- host-side service driver (capacity, events, state).
 * ``refimpl``     -- paper-faithful sequential oracle & baselines.
 * ``distributed`` -- shard_map variants: edge-sharded relaxation plugged
@@ -30,8 +30,8 @@ from repro.core.query import (pair_query, pre_pair_query, batched_query,
                               one_to_all)
 from repro.core.bfs import plain_spc_bfs, pruned_spc_bfs
 from repro.core.construct import build_index
-from repro.core.incremental import inc_spc, inc_spc_batch
-from repro.core.decremental import dec_spc, dec_spc_batch, srr_search
+from repro.core.incremental import inc_spc
+from repro.core.decremental import dec_spc, srr_search
 from repro.core.hybrid import OP_DELETE, OP_INSERT, hyb_spc_batch
 from repro.core.dynamic import DynamicSPC
 
@@ -41,8 +41,7 @@ __all__ = [
     "pair_query", "pre_pair_query", "batched_query", "batched_query_merge",
     "gather_rows", "merge_rows", "one_to_all",
     "plain_spc_bfs", "pruned_spc_bfs",
-    "build_index", "inc_spc", "inc_spc_batch",
-    "dec_spc", "dec_spc_batch", "srr_search",
+    "build_index", "inc_spc", "dec_spc", "srr_search",
     "hyb_spc_batch", "OP_INSERT", "OP_DELETE",
     "DynamicSPC",
 ]

@@ -4,11 +4,13 @@ Two builders share the pruned-BFS machinery of ``repro.core.bfs``:
 
 * :func:`build_index` -- the paper-faithful sequential builder: one hub
   at a time, fully jitted (one ``fori_loop`` over all n hubs).  Kept as
-  the differential oracle for everything below.
+  the differential oracle for everything below; no service path builds
+  with it.
 
 * :func:`build_index_batched` -- PSPC-style batched construction
-  (arXiv:2212.00977): ``hub_batch`` hubs run their pruned BFS *in
-  lockstep* inside one jitted ``lax.while_loop``
+  (arXiv:2212.00977), the builder ``repro.core.dynamic.DynamicSPC``
+  runs: ``hub_batch`` hubs run their pruned BFS *in lockstep* inside
+  one jitted ``lax.while_loop``
   (:func:`repro.core.bfs.multi_pruned_spc_bfs`), pruning against the
   labels committed by all earlier batches plus rank-masked in-batch
   pruning, and commit a whole batch of labels in one bulk scatter
@@ -96,6 +98,11 @@ def provision_l_cap(g: Graph, floor: int = 4) -> int:
     return min(cap, n + 1)
 
 
+#: Hubs per lockstep round: CONFIG's ``construct_batch`` and the default
+#: of every builder entry point.
+DEFAULT_HUB_BATCH = 32
+
+
 @partial(jax.jit, static_argnames=("hub_batch", "multi_relax_fn"))
 def _hub_batch_round(g: Graph, idx: SPCIndex, h0, hub_batch: int,
                      multi_relax_fn: MultiRelaxFn | None = None
@@ -123,7 +130,7 @@ def build_index_batched(
     g: Graph,
     l_cap: int | None = None,
     *,
-    hub_batch: int = 32,
+    hub_batch: int = DEFAULT_HUB_BATCH,
     order: str = "id",
     multi_relax_fn: MultiRelaxFn | None = None,
     on_regrow: Callable[[int], None] | None = None,

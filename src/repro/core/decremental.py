@@ -7,10 +7,10 @@ masks.  Phase 2 walks the affected hubs in rank order; per hub one
 PreQuery table + one pruned BFS + one bulk upsert + (for common hubs of a
 and b) one bulk removal.
 
-The isolated-vertex optimization (Section 3.2.3) lives in the host-side
-driver (``repro.core.dynamic``) since it short-circuits the whole
-procedure; the traced path below is correct for that case too, just
-slower.
+The isolated-vertex optimization (Section 3.2.3) short-circuits the
+whole procedure; it is folded into :func:`dec_spc_step_work`, the
+deletion step of the hybrid engine (``repro.core.hybrid``).  The plain
+:func:`dec_spc` below is correct for that case too, just slower.
 
 Every entry point accepts a ``relax_fn`` (static under jit) so both the
 SRRSearch BFSs and the per-hub repair BFS run against the abstract
@@ -137,7 +137,8 @@ def _dec_spc(g: Graph, idx: SPCIndex, a, b,
     return g2, idx
 
 
-#: Algorithm 4: delete edge (a, b) and repair the index.
+#: Algorithm 4: delete edge (a, b) and repair the index (one event; the
+#: hybrid engine runs :func:`dec_spc_step_work` instead).
 dec_spc = jax.jit(_dec_spc, static_argnames=("relax_fn",))
 
 
@@ -148,13 +149,11 @@ def dec_spc_step_work(g: Graph, idx: SPCIndex, a, b,
     path folded in, and the work it did (a fast path counts as one
     ``isolated_fast_path`` and no repair).
 
-    Mirrors the host driver's ``delete_edge`` exactly: when the
-    lower-ranked endpoint has degree 1 it becomes isolated, is never a
-    hub in any other row, and its row collapses to the self label -- a
-    cheap masked reset instead of the full SRRSearch + per-hub repair.
-    Used by :func:`dec_spc_batch` and the hybrid engine
-    (``repro.core.hybrid``) so batched replay is bit-identical to the
-    per-event driver path.
+    When the lower-ranked endpoint has degree 1 it becomes isolated, is
+    never a hub in any other row, and its row collapses to the self
+    label -- a cheap masked reset instead of the full SRRSearch +
+    per-hub repair, leaving the labels :func:`dec_spc` would.  The
+    hybrid engine (``repro.core.hybrid``) runs every deletion here.
     """
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
@@ -172,43 +171,3 @@ def dec_spc_step_work(g: Graph, idx: SPCIndex, a, b,
 
     return jax.lax.cond(deg_hi == 1, fast, full, (g, idx))
 
-
-def dec_spc_step(g: Graph, idx: SPCIndex, a, b,
-                 relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
-    """:func:`dec_spc_step_work` without the work counts."""
-    g2, idx, _ = dec_spc_step_work(g, idx, a, b, relax_fn)
-    return g2, idx
-
-
-#: One-dispatch variant of :func:`dec_spc_step` (the distributed updater
-#: and other single-delete callers jit here; the batch engines inline the
-#: traced body instead).
-dec_spc_step_jit = jax.jit(dec_spc_step, static_argnames=("relax_fn",))
-
-
-def _dec_spc_batch(g: Graph, idx: SPCIndex, edges: jax.Array,
-                   relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
-    def step(carry, edge):
-        g, idx = carry
-        a, b = edge[0], edge[1]
-
-        def apply(args):
-            g, idx = args
-            return dec_spc_step(g, idx, a, b, relax_fn)
-
-        g, idx = jax.lax.cond(a != b, apply, lambda x: x, (g, idx))
-        return (g, idx), None
-
-    (g, idx), _ = jax.lax.scan(step, (g, idx),
-                               edges.astype(jnp.int32))
-    return g, idx
-
-
-#: Batched DecSPC: delete ``edges`` int32[B, 2] sequentially inside ONE
-#: jitted call -- the decremental sibling of
-#: ``incremental.inc_spc_batch``.  Rows with a == b are skipped (use as
-#: padding for fixed batch shapes).  Caller guarantees every listed edge
-#: is present at its turn in the sequence.  Overflow from any step
-#: accumulates in the returned index's counter; the driver replays the
-#: pre-batch snapshot at a larger capacity.
-dec_spc_batch = jax.jit(_dec_spc_batch, static_argnames=("relax_fn",))

@@ -40,9 +40,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import decremental as D
 from repro.core import hybrid as H
-from repro.core import incremental as I
 from repro.core.bfs import compress_frontier
 from repro.core.construct import build_index, build_index_batched
 from repro.core.counts import relax_sums
@@ -146,13 +144,8 @@ class DistributedUpdater:
     num_shards: int
     relax_fn: Callable
     multi_relax_fn: Callable  # bfs.MultiRelaxFn, edge-sharded
-    build_index: Callable    # (g, l_cap) -> SPCIndex
+    build_index: Callable    # (g, l_cap) -> SPCIndex; the dry-run's builder
     build_index_batched: Callable  # (g, l_cap=None, hub_batch=, ...) -> SPCIndex
-    inc_spc: Callable        # (g, idx, a, b) -> (g, idx)
-    inc_spc_batch: Callable  # (g, idx, edges[B, 2]) -> (g, idx)
-    dec_spc: Callable        # (g, idx, a, b) -> (g, idx), no fast path
-    dec_spc_step: Callable   # dec_spc + traced isolated-vertex fast path
-    dec_spc_batch: Callable  # (g, idx, edges[B, 2]) -> (g, idx)
     hyb_spc_batch: Callable  # (g, idx, events[B, 3]) -> ((g, work), idx)
 
     def pad(self, g: Graph) -> Graph:
@@ -162,8 +155,9 @@ class DistributedUpdater:
 @lru_cache(maxsize=None)
 def make_distributed_updater(mesh: Mesh,
                              edge_axis: str = "model") -> DistributedUpdater:
-    """Edge-sharded IncSPC/DecSPC/HybSPC variants (ROADMAP "sharded
-    update path").
+    """The edge-sharded build and update engines: the batched builder
+    and HybSPC (the engines ``DynamicSPC(mesh=)`` runs), plus the
+    sequential builder behind :func:`make_distributed_builder`.
 
     Memoized on (mesh, edge_axis): jit keys the static ``relax_fn`` by
     identity, so handing every caller the SAME shard_map closure for
@@ -194,11 +188,6 @@ def make_distributed_updater(mesh: Mesh,
         build_index=partial(build_index, relax_fn=relax_fn),
         build_index_batched=partial(build_index_batched,
                                     multi_relax_fn=multi_relax_fn),
-        inc_spc=partial(I.inc_spc, relax_fn=relax_fn),
-        inc_spc_batch=partial(I.inc_spc_batch, relax_fn=relax_fn),
-        dec_spc=partial(D.dec_spc, relax_fn=relax_fn),
-        dec_spc_step=partial(D.dec_spc_step_jit, relax_fn=relax_fn),
-        dec_spc_batch=partial(D.dec_spc_batch, relax_fn=relax_fn),
         hyb_spc_batch=partial(H.hyb_spc_batch, relax_fn=relax_fn),
     )
 

@@ -2,20 +2,24 @@
 
 Responsibilities beyond the jitted algorithm steps:
 
+* one build engine -- the batched builder
+  (``construct.build_index_batched``), which grows label capacity per
+  hub round by itself;
+* one update engine -- every mutation (an edge, a vertex's edges, a
+  stream of mixed events: the Section 4.4 scenario) goes through
+  :meth:`DynamicSPC.apply_events`, chunked through the hybrid engine
+  ``repro.core.hybrid`` so a whole chunk costs one jitted dispatch;
+  the Section 3.2.3 isolated-vertex fast path runs inside it;
 * capacity management -- grows the edge arrays and the label matrices
-  (overflow-retry: every jitted update reports lost writes through the
-  index's ``overflow`` counter; the driver re-pads the *pre-op* snapshot
-  and replays the op, which is sound because all ops are functional);
-* the isolated-vertex fast path of Section 3.2.3;
+  (overflow-retry: the engine reports lost writes through the index's
+  ``overflow`` counter; the driver re-pads the *pre-chunk* snapshot
+  and replays the chunk, which is sound because all ops are functional);
 * vertex insertion/deletion (reduction to edge events, Section 3);
-* update batching (streams of mixed events, the Section 4.4 scenario,
-  chunked through the hybrid engine ``repro.core.hybrid`` so a whole
-  chunk costs one jitted dispatch);
 * stream validation (op tags, vertex bounds, presence/absence -- the
-  batched engine treats unknown tags as padding inside the trace, so
-  corrupted streams MUST be rejected host-side before dispatch);
-* distributed updates: ``mesh=`` swaps every build/update engine for
-  the edge-sharded variants of ``repro.core.distributed
+  engine treats unknown tags as padding inside the trace, so corrupted
+  streams MUST be rejected host-side before dispatch);
+* distributed updates: ``mesh=`` swaps both engines for the
+  edge-sharded variants of ``repro.core.distributed
   .make_distributed_updater`` (same algorithms, relaxation sharded over
   the mesh's edge axis) while this driver's capacity pre-provision and
   overflow-retry machinery runs unchanged, re-padding the edge arrays
@@ -35,7 +39,7 @@ recoverable, shardable form.
 from __future__ import annotations
 
 import dataclasses
-import threading
+import re
 from typing import Iterable, Sequence, Tuple
 
 import jax
@@ -45,11 +49,8 @@ import numpy as np
 from repro.analysis.shadow import make_lock
 from repro.core import graph as G
 from repro.core import labels as L
-from repro.core.construct import (build_index, build_index_batched,
-                                  provision_l_cap)
-from repro.core.decremental import dec_spc
+from repro.core.construct import DEFAULT_HUB_BATCH, build_index_batched
 from repro.core.graph import Graph
-from repro.core.incremental import inc_spc
 from repro.core.labels import SPCIndex
 from repro.core.order import (identity_ordering, ordering_from_state,
                               vertex_ordering)
@@ -86,8 +87,7 @@ class UpdateStatsView:
 class UpdateStats:
     inserts: int = 0
     deletions: int = 0
-    isolated_fast_path: int = 0  # deletions that took the fast path,
-    # on the host path and inside the hybrid engine's trace
+    isolated_fast_path: int = 0  # deletions that took the fast path
     label_regrows: int = 0
     edge_regrows: int = 0
     batches: int = 0          # jitted hybrid-engine dispatches
@@ -141,15 +141,14 @@ class DynamicSPC:
     def __init__(self, n: int, edges: Sequence[Tuple[int, int]] = (),
                  l_cap: int | None = 32, cap_e: int | None = None, *,
                  mesh=None, edge_axis: str = "model",
-                 construct_batch: int | None = None,
+                 construct_batch: int = DEFAULT_HUB_BATCH,
                  vertex_order: str = "id") -> None:
-        """``construct_batch`` >= 2 builds the index through the batched
-        PSPC-style constructor (``construct.build_index_batched``; same
-        index, fewer dispatches); ``vertex_order="degree"`` relabels the
-        vertex ids into degree-rank space at this driver's id boundary
-        (every public entry point translates; the engines keep their
-        rank == id invariant).  ``l_cap=None`` pre-provisions the label
-        capacity from the graph's degree statistics."""
+        """``construct_batch`` is the hub batch of the PSPC-style builder
+        (``construct.build_index_batched``); ``vertex_order="degree"``
+        relabels the vertex ids into degree-rank space at this driver's
+        id boundary (every public entry point translates; the engines
+        keep their rank == id invariant).  ``l_cap=None`` pre-provisions
+        the label capacity from the graph's degree statistics."""
         self.stats = UpdateStats()
         self._engine = None
         self._updater = None
@@ -170,27 +169,14 @@ class DynamicSPC:
 
     # -- construction with overflow-retry ---------------------------------
     def _build(self, l_cap: int | None) -> SPCIndex:
-        if self._construct_batch is not None and self._construct_batch >= 2:
-            # batched constructor: overflow-retry happens inside, per
-            # hub round from the pre-round snapshot (committed labels
-            # survive); the stats hook keeps regrow accounting at parity
-            # with the sequential path below
-            build_b = (self._updater.build_index_batched
-                       if self._updater is not None else build_index_batched)
-            return build_b(
-                self.graph, l_cap, hub_batch=self._construct_batch,
-                on_regrow=lambda _cap: self.stats.bump(label_regrows=1),
-                on_round=lambda r: self.stats.bump(build_relax_rounds=r))
-        if l_cap is None:
-            l_cap = provision_l_cap(self.graph)
-        build = (self._updater.build_index if self._updater is not None
-                 else build_index)
-        while True:
-            idx = build(self.graph, l_cap)
-            if int(idx.overflow) == 0:
-                return idx
-            l_cap *= 2
-            self.stats.bump(label_regrows=1)
+        # overflow-retry happens inside the builder, per hub round from
+        # the pre-round snapshot (committed labels survive)
+        build = (self._updater.build_index_batched
+                 if self._updater is not None else build_index_batched)
+        return build(
+            self.graph, l_cap, hub_batch=self._construct_batch,
+            on_regrow=lambda _cap: self.stats.bump(label_regrows=1),
+            on_round=lambda r: self.stats.bump(build_relax_rounds=r))
 
     def rebuild(self) -> None:
         """Reconstruction baseline (what the paper's HP-SPC rerun does)."""
@@ -281,76 +267,12 @@ class DynamicSPC:
             raise ValueError(f"self loop ({a},{b}) not allowed")
 
     def insert_edge(self, a: int, b: int) -> None:
-        self._check_edge_ids(a, b)
-        a, b = self.order.to_internal(a), self.order.to_internal(b)
-        if bool(G.has_edge(self.graph, a, b)):
-            raise ValueError(f"edge ({a},{b}) already present")
-        self.graph = self._pad_for_mesh(G.ensure_capacity(self.graph, 2))
-        inc = (self._updater.inc_spc if self._updater is not None
-               else inc_spc)
-        while True:
-            g2, idx2 = inc(self.graph, self.index, a, b)
-            if int(idx2.overflow) == 0:
-                self.graph, self.index = g2, idx2
-                break
-            self.index = L.repad(self.index, self.index.l_cap * 2)
-            self.stats.bump(label_regrows=1)
-        self.stats.bump(inserts=1)
-        self._commit()
+        """Insert edge (a, b): a one-event :meth:`apply_events`."""
+        self.apply_events([("+", a, b)])
 
     def delete_edge(self, a: int, b: int) -> None:
-        self._check_edge_ids(a, b)
-        a, b = self.order.to_internal(a), self.order.to_internal(b)
-        if not bool(G.has_edge(self.graph, a, b)):
-            raise ValueError(f"edge ({a},{b}) not present")
-        lo, hi = (a, b) if a < b else (b, a)
-        deg = G.degrees(self.graph)
-        if int(deg[hi]) == 1:
-            # Section 3.2.3: the lower-ranked endpoint becomes isolated and
-            # is never a hub elsewhere -- reset its row to the self label.
-            self.graph = G.delete_edge(self.graph, a, b)
-            self.index = L.reset_isolated_row(self.index, hi)
-            self.stats.bump(isolated_fast_path=1)
-        else:
-            # the isolated case was excluded host-side above, so both
-            # modes jit the same plain dec_spc body (shared compile cache)
-            dec = (self._updater.dec_spc if self._updater is not None
-                   else dec_spc)
-            while True:
-                g2, idx2 = dec(self.graph, self.index, a, b)
-                if int(idx2.overflow) == 0:
-                    self.graph, self.index = g2, idx2
-                    break
-                self.index = L.repad(self.index, self.index.l_cap * 2)
-                self.stats.bump(label_regrows=1)
-        self.stats.bump(deletions=1)
-        self._commit()
-
-    def insert_edges(self, edges) -> None:
-        """Batched insertion: one jitted call for the whole batch
-        (beyond-paper; see ``incremental.inc_spc_batch``)."""
-        from repro.core.incremental import inc_spc_batch
-        edges = [(a, b) for a, b in edges]
-        for a, b in edges:
-            self._check_edge_ids(a, b)
-        edges = self.order.edges_to_internal(edges)
-        for a, b in edges:
-            if bool(G.has_edge(self.graph, a, b)):
-                raise ValueError(f"edge ({a},{b}) already present")
-        self.graph = self._pad_for_mesh(
-            G.ensure_capacity(self.graph, 2 * len(edges)))
-        batch = (self._updater.inc_spc_batch if self._updater is not None
-                 else inc_spc_batch)
-        arr = jnp.asarray(np.asarray(edges, dtype=np.int32))
-        while True:
-            g2, idx2 = batch(self.graph, self.index, arr)
-            if int(idx2.overflow) == 0:
-                self.graph, self.index = g2, idx2
-                break
-            self.index = L.repad(self.index, self.index.l_cap * 2)
-            self.stats.bump(label_regrows=1)
-        self.stats.bump(inserts=len(edges))
-        self._commit()
+        """Delete edge (a, b): a one-event :meth:`apply_events`."""
+        self.apply_events([("-", a, b)])
 
     def insert_vertex(self) -> int:
         """Append an isolated vertex (lowest rank). Recompiles (n changes)."""
@@ -361,9 +283,9 @@ class DynamicSPC:
         return self.n - 1
 
     def delete_vertex(self, v: int,
-                      batch_size: int | None = DEFAULT_BATCH) -> None:
+                      batch_size: int = DEFAULT_BATCH) -> None:
         """Reduce to edge deletions (Section 3) and replay them through
-        the batched engine -- one jitted dispatch per chunk instead of
+        :meth:`apply_events` -- one jitted dispatch per chunk instead of
         one per incident edge."""
         self._check_vertex(v)
         vi = self.order.to_internal(v)
@@ -428,8 +350,9 @@ class DynamicSPC:
 
     def _validate_events(self, events) -> None:
         """Host-side simulation of the stream against the current edge
-        set: the batched engine has no way to raise mid-scan, so the
-        per-event error semantics are enforced up front."""
+        set: the engine has no way to raise mid-scan, so each event's
+        error (bad id, self loop, duplicate insert, absent delete) is
+        raised up front, before any chunk dispatches."""
         present = self._edge_set()
         for i, (op, a, b) in enumerate(events):
             try:
@@ -448,38 +371,29 @@ class DynamicSPC:
                 present.discard(key)
 
     def apply_events(self, events: Iterable[Tuple[str, int, int]],
-                     batch_size: int | None = DEFAULT_BATCH) -> None:
-        """Apply a stream of ('+'|'-', a, b) events (Section 4.4).
+                     batch_size: int = DEFAULT_BATCH) -> None:
+        """Apply a stream of ('+'|'-', a, b) events (Section 4.4): the one
+        path by which this driver mutates its graph and index.
 
-        By default the stream is chunked and each chunk replays inside
-        ONE jitted dispatch (``hybrid.hyb_spc_batch``), padded with
-        self-loop rows to a fixed shape.  Each chunk gets a single
-        edge-capacity pre-provision and the usual overflow-retry: on
-        label overflow anywhere in the chunk the *pre-chunk* snapshot is
-        re-padded at doubled capacity and the whole chunk replays (sound
-        because every op is functional).  ``batch_size=None`` (or <= 1)
-        falls back to one jitted dispatch per event -- kept as the
-        differential-testing and benchmark baseline.
+        The stream is validated whole, then chunked into ``batch_size``
+        events (>= 1), and each chunk replays inside ONE jitted dispatch
+        (``hybrid.hyb_spc_batch``), padded with self-loop rows to
+        ``batch_size`` rows.  Each chunk gets a single edge-capacity
+        pre-provision and the overflow-retry: on label overflow anywhere
+        in the chunk the *pre-chunk* snapshot is re-padded at doubled
+        capacity and the whole chunk replays (sound because every op is
+        functional).
         """
-        batched = batch_size is not None and batch_size > 1
+        if isinstance(batch_size, bool) or \
+                not isinstance(batch_size, (int, np.integer)) or \
+                batch_size < 1:
+            raise ValueError(
+                f"batch_size must be an int >= 1, got {batch_size!r}")
         with span("spc.update.validate"):
-            events = self._normalize_events(events)
-            if batched:
-                # the per-event fallback below translates inside
-                # insert_edge / delete_edge; the chunked path translates
-                # here, once, before the stream is simulated against the
-                # (internal-id) edge set
-                events = [(op, self.order.to_internal(a),
-                           self.order.to_internal(b))
-                          for op, a, b in events]
-                self._validate_events(events)
-        if not batched:
-            for op, a, b in events:
-                if op == "+":
-                    self.insert_edge(a, b)
-                else:
-                    self.delete_edge(a, b)
-            return
+            events = [(op, self.order.to_internal(a),
+                       self.order.to_internal(b))
+                      for op, a, b in self._normalize_events(events)]
+            self._validate_events(events)
 
         from repro.core.hybrid import OP_DELETE, OP_INSERT, hyb_spc_batch
         hyb = (self._updater.hyb_spc_batch if self._updater is not None
@@ -536,12 +450,11 @@ class DynamicSPC:
             "graph.m2": self.graph.m2,
             "index.hub": self.index.hub, "index.dist": self.index.dist,
             "index.cnt": self.index.cnt, "index.size": self.index.size,
-            "index.cnt_sum": self.index.cnt_sum,
             "version": jnp.int64(self.version),
         }
         if not self.order.identity:
             # the external->rank permutation travels with the state; the
-            # default "id" order keeps the seed's 9-leaf schema verbatim
+            # default "id" order leaves it out (8 leaves)
             state["order.vertex_of"] = jnp.asarray(self.order.vertex_of,
                                                    jnp.int32)
         return state
@@ -592,8 +505,6 @@ class DynamicSPC:
         want("index.dist", hub)
         want("index.cnt", hub)
         want("index.size", (n + 1,))
-        if "index.cnt_sum" in host:
-            want("index.cnt_sum", (n + 1,))
         if "order.vertex_of" in host:
             want("order.vertex_of", (n,))
         if "version" in host:
@@ -606,8 +517,11 @@ class DynamicSPC:
     @classmethod
     def from_state_dict(cls, n: int, state: dict, *,
                         mesh=None, edge_axis: str = "model",
-                        construct_batch: int | None = None) -> "DynamicSPC":
-        host = cls._validate_state(n, state)
+                        construct_batch: int = DEFAULT_HUB_BATCH
+                        ) -> "DynamicSPC":
+        # states written while the index cached its row sums carry them
+        host = cls._validate_state(n, {k: v for k, v in state.items()
+                                       if k != "index.cnt_sum"})
         obj = cls.__new__(cls)
         obj.stats = UpdateStats()
         obj._engine = None
@@ -624,15 +538,12 @@ class DynamicSPC:
             Graph(src=jnp.asarray(host["graph.src"], jnp.int32),
                   dst=jnp.asarray(host["graph.dst"], jnp.int32),
                   m2=jnp.asarray(host["graph.m2"], jnp.int32), n=n))
-        cnt = jnp.asarray(host["index.cnt"], jnp.int64)
-        # pre-cached-bound state dicts lack the field: rebuild the cache
-        cnt_sum = (jnp.asarray(host["index.cnt_sum"], jnp.int64)
-                   if "index.cnt_sum" in host else L.recompute_cnt_sum(cnt))
         obj.index = SPCIndex(
             hub=jnp.asarray(host["index.hub"], jnp.int32),
             dist=jnp.asarray(host["index.dist"], jnp.int32),
-            cnt=cnt, size=jnp.asarray(host["index.size"], jnp.int32),
-            cnt_sum=cnt_sum, overflow=jnp.int32(0), n=n)
+            cnt=jnp.asarray(host["index.cnt"], jnp.int64),
+            size=jnp.asarray(host["index.size"], jnp.int32),
+            overflow=jnp.int32(0), n=n)
         return obj
 
     @classmethod
@@ -640,28 +551,20 @@ class DynamicSPC:
                         mesh=None, edge_axis: str = "model") -> "DynamicSPC":
         """Restore from an on-disk ``repro.train.checkpoint`` directory.
 
-        Builds the restore template from the *committed manifest* rather
-        than from a live ``state_dict()``, so checkpoints written before
-        the cached-bound/version schema (7 leaves instead of 9) restore
-        too -- ``checkpoint.restore(dir, svc.state_dict())`` would
-        reject them on leaf count before :meth:`from_state_dict`'s
-        legacy handling could run.
+        Builds the restore template from the *committed manifest* -- its
+        leaf names (the dict keys of its treedef, in flatten order) and
+        shapes -- rather than from a live ``state_dict()``, so every
+        schema this driver has written restores: with or without
+        ``version``, ``order.vertex_of`` and the row-sum leaf older
+        states carry (which :meth:`from_state_dict` drops).
         """
         from repro.train import checkpoint as C
         man = C.manifest(path, step)
-        ordered = sorted(("graph.src", "graph.dst", "graph.m2", "index.hub",
-                          "index.dist", "index.cnt", "index.size",
-                          "index.cnt_sum", "order.vertex_of", "version"))
-        new = sorted(k for k in ordered if k != "order.vertex_of")
-        legacy = sorted(k for k in new
-                        if k not in ("index.cnt_sum", "version"))
-        for keys in (ordered, new, legacy):
-            if len(keys) == len(man["shapes"]):
-                break
-        else:
+        keys = re.findall(r"'([^']+)': \*", man["treedef"])
+        if len(keys) != len(man["shapes"]) or "index.hub" not in keys:
             raise ValueError(
-                f"checkpoint at {path} has {len(man['shapes'])} leaves; "
-                f"not a DynamicSPC state dict")
+                f"checkpoint at {path} has leaves {keys}; not a "
+                f"DynamicSPC state dict")
         tree_like = {
             k: np.empty(shape, dtype=np.dtype(dt))
             for k, shape, dt in zip(keys, man["shapes"], man["dtypes"])

@@ -5,8 +5,10 @@ Why batching, and why *sequential-inside-scan*
 ----------------------------------------------
 The paper's headline result is that maintaining the SPC-Index beats
 reconstruction by up to three orders of magnitude on hybrid update
-streams.  Our per-event driver already achieves the algorithmic part of
-that, but it pays one Python->XLA dispatch per event: for the small
+streams.  The per-event algorithms (``incremental.inc_spc``,
+``decremental.dec_spc``) achieve the algorithmic part of that, but
+driven one event at a time they pay one Python->XLA dispatch per
+event: for the small
 repaired regions typical of real streams, dispatch overhead -- argument
 flattening, executable lookup, device sync for the overflow check --
 dominates the actual repair work.  This is the same observation that
@@ -23,8 +25,9 @@ the graph with the edge still present.  Replaying events in stream order
 inside a single ``lax.scan`` therefore preserves the ESPC invariant
 (index answers == BFS counting) after EVERY prefix of the stream, not
 just at the end: step k of the scan sees exactly the (graph, index) pair
-the per-event driver would have seen, so by induction over the stream
-the scan's carry equals the per-event trajectory state-for-state.  What
+a one-event-at-a-time replay through ``inc_spc``/``dec_spc`` would have
+seen, so by induction over the stream the scan's carry equals that
+trajectory state-for-state (the tests hold it to exactly that).  What
 the batch buys is not a different algorithm but a different *execution*:
 one fused executable, one host round-trip for the overflow check, one
 capacity pre-provision -- the per-event overhead is paid once per chunk
@@ -37,12 +40,13 @@ Events are a tagged ``int32[B, 3]`` array of ``(op, a, b)`` rows:
 * ``op == OP_INSERT`` (1): insert undirected edge (a, b);
 * ``op == OP_DELETE`` (2): delete undirected edge (a, b), taking the
   Section 3.2.3 isolated-vertex fast path when the lower-ranked
-  endpoint has degree 1 (exactly like the per-event driver);
+  endpoint has degree 1 (``decremental.dec_spc_step_work``);
 * rows with ``a == b`` (any op, canonically ``(0, 0, 0)``) are padding
   and are skipped -- drivers pad chunks to a fixed B so the engine
   compiles once per shape.
 
-The caller (``repro.core.dynamic.DynamicSPC.apply_events``) guarantees
+The caller (``repro.core.dynamic.DynamicSPC.apply_events``, the one
+path by which the service mutates its index) guarantees
 edge-slot capacity for all insertions in the batch and validates the
 stream host-side (op tags resolved with the first bad row named --
 unknown tags hit the padding branch *inside the trace* and would

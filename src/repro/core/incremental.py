@@ -107,34 +107,9 @@ def _inc_spc(g: Graph, idx: SPCIndex, a, b,
 
 
 #: Algorithm 2: insert edge (a, b) and repair the index.  The caller
-#: guarantees the edge is absent and capacity is available
-#: (``repro.core.dynamic`` handles both plus overflow-retry).
+#: guarantees the edge is absent and capacity is available.  The
+#: service applies events through the hybrid engine
+#: (``repro.core.hybrid``), which runs the same traced body; this
+#: one-event entry point is the oracle the tests hold that engine to.
 inc_spc = jax.jit(_inc_spc, static_argnames=("relax_fn",))
 
-
-def _inc_spc_batch(g: Graph, idx: SPCIndex, edges: jax.Array,
-                   relax_fn: RelaxFn | None = None) -> tuple[Graph, SPCIndex]:
-    def step(carry, edge):
-        g, idx = carry
-        a, b = edge[0], edge[1]
-
-        def apply(args):
-            g, idx = args
-            return _inc_spc(g, idx, a, b, relax_fn)
-
-        g, idx = jax.lax.cond(a != b, apply, lambda x: x, (g, idx))
-        return (g, idx), None
-
-    (g, idx), _ = jax.lax.scan(step, (g, idx),
-                               edges.astype(jnp.int32))
-    return g, idx
-
-
-#: Batched IncSPC: apply ``edges`` int32[B, 2] sequentially inside ONE
-#: jitted call (beyond-paper: amortizes the per-update dispatch overhead
-#: that dominates small updates -- cf. BatchHL's motivation for distance
-#: labeling [Farhan et al., SIGMOD'22], but kept exactly sequential so
-#: ESPC holds after every prefix).  Rows with a == b are skipped (use as
-#: padding for fixed batch shapes).  Caller guarantees capacity for 2B
-#: directed slots and absence of the inserted edges.
-inc_spc_batch = jax.jit(_inc_spc_batch, static_argnames=("relax_fn",))

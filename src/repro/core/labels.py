@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.counts import COUNT_MAX, sat_add, sat_sum
+from repro.core.counts import COUNT_MAX
 from repro.core.graph import INF
 
 
@@ -36,7 +36,6 @@ class SPCIndex:
     dist: jax.Array  # int32[n + 1, L_cap], pad = INF
     cnt: jax.Array   # int64[n + 1, L_cap], pad = 0
     size: jax.Array  # int32[n + 1]
-    cnt_sum: jax.Array   # int64[n + 1]: sum of the row's counts (see below)
     overflow: jax.Array  # int32 scalar: #lost label writes (grow & retry)
     n: int = dataclasses.field(metadata=dict(static=True))
 
@@ -48,35 +47,11 @@ class SPCIndex:
         return jnp.sum(self.size)
 
 
-#: ``cnt_sum`` invariant -- ``cnt_sum[v] == min(sum(cnt[v]), 2^63 - 1)``
-#: at all times (the count contract, ``repro.core.counts``).
-#: ``sum(cnt[s]) * sum(cnt[t])`` is a per-row upper bound on a pair's
-#: count (``repro.core.query.count_upper_bound_rows``); caching the
-#: per-vertex factor on the index turns the per-batch O(B L) reduction
-#: into an O(1) lookup per row (``cached_count_bound``).  The serving
-#: read path no longer routes on it: it decides exactness from the
-#: kernel's own fp32 answer (``kernels/spc_query/ops.py``).  The
-#: four bulk mutation helpers below are the ONLY label writers -- every
-#: update engine (IncSPC/DecSPC/HybSPC, replicated or edge-sharded) goes
-#: through them -- so maintaining the delta here keeps the cache exact
-#: everywhere (differential-tested against :func:`recompute_cnt_sum`).
-#: Adds saturate; a saturated sum cannot be kept by subtraction, so a
-#: row at 2^63 - 1 that loses or replaces a label is summed again from
-#: its row (:func:`_resum`), which only ever runs where one is.
-
-
-def recompute_cnt_sum(cnt: jax.Array) -> jax.Array:
-    """The cached field from scratch (validation / legacy state dicts)."""
-    return sat_sum(cnt, axis=1)
-
-
-def _resum(cnt_sum: jax.Array, cnt: jax.Array, stale: jax.Array):
-    """``cnt_sum`` with the rows of ``stale`` summed again from ``cnt``;
-    the row sums run only when some row is stale."""
-    return jax.lax.cond(
-        jnp.any(stale),
-        lambda: jnp.where(stale, recompute_cnt_sum(cnt), cnt_sum),
-        lambda: cnt_sum)
+#: Counts keep the count contract (``repro.core.counts``): each entry is
+#: min(true count, 2^63 - 1).  The mutation helpers below are the ONLY
+#: label writers -- the build and every update engine
+#: (IncSPC/DecSPC/HybSPC, replicated or edge-sharded) go through them --
+#: so a change to how labels are stored is made here, once.
 
 
 def empty_index(n: int, l_cap: int) -> SPCIndex:
@@ -85,7 +60,6 @@ def empty_index(n: int, l_cap: int) -> SPCIndex:
         dist=jnp.full((n + 1, l_cap), INF, dtype=jnp.int32),
         cnt=jnp.zeros((n + 1, l_cap), dtype=jnp.int64),
         size=jnp.zeros(n + 1, dtype=jnp.int32),
-        cnt_sum=jnp.zeros(n + 1, dtype=jnp.int64),
         overflow=jnp.int32(0),
         n=n,
     )
@@ -101,7 +75,6 @@ def repad(idx: SPCIndex, new_cap: int) -> SPCIndex:
         dist=jnp.pad(idx.dist, ((0, 0), (0, pad)), constant_values=int(INF)),
         cnt=jnp.pad(idx.cnt, ((0, 0), (0, pad)), constant_values=0),
         size=idx.size,
-        cnt_sum=idx.cnt_sum,  # pad entries carry cnt = 0
         overflow=jnp.int32(0),
         n=idx.n,
     )
@@ -121,23 +94,19 @@ def add_vertices(idx: SPCIndex, count: int) -> SPCIndex:
     new_dist = np.full((n_new + 1, l_cap), int(INF), dtype=np.int32)
     new_cnt = np.zeros((n_new + 1, l_cap), dtype=np.int64)
     new_size = np.zeros(n_new + 1, dtype=np.int32)
-    new_cnt_sum = np.zeros(n_new + 1, dtype=np.int64)
     new_hub[: idx.n] = hub[: idx.n]
     new_dist[: idx.n] = np.asarray(idx.dist)[: idx.n]
     new_cnt[: idx.n] = np.asarray(idx.cnt)[: idx.n]
     new_size[: idx.n] = np.asarray(idx.size)[: idx.n]
-    new_cnt_sum[: idx.n] = np.asarray(idx.cnt_sum)[: idx.n]
     for k in range(count):  # self labels for the new vertices
         v = idx.n + k
         new_hub[v, 0] = v
         new_dist[v, 0] = 0
         new_cnt[v, 0] = 1
         new_size[v] = 1
-        new_cnt_sum[v] = 1
     return SPCIndex(
         hub=jnp.asarray(new_hub), dist=jnp.asarray(new_dist),
         cnt=jnp.asarray(new_cnt), size=jnp.asarray(new_size),
-        cnt_sum=jnp.asarray(new_cnt_sum),
         overflow=idx.overflow, n=n_new,
     )
 
@@ -162,9 +131,8 @@ def bulk_append(idx: SPCIndex, h, d_new, c_new, mask) -> SPCIndex:
     cnt = idx.cnt.at[rows, col].set(
         jnp.where(fits, c_new.astype(jnp.int64), idx.cnt[rows, col]))
     size = idx.size + fits.astype(jnp.int32)
-    cnt_sum = sat_add(idx.cnt_sum, jnp.where(fits, c_new, 0))
     return dataclasses.replace(
-        idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
+        idx, hub=hub, dist=dist, cnt=cnt, size=size,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
 
 
@@ -179,8 +147,7 @@ def bulk_append_batch(idx: SPCIndex, h0, d_new, c_new, mask) -> SPCIndex:
     would produce, including the overflow accounting: column offsets
     only grow along the lane axis, so the lanes that fit are precisely
     the first ``l_cap - size[v]`` kept ones, and everything later in
-    the row is counted lost (grow & retry, as ever).  ``cnt_sum`` is
-    maintained incrementally from the same fit mask.
+    the row is counted lost (grow & retry, as ever).
 
     Only valid during construction where batches arrive in ascending
     hub-id order (append keeps rows sorted); hub ids ``h0 + b >= n``
@@ -197,14 +164,12 @@ def bulk_append_batch(idx: SPCIndex, h0, d_new, c_new, mask) -> SPCIndex:
     hubs = jnp.broadcast_to(
         jnp.asarray(h0, jnp.int32) + jnp.arange(b, dtype=jnp.int32)[:, None],
         mask.shape)
-    c64 = c_new.astype(jnp.int64)
     hub = idx.hub.at[rows, cols].set(hubs, mode="drop")
     dist = idx.dist.at[rows, cols].set(d_new.astype(jnp.int32), mode="drop")
-    cnt = idx.cnt.at[rows, cols].set(c64, mode="drop")
+    cnt = idx.cnt.at[rows, cols].set(c_new.astype(jnp.int64), mode="drop")
     size = idx.size + jnp.sum(fits, axis=0, dtype=jnp.int32)
-    cnt_sum = sat_add(idx.cnt_sum, sat_sum(jnp.where(fits, c64, 0), axis=0))
     return dataclasses.replace(
-        idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
+        idx, hub=hub, dist=dist, cnt=cnt, size=size,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
 
 
@@ -217,8 +182,6 @@ def bulk_upsert(idx: SPCIndex, h, d_new, c_new, mask) -> SPCIndex:
     h = jnp.asarray(h, jnp.int32)
     eq = idx.hub == h                              # [n+1, L]
     has = jnp.any(eq, axis=1)                      # [n+1]
-    rows_i = jnp.arange(idx.n + 1)
-    old_c = idx.cnt[rows_i, jnp.argmax(eq, axis=1)]  # (h, .) value, if any
     # --- replace path -----------------------------------------------------
     rep = (mask & has)[:, None] & eq
     dist = jnp.where(rep, d_new[:, None].astype(jnp.int32), idx.dist)
@@ -254,15 +217,8 @@ def bulk_upsert(idx: SPCIndex, h, d_new, c_new, mask) -> SPCIndex:
                             cnt_sh)),
         cnt)
     size = idx.size + fits.astype(jnp.int32)
-    c64 = c_new.astype(jnp.int64)
-    replaced = mask & has
-    # replaced in place: the old value comes off an exact sum; freshly
-    # inserted: a saturated sum stays saturated
-    cnt_sum = sat_add(jnp.where(replaced, idx.cnt_sum - old_c, idx.cnt_sum),
-                      jnp.where(replaced | fits, c64, 0))
-    cnt_sum = _resum(cnt_sum, cnt, replaced & (idx.cnt_sum == COUNT_MAX))
     return dataclasses.replace(
-        idx, hub=hub, dist=dist, cnt=cnt, size=size, cnt_sum=cnt_sum,
+        idx, hub=hub, dist=dist, cnt=cnt, size=size,
         overflow=idx.overflow + jnp.sum(lost, dtype=jnp.int32))
 
 
@@ -290,11 +246,7 @@ def bulk_remove(idx: SPCIndex, h, mask) -> SPCIndex:
     cnt = jnp.where(actb & (cols >= posb),
                     jnp.where(last, jnp.int64(0), cnt_sh), idx.cnt)
     size = idx.size - act.astype(jnp.int32)
-    rows = jnp.arange(idx.n + 1)
-    cnt_sum = idx.cnt_sum - jnp.where(act, idx.cnt[rows, pos], 0)
-    cnt_sum = _resum(cnt_sum, cnt, act & (idx.cnt_sum == COUNT_MAX))
-    return dataclasses.replace(idx, hub=hub, dist=dist, cnt=cnt, size=size,
-                               cnt_sum=cnt_sum)
+    return dataclasses.replace(idx, hub=hub, dist=dist, cnt=cnt, size=size)
 
 
 def reset_isolated_row(idx: SPCIndex, v) -> SPCIndex:
@@ -314,7 +266,6 @@ def reset_isolated_row(idx: SPCIndex, v) -> SPCIndex:
         dist=idx.dist.at[v].set(row_dist),
         cnt=idx.cnt.at[v].set(row_cnt),
         size=idx.size.at[v].set(1),
-        cnt_sum=idx.cnt_sum.at[v].set(1),
     )
 
 
@@ -364,5 +315,4 @@ def from_ref(ref, l_cap: int | None = None) -> SPCIndex:
         size[v] = len(row)
     return SPCIndex(hub=jnp.asarray(hub), dist=jnp.asarray(dist),
                     cnt=jnp.asarray(cnt), size=jnp.asarray(size),
-                    cnt_sum=recompute_cnt_sum(jnp.asarray(cnt)),
                     overflow=jnp.int32(0), n=n)

@@ -13,12 +13,13 @@ Two evaluation strategies, both O(1)-control-flow for XLA:
   hub is queried against all vertices (turns the per-level O(n L^2) of a
   naive transcription into O(n L) per hub, computed once per BFS).
 
-Row-level cores (``gather_rows`` / ``merge_rows`` / ``table_rows`` /
-``count_upper_bound_rows``) operate on *gathered* label rows so callers
-that hold B (s, t) pairs gather each side exactly once and reuse the rows
-across routing decisions and evaluation -- this is the contract of the
-serving engine (``repro.serve``) and the sharded query path
-(``repro.core.distributed``).
+Row-level cores (``gather_rows`` / ``merge_rows`` / ``table_rows``)
+operate on *gathered* label rows so callers that hold B (s, t) pairs
+gather each side exactly once and reuse the rows across evaluations --
+this is the contract of the serving engine (``repro.serve``) and the
+sharded query path (``repro.core.distributed``).  No count bound is
+kept or consulted: the serving read path decides exactness from the
+kernel's own fp32 answer (``repro.kernels.spc_query.ops``).
 
 Counts keep the count contract (``repro.core.counts``): products and
 sums over common hubs saturate at 2^63 - 1.  Each evaluation keeps
@@ -159,34 +160,6 @@ merge_rows_jit = jax.jit(merge_rows)
 #: arithmetic as the Pallas kernel but int64 under the count contract.
 table_rows = _keeps_contract(_intersect, in_axes=(0, 0, 0, 0, 0, 0, None),
                              name="table_rows")
-
-
-def count_upper_bound_rows(cnt_s, cnt_t):
-    """Sound per-row upper bound on the pair count, [B] float64.
-
-    ``SpcQuery(s, t).cnt = sum over common hubs of cnt_s * cnt_t`` and
-    every term is non-negative, so ``sum(cnt_s) * sum(cnt_t)`` bounds the
-    count AND every partial sum/product the fp32 kernel forms (pad
-    entries carry cnt = 0 and do not inflate the bound).  float64 so
-    the bound itself cannot overflow (exact to 2^53); each row's sum
-    saturates at 2^63 - 1 rather than wrap.
-    """
-    tot_s = sat_sum(cnt_s, axis=1).astype(jnp.float64)
-    tot_t = sat_sum(cnt_t, axis=1).astype(jnp.float64)
-    return tot_s * tot_t
-
-
-def cached_count_bound(idx: SPCIndex, s, t):
-    """The same per-row bound as :func:`count_upper_bound_rows`, but from
-    the index's cached per-vertex ``cnt_sum`` field: two O(1) lookups per
-    row instead of an O(L) reduction per side.  The cache is maintained
-    incrementally by every update engine (see ``repro.core.labels``), so
-    a bound read off a published snapshot equals the bound recomputed
-    from that snapshot's rows -- routing stays consistent across serving
-    replicas mid-refresh.
-    """
-    return (idx.cnt_sum[s].astype(jnp.float64)
-            * idx.cnt_sum[t].astype(jnp.float64))
 
 
 def pre_pair_query(idx: SPCIndex, s, t):

@@ -493,10 +493,9 @@ def dspc_bundle(spec: ArchSpec, shape: ShapeSpec, smoke: bool) -> StepBundle:
                        dist=SDS((n + 1, l_cap), jnp.int32),
                        cnt=SDS((n + 1, l_cap), jnp.int64),
                        size=SDS((n + 1,), jnp.int32),
-                       cnt_sum=SDS((n + 1,), jnp.int64),
                        overflow=SDS((), jnp.int32), n=n)
-    index_spec = SPCIndex(hub=(), dist=(), cnt=(), size=(), cnt_sum=(),
-                          overflow=(), n=n)
+    index_spec = SPCIndex(hub=(), dist=(), cnt=(), size=(), overflow=(),
+                          n=n)
     # op-count proxy: per hub ~ one BFS over m edges + nL label merge
     build_ops = float(n) * (2.0 * m + 2.0 * n * l_cap) / 50.0
     update_ops = 2.0 * m + 4.0 * (n + 1) * l_cap

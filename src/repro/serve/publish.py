@@ -25,9 +25,9 @@ version-counted snapshot store between the updater and the replicas:
   old state) raise instead of silently rolling replicas back.
 
 * **The whole index travels with the version.**  Every field of the
-  published index, the per-vertex cached ``cnt_sum`` included
-  (``repro.core.labels``), rides inside the snapshot, so every replica
-  pinned on version k answers from k alone, consistent mid-refresh.
+  published index (``repro.core.labels``) rides inside the snapshot, so
+  every replica pinned on version k answers from k alone, consistent
+  mid-refresh.
 
 * **The medium is pluggable.**  This store only *versions* snapshots;
   *moving* them between processes/hosts is a

@@ -9,7 +9,7 @@ each serving host runs one puller thread per source transport that
    pure-polling media;
 2. **verifies before staging** -- the fetch path cross-checks the
    committed manifest against the payload (leaf count, version == step,
-   ``cnt_sum`` row count) and the group rejects a snapshot whose vertex
+   ``size`` row count) and the group rejects a snapshot whose vertex
    count disagrees with what it already serves, so a torn or foreign
    payload never reaches a reader;
 3. **swaps locally** -- the verified snapshot is published into the

@@ -96,6 +96,7 @@ from typing import Iterable, Sequence, Tuple
 
 from repro.analysis.shadow import (make_condition, make_lock,
                                    make_rlock)
+from repro.core.construct import DEFAULT_HUB_BATCH
 from repro.core.dynamic import DEFAULT_BATCH, DynamicSPC
 from repro.core.order import identity_ordering
 from repro.serve.engine import DEFAULT_BUCKETS, QueryEngine
@@ -225,7 +226,7 @@ class SPCService:
                  spc: DynamicSPC | None = None,
                  l_cap: int | None = 32, cap_e: int | None = None,
                  mesh=None, edge_axis: str = "model",
-                 construct_batch: int | None = None,
+                 construct_batch: int = DEFAULT_HUB_BATCH,
                  vertex_order: str = "id",
                  serve_mesh=None, batch_axes: Tuple[str, ...] = ("data",),
                  route: RoutePolicy | str | None = None,
@@ -241,6 +242,13 @@ class SPCService:
                  wait_timeout: float = 60.0) -> None:
         if role not in ROLES:
             raise ValueError(f"unknown role {role!r}; want one of {ROLES}")
+        if not isinstance(replicas, int) or replicas < 1:
+            raise ValueError(f"replicas must be >= 1, got {replicas!r}")
+        if not isinstance(queue_size, int) or queue_size < 1:
+            raise ValueError(f"queue_size must be >= 1, got {queue_size!r}")
+        if not isinstance(update_batch, int) or update_batch < 1:
+            raise ValueError(
+                f"update_batch must be an int >= 1, got {update_batch!r}")
         if role == "replica":
             if spc is not None or n is not None or edges:
                 raise ValueError(
@@ -258,14 +266,6 @@ class SPCService:
                              mesh=mesh, edge_axis=edge_axis,
                              construct_batch=construct_batch,
                              vertex_order=vertex_order)
-        if not isinstance(replicas, int) or replicas < 1:
-            raise ValueError(f"replicas must be >= 1, got {replicas!r}")
-        if not isinstance(queue_size, int) or queue_size < 1:
-            raise ValueError(f"queue_size must be >= 1, got {queue_size!r}")
-        if update_batch is not None and update_batch < 1:
-            raise ValueError(
-                f"update_batch must be >= 1 (or None for per-event "
-                f"replay), got {update_batch!r}")
         self._serve_mesh = serve_mesh
         self._batch_axes = tuple(batch_axes)
         self._policy = self._coerce_route(route)
@@ -927,7 +927,8 @@ class SPCService:
             l_cap=config.l_cap,
             update_batch=getattr(config, "update_batch", DEFAULT_BATCH),
             queue_size=getattr(config, "queue_size", 8),
-            construct_batch=getattr(config, "construct_batch", None),
+            construct_batch=getattr(config, "construct_batch",
+                                    DEFAULT_HUB_BATCH),
             vertex_order=getattr(config, "vertex_order", "id"),
         ), **{k: v for k, v in overrides.items() if k in (
             "l_cap", "update_batch", "queue_size", "construct_batch",

@@ -121,13 +121,12 @@ def snapshot_tree(snap: Snapshot) -> dict:
         "index.dist": np.asarray(idx.dist),
         "index.cnt": np.asarray(idx.cnt),
         "index.size": np.asarray(idx.size),
-        "index.cnt_sum": np.asarray(idx.cnt_sum),
         "version": np.int64(snap.version),
     }
 
 
 _SNAPSHOT_KEYS = sorted(("index.hub", "index.dist", "index.cnt",
-                         "index.size", "index.cnt_sum", "version"))
+                         "index.size", "version"))
 
 
 def _load_snapshot_once(path: str, step: int | None) -> Snapshot:
@@ -152,17 +151,15 @@ def _load_snapshot_once(path: str, step: int | None) -> Snapshot:
             path, got_step,
             f"payload version {version} does not match committed step "
             f"{got_step} / manifest version {meta.get('version')}")
-    if int(np.asarray(tree["index.cnt_sum"]).shape[0]) != n + 1:
+    rows = np.asarray(tree["index.size"]).shape[0]
+    if rows != n + 1:
         raise C.CheckpointCorruptError(
-            path, got_step,
-            f"cnt_sum has {np.asarray(tree['index.cnt_sum']).shape[0]} "
-            f"rows for manifest n={n}")
+            path, got_step, f"size has {rows} rows for manifest n={n}")
     idx = SPCIndex(
         hub=jnp.asarray(tree["index.hub"]),
         dist=jnp.asarray(tree["index.dist"]),
         cnt=jnp.asarray(tree["index.cnt"]),
         size=jnp.asarray(tree["index.size"]),
-        cnt_sum=jnp.asarray(tree["index.cnt_sum"]),
         overflow=jnp.int32(0), n=n)
     return Snapshot(version=version, index=idx)
 

@@ -7,8 +7,11 @@ import pytest
 
 from repro.analytics import (common_neighbor_ids, recommend, recommend_numpy,
                              recommendation_features)
+from repro.core import refimpl as R
+from repro.core.counts import COUNT_MAX
 from repro.core.dynamic import DynamicSPC
 from repro.core.graph import INF
+from repro.core.labels import from_ref
 from repro.data import graph_stream, random_graph_edges
 
 
@@ -46,6 +49,21 @@ def test_recommendation_features_rows():
     d, sigma = feats[:, 0], feats[:, 1]
     assert (d[0], sigma[0]) == (2.0, 1.0)
     assert (d[1], sigma[1]) == (-1.0, 0.0)  # disconnected sentinel
+
+
+def test_count_mass_feature_clamps_a_saturated_row():
+    """The fourth feature is the row's count sum in Python ints, clamped
+    at 2^63 - 1: a row of two 2^62 counts plus one reads 2^63 - 1 (an
+    int64 sum would wrap negative), a small row its exact sum."""
+    ref = R.RefSPCIndex(3)
+    ref.labels[0] = [(0, 0, 1)]
+    ref.labels[1] = [(0, 1, 2 ** 62), (1, 0, 2 ** 62 + 1)]
+    ref.labels[2] = [(0, 1, 5), (2, 0, 1)]
+    feats = recommendation_features(from_ref(ref, l_cap=4), 0,
+                                    np.asarray([1, 2]))
+    assert feats[0, 3] == np.float32(COUNT_MAX)
+    assert feats[1, 3] == np.float32(6)
+    np.testing.assert_array_equal(feats[:, 2], [2.0, 2.0])   # row sizes
 
 
 def test_features_sigma_equals_common_friend_count():

@@ -1,14 +1,16 @@
-"""Batched IncSPC (beyond-paper API): exact agreement with sequential
-application, padding rows skipped, overflow propagates.  Plus coverage
-for the driver's vertex-level events and the isolated-vertex fast path,
-checked against freshly rebuilt indexes."""
+"""All-insert chunks through the update engine: exact agreement with
+one-at-a-time IncSPC, padding rows skipped.  Plus coverage for the
+driver's vertex-level events and the isolated-vertex fast path, checked
+against freshly rebuilt indexes."""
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import graph as G
 from repro.core.dynamic import DynamicSPC
+from repro.core.hybrid import OP_INSERT, hyb_spc_batch
 from repro.core.labels import to_ref
-from repro.core.query import batched_query
+from repro.core.query import batched_query, pair_query
 from repro.data import random_graph_edges
 
 
@@ -23,40 +25,40 @@ def fresh_edges(n, present, k, rng):
     return out
 
 
-def test_batch_equals_sequential():
+def test_batch_equals_sequential(per_event):
+    """An all-insert apply_events chunk == one inc_spc per edge."""
     n = 120
     edges = random_graph_edges(n, 300, seed=0)
-    svc_seq = DynamicSPC(n, edges, l_cap=32)
-    svc_bat = DynamicSPC(n, edges, l_cap=32)
+    svc = DynamicSPC(n, edges, l_cap=32)
     rng = np.random.default_rng(3)
     present = set(edges)
-    batch = fresh_edges(n, present, 6, rng)
-    for a, b in batch:
-        svc_seq.insert_edge(a, b)
-    svc_bat.insert_edges(batch)
+    batch = [("+",) + e for e in fresh_edges(n, present, 6, rng)]
+    _, want = per_event(svc.graph, svc.index, batch)
+    svc.apply_events(batch)
+    assert svc.stats.batches == 1 and svc.stats.inserts == len(batch)
+    assert to_ref(svc.index).labels == to_ref(want).labels
     for s in range(0, n, 17):
         for t in range(0, n, 13):
-            assert svc_seq.query(s, t) == svc_bat.query(s, t), (s, t)
+            assert svc.query(s, t) == tuple(
+                int(x) for x in pair_query(want, s, t)), (s, t)
 
 
-def test_batch_padding_rows_noop():
-    from repro.core.incremental import inc_spc_batch
+def test_batch_padding_rows_noop(per_event):
+    """Self-loop rows of any op inside an all-insert hyb_spc_batch chunk
+    are skipped: the chunk leaves what the real inserts alone leave."""
     n = 40
     edges = random_graph_edges(n, 100, seed=1)
     svc = DynamicSPC(n, edges, l_cap=24)
     rng = np.random.default_rng(4)
     present = set(edges)
     real = fresh_edges(n, present, 3, rng)
-    padded = jnp.asarray(
-        np.asarray(real + [(7, 7), (0, 0)], np.int32))  # a==b pads
-    from repro.core import graph as G
+    padded = jnp.asarray(np.asarray(
+        [(OP_INSERT, a, b) for a, b in real]
+        + [(OP_INSERT, 7, 7), (0, 0, 0)], np.int32))  # a == b pads
     g = G.ensure_capacity(svc.graph, 2 * len(real) + 4)
-    g2, idx2 = inc_spc_batch(g, svc.index, padded)
+    (_, _), idx2 = hyb_spc_batch(g, svc.index, padded)
     assert int(idx2.overflow) == 0
-    # compare against plain sequential inserts
-    for a, b in real:
-        svc.insert_edge(a, b)
-    ref = svc.index
+    _, ref = per_event(svc.graph, svc.index, [("+", a, b) for a, b in real])
     np.testing.assert_array_equal(np.asarray(idx2.hub[: n]),
                                   np.asarray(ref.hub[: n]))
     np.testing.assert_array_equal(np.asarray(idx2.cnt[: n]),

@@ -23,7 +23,7 @@ from repro.core.counts import COUNT_MAX
 from repro.core.dynamic import DynamicSPC
 from repro.core.graph import INF
 from repro.core.labels import (SPCIndex, bulk_remove, bulk_upsert,
-                               empty_index, from_ref, recompute_cnt_sum)
+                               empty_index, from_ref)
 from repro.core.query import batched_query_merge
 from repro.kernels.spc_query.ops import (PATCH_AT, exact_query_split,
                                          prep_rows, rows_query_pallas)
@@ -161,18 +161,29 @@ def test_read_routes_match_clamped_reference(built, route):
     _check(built, s[sel], t[sel], d, c)
 
 
-def test_cnt_sum_saturates_after_build(built):
+def _row_sums(cnt):
+    """Each row's count sum in Python ints, clamped at 2^63 - 1."""
+    return np.asarray([min(sum(int(c) for c in row), COUNT_MAX)
+                       for row in np.asarray(cnt)], dtype=np.int64)
+
+
+def test_row_sums_saturate_after_build(built):
+    """The build stores clamped counts (none wraps negative), and on the
+    full grid only some rows hold counts summing past 2^63 - 1: the
+    saturating device sum agrees with the Python-int one there."""
     idx = built["dyn"].index
-    np.testing.assert_array_equal(np.asarray(idx.cnt_sum),
-                                  np.asarray(recompute_cnt_sum(idx.cnt)))
-    saturated = int((np.asarray(idx.cnt_sum) == COUNT_MAX).sum())
+    assert (np.asarray(idx.cnt) >= 0).all()
+    sums = _row_sums(idx.cnt)
+    np.testing.assert_array_equal(np.asarray(C.sat_sum(idx.cnt, axis=1)),
+                                  sums)
+    saturated = int((sums == COUNT_MAX).sum())
     assert (saturated > 0) == (built["name"] == "full")
 
 
 def test_updates_keep_the_contract(built):
-    """Inserts and deletes through ``hyb_spc_batch``: the cached row sums
-    stay the saturating sums, and reads of every event endpoint and of
-    the sources match a BFS of the final graph."""
+    """Inserts and deletes through ``hyb_spc_batch``: the stored counts
+    stay clamped, and reads of every event endpoint and of the sources
+    match a BFS of the final graph."""
     src = built["dyn"]
     dyn = DynamicSPC.from_state_dict(src.n, src.state_dict())
     ref = built["ref"].copy()
@@ -180,9 +191,8 @@ def test_updates_keep_the_contract(built):
     for op, a, b in built["events"]:
         (ref.add_edge if op == "+" else ref.remove_edge)(a, b)
     idx = dyn.index
-    np.testing.assert_array_equal(np.asarray(idx.cnt_sum),
-                                  np.asarray(recompute_cnt_sum(idx.cnt)))
-    assert (np.asarray(idx.cnt_sum) == COUNT_MAX).any() == (
+    assert (np.asarray(idx.cnt) >= 0).all()
+    assert (_row_sums(idx.cnt) == COUNT_MAX).any() == (
         built["name"] == "full")
     n = dyn.n
     reads = sorted({v for _, a, b in built["events"] for v in (a, b)}
@@ -193,7 +203,7 @@ def test_updates_keep_the_contract(built):
                np.arange(n), d, c)
 
 
-# -- the saturating helpers and the cached row sums ---------------------------
+# -- the saturating helpers and the bulk label edits -------------------------
 BIG = [0, 1, 3, 2 ** 31 - 1, 2 ** 31, 2 ** 32 + 5, 2 ** 40, 3 ** 25,
        2 ** 62, COUNT_MAX - 1, COUNT_MAX]
 
@@ -236,18 +246,17 @@ def _row_index(counts) -> SPCIndex:
     cnt = idx.cnt.at[0, :k].set(jnp.asarray(counts, jnp.int64))
     return SPCIndex(hub=hub, dist=dist, cnt=cnt,
                     size=idx.size.at[0].set(k),
-                    cnt_sum=recompute_cnt_sum(cnt),
                     overflow=idx.overflow, n=8)
 
 
 @pytest.mark.parametrize("edit", ["remove", "replace", "insert"])
 @pytest.mark.parametrize("counts", [[2 ** 62, 2 ** 62, 5],
                                     [7, 2 ** 40, 5]])
-def test_cnt_sum_upkeep_at_saturation(edit, counts):
-    """A row whose sum sits at 2^63 - 1 is summed again when it loses or
-    replaces a label; an unsaturated one keeps its exact running sum."""
+def test_bulk_edits_keep_counts_at_saturation(edit, counts):
+    """A row holding 2^62 counts (its sum past 2^63 - 1) keeps every
+    count exactly through a remove, a replace and an insert: the bulk
+    helpers move counts, they never add them up."""
     idx = _row_index(counts)
-    assert (int(idx.cnt_sum[0]) == COUNT_MAX) == (counts[0] == 2 ** 62)
     mask = jnp.zeros(9, bool).at[0].set(True)
     if edit == "remove":
         out = jax.jit(bulk_remove)(idx, 2, mask)
@@ -258,9 +267,10 @@ def test_cnt_sum_upkeep_at_saturation(edit, counts):
                                    jnp.full(9, 9, jnp.int64), mask)
         row = ([counts[0], 9, counts[2]] if edit == "replace"
                else counts + [9])
-    assert int(out.cnt_sum[0]) == min(sum(row), COUNT_MAX)
-    np.testing.assert_array_equal(np.asarray(out.cnt_sum),
-                                  np.asarray(recompute_cnt_sum(out.cnt)))
+    assert int(out.size[0]) == len(row)
+    assert [int(c) for c in out.cnt[0, :len(row)]] == row
+    assert (np.asarray(out.cnt[0, len(row):]) == 0).all()
+    assert _row_sums(out.cnt)[0] == min(sum(row), COUNT_MAX)
 
 
 def test_plain_reference_clamps_at_count_max():

@@ -68,7 +68,7 @@ SCRIPT = textwrap.dedent(
                 assert (int(d[i]), int(c[i])) == \\
                     (int(dist[t]), int(cnt[t])), (tag, s, t)
 
-    # 1. inserts (sharded inc_spc)
+    # 1. inserts (one-event chunks of the sharded hyb_spc_batch)
     def absent_edges(k):
         got, have = [], set(rg.edge_list())
         for a in range(n - 1):           # avoid the pendant vertex n-1
@@ -84,14 +84,14 @@ SCRIPT = textwrap.dedent(
         rg.add_edge(a, b)
     check("insert")
 
-    # 2. delete, full SRRSearch path (sharded dec_spc_step)
+    # 2. delete, full SRRSearch path
     a, b = edges[0]
     rep.delete_edge(a, b)
     sh.delete_edge(a, b)
     rg.remove_edge(a, b)
     check("delete")
 
-    # 3. isolated-vertex fast path (host-side, Section 3.2.3)
+    # 3. isolated-vertex fast path (inside the engine, Section 3.2.3)
     rep.delete_edge(2, n - 1)
     sh.delete_edge(2, n - 1)
     rg.remove_edge(2, n - 1)

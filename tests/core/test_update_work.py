@@ -90,11 +90,17 @@ def test_apply_events_counts_the_batched_path(batch_size):
     assert st.batched_events == len(events)
 
 
-def test_the_per_event_path_keeps_its_host_fast_path_count():
+def test_single_edge_calls_count_the_engine_work():
+    """insert_edge / delete_edge run as one-event chunks of the engine,
+    so they count its work -- the isolated-vertex fast path included --
+    and one batch each."""
     n = 24
     edges, events = _stream(n)
+    want = dict(zip(KEYS, np.sum(_reference(n, edges, events[:8]), axis=0)))
+    assert want["isolated_fast_path"] == 1
     svc = DynamicSPC(n, edges, l_cap=n + 2)
-    svc.apply_events(events[:8], batch_size=None)
+    for op, a, b in events[:8]:
+        (svc.insert_edge if op == "+" else svc.delete_edge)(a, b)
     st = svc.stats.snapshot()
-    assert st.isolated_fast_path == 1
-    assert (st.hub_repairs, st.relax_rounds) == (0, 0)  # not counted there
+    assert {k: getattr(st, k) for k in KEYS} == want
+    assert st.batches == st.batched_events == 8

@@ -45,11 +45,6 @@ def test_apply_events_batched_rejects_bad_ids(svc, a, b):
     assert to_ref(svc.index).labels == before
 
 
-def test_insert_edges_rejects_bad_ids(svc):
-    with pytest.raises(ValueError):
-        svc.insert_edges([(0, 19), (2, 20)])
-
-
 def test_query_rejects_bad_ids(svc):
     for s, t in ((-1, 0), (0, 20), (20, 20)):
         with pytest.raises(ValueError):
@@ -92,7 +87,7 @@ def test_from_state_dict_rejects_missing_key(svc):
 
 
 @pytest.mark.parametrize("key", ["graph.dst", "index.dist", "index.cnt",
-                                 "index.size", "index.cnt_sum"])
+                                 "index.size", "index.hub"])
 def test_from_state_dict_rejects_truncated_leaf(svc, key):
     """Regression: a truncated array used to silently build a corrupt
     service (gathers clamp into the dump row); now the offending key is
@@ -123,15 +118,18 @@ def test_from_state_dict_rejects_bad_m2_and_dtype(svc):
         DynamicSPC.from_state_dict(svc.n, state)
 
 
-def test_from_state_dict_accepts_legacy_dict(svc):
-    """Pre-cached-bound state dicts (no cnt_sum / version) must load,
-    rebuilding the cache from the stored counts."""
-    from repro.core.labels import recompute_cnt_sum
+@pytest.mark.parametrize("schema", ["row_sums", "no_version"])
+def test_from_state_dict_accepts_legacy_dict(svc, schema):
+    """State dicts of earlier schemas load: one whose index still
+    carries its cached row sums (``index.cnt_sum``, dropped on load),
+    and one from before the version counter (version 0)."""
     state = _state(svc)
-    del state["index.cnt_sum"]
-    del state["version"]
+    assert "index.cnt_sum" not in state
+    if schema == "row_sums":
+        state["index.cnt_sum"] = np.asarray(state["index.cnt"]).sum(axis=1)
+    else:
+        del state["version"]
     svc2 = DynamicSPC.from_state_dict(svc.n, state)
-    assert svc2.version == 0
-    np.testing.assert_array_equal(
-        np.asarray(svc2.index.cnt_sum),
-        np.asarray(recompute_cnt_sum(svc2.index.cnt)))
+    assert svc2.version == (svc.version if schema == "row_sums" else 0)
+    assert to_ref(svc2.index).labels == to_ref(svc.index).labels
+    assert sorted(svc2.state_dict()) == sorted(svc.state_dict())

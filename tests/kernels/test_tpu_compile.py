@@ -54,7 +54,6 @@ def _index(chip, l_cap):
     rows = (N + 1, l_cap)
     return SPCIndex(hub=chip(rows, jnp.int32), dist=chip(rows, jnp.int32),
                     cnt=chip(rows, jnp.int64), size=chip((N + 1,), jnp.int32),
-                    cnt_sum=chip((N + 1,), jnp.int64),
                     overflow=chip((), jnp.int32), n=N)
 
 

@@ -1,8 +1,8 @@
 """Snapshot publish subsystem: version monotonicity, reader pinning
 across concurrent swaps, overflow-retry atomicity, the publish ->
-checkpoint durability hook, and the cached ``cnt_sum`` routing bound
-(state-dict round trip + differential vs the recomputed per-batch bound
-and the ``bfs_spc`` oracle, replicated and ``mesh=`` modes)."""
+checkpoint durability hook, the kernel's exactness patch on published
+indexes, and served answers after a stream against the ``bfs_spc``
+oracle (replicated and ``mesh=`` modes)."""
 
 import threading
 
@@ -15,11 +15,10 @@ from jax.sharding import Mesh
 from repro.core import refimpl as R
 from repro.core.dynamic import DynamicSPC
 from repro.core.graph import INF
-from repro.core.labels import from_ref, recompute_cnt_sum
-from repro.core.query import (batched_query_merge, cached_count_bound,
-                              count_upper_bound_rows)
+from repro.core.labels import from_ref, to_ref
+from repro.core.query import batched_query_merge
 from repro.data import graph_stream, random_graph_edges
-from repro.kernels.spc_query.ops import exact_query_batch, prep_rows
+from repro.kernels.spc_query.ops import exact_query_batch
 from repro.serve import QueryEngine, SnapshotStore, load_snapshot
 from repro.serve.publish import Snapshot
 
@@ -34,7 +33,7 @@ def _one_insert_one_delete(svc):
 
 def _arrays(idx):
     return {k: np.asarray(getattr(idx, k)).copy()
-            for k in ("hub", "dist", "cnt", "size", "cnt_sum")}
+            for k in ("hub", "dist", "cnt", "size")}
 
 
 def _assert_index_equal(a, b):
@@ -95,14 +94,14 @@ def test_reader_pinned_while_next_version_is_written(svc):
     _assert_index_equal(store.current().index, svc.index)
 
 
-def test_swap_atomicity_under_overflow_retry():
+def test_swap_atomicity_under_overflow_retry(per_event):
     """A chunk that overflows and replays must publish exactly once --
     after the retry commits -- and never expose the overflowed
     intermediate index to readers."""
     n = 8
     star = [(0, v) for v in range(1, n)]           # fits exactly at l_cap=2
     svc = DynamicSPC(n, star, l_cap=2)
-    seq = DynamicSPC(n, star, l_cap=2)
+    seq = DynamicSPC(n, star, l_cap=n + 2)
     store = svc.attach_store()
     pinned = store.current()
     before = _arrays(pinned.index)
@@ -115,9 +114,8 @@ def test_swap_atomicity_under_overflow_retry():
         np.testing.assert_array_equal(arr, before[k], err_msg=k)
     front = store.current().index
     assert int(front.overflow) == 0
-    seq.apply_events(events, batch_size=None)      # per-event trajectory
-    from repro.core.labels import to_ref
-    assert to_ref(front).labels == to_ref(seq.index).labels
+    _, want = per_event(seq.graph, seq.index, events)  # per-event trajectory
+    assert to_ref(front).labels == to_ref(want).labels
 
 
 def test_serve_from_bit_identical_across_publish(svc):
@@ -158,7 +156,7 @@ def test_concurrent_updater_and_reader_threads(svc):
     store = svc.attach_store()
     eng = QueryEngine()
     serve = eng.serve_from(store)
-    expected = {0: _arrays(svc.index)["cnt_sum"]}
+    expected = {0: _arrays(svc.index)}
     events = graph_stream(sorted(svc._edge_set()), svc.n, 10, 5, seed=3)
     errors = []
 
@@ -166,7 +164,7 @@ def test_concurrent_updater_and_reader_threads(svc):
         try:
             for lo in range(0, len(events), 3):
                 svc.apply_events(events[lo:lo + 3], batch_size=3)
-                expected[svc.version] = _arrays(svc.index)["cnt_sum"]
+                expected[svc.version] = _arrays(svc.index)
         except Exception as e:  # pragma: no cover - surfaced below
             errors.append(e)
 
@@ -176,17 +174,18 @@ def test_concurrent_updater_and_reader_threads(svc):
     while th.is_alive():
         snap = store.current()
         seen.append(snap.version)
-        # a torn snapshot would break the cnt_sum invariant; the
-        # expected map only has versions the updater already recorded
-        # (publish happens inside apply_events, records after it)
+        # a torn snapshot would break a row's size == its real labels;
+        # the expected map only has versions the updater already
+        # recorded (publish happens inside apply_events, records after)
+        got = _arrays(snap.index)
         np.testing.assert_array_equal(
-            np.asarray(snap.index.cnt_sum),
-            np.asarray(recompute_cnt_sum(snap.index.cnt)),
+            got["size"], (got["hub"] < svc.n).sum(axis=1),
             err_msg=f"torn read at version {snap.version}")
         if snap.version in expected:
-            np.testing.assert_array_equal(
-                np.asarray(snap.index.cnt_sum), expected[snap.version],
-                err_msg=f"wrong state at version {snap.version}")
+            for k, arr in expected[snap.version].items():
+                np.testing.assert_array_equal(
+                    got[k], arr,
+                    err_msg=f"wrong {k} at version {snap.version}")
         d, c = serve([0, 1], [2, 3])
         assert d.shape == (2,)
     th.join()
@@ -228,7 +227,7 @@ def test_loaded_snapshot_serves_identically(svc, tmp_path):
     np.testing.assert_array_equal(np.asarray(c), np.asarray(c0))
 
 
-# -- cached cnt-sum bound ---------------------------------------------------
+# -- reads of published indexes -------------------------------------------
 def _big_count_index():
     big = 2 ** 24 + 1
     ref = R.RefSPCIndex(3)
@@ -238,50 +237,42 @@ def _big_count_index():
     return from_ref(ref, l_cap=4)
 
 
-def _assert_bound_consistent(idx, s, t):
-    """The cached bound equals the recomputed per-batch bound and bounds
-    every true count, and exact_query_batch patches a batch iff one of
-    its true counts reaches 2^23 (the kernel reads such a count as 2^23
-    or more, and any smaller one exactly)."""
+def _assert_patch_exact(idx, s, t):
+    """exact_query_batch answers as the int64 merge does, and patches a
+    batch iff one of its true counts reaches 2^23 (the kernel reads
+    such a count as 2^23 or more, and any smaller one exactly)."""
     s = jnp.asarray(np.asarray(s, np.int32))
     t = jnp.asarray(np.asarray(t, np.int32))
-    rows = prep_rows(idx, s, t)
-    recomputed = np.asarray(count_upper_bound_rows(rows[2], rows[5]))
-    cached = np.asarray(cached_count_bound(idx, s, t))
-    np.testing.assert_array_equal(cached, recomputed)
     d, c, route = exact_query_batch(idx, s, t)
-    _, c_merge = batched_query_merge(idx, s, t)
+    d_merge, c_merge = batched_query_merge(idx, s, t)
     np.testing.assert_array_equal(np.asarray(c), np.asarray(c_merge))
-    assert (recomputed >= np.asarray(c_merge)).all()
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(d_merge))
     want = ("pallas+merge" if (np.asarray(c_merge) >= 2 ** 23).any()
             else "pallas")
     assert route == want
 
 
-def test_cached_bound_matches_recomputed_on_engine_cases(svc):
+def test_exact_query_patches_published_indexes(svc):
     rng = np.random.default_rng(5)
-    _assert_bound_consistent(svc.index, rng.integers(0, svc.n, 64),
-                             rng.integers(0, svc.n, 64))
+    _assert_patch_exact(svc.index, rng.integers(0, svc.n, 64),
+                        rng.integers(0, svc.n, 64))
     svc.apply_events(graph_stream(sorted(svc._edge_set()), svc.n, 6, 3,
                                   seed=6), batch_size=4)
-    _assert_bound_consistent(svc.index, rng.integers(0, svc.n, 64),
-                             rng.integers(0, svc.n, 64))
+    _assert_patch_exact(svc.index, rng.integers(0, svc.n, 64),
+                        rng.integers(0, svc.n, 64))
     idx = _big_count_index()
-    _assert_bound_consistent(idx, [0, 0, 2], [2, 1, 2])   # mixed split
-    _assert_bound_consistent(idx, [0], [1])               # all-inexact
-    _assert_bound_consistent(idx, [2], [2])               # all-exact
+    _assert_patch_exact(idx, [0, 0, 2], [2, 1, 2])   # mixed split
+    _assert_patch_exact(idx, [0], [1])               # all-inexact
+    _assert_patch_exact(idx, [2], [2])               # all-exact
 
 
-def test_cached_bound_survives_state_dict_round_trip(svc):
+def test_index_survives_state_dict_round_trip(svc):
     svc.apply_events(_one_insert_one_delete(svc), batch_size=1)
     state = {k: np.asarray(v) for k, v in svc.state_dict().items()}
+    assert "index.cnt_sum" not in state
     svc2 = DynamicSPC.from_state_dict(svc.n, state)
     assert svc2.version == svc.version == 2
-    np.testing.assert_array_equal(np.asarray(svc2.index.cnt_sum),
-                                  np.asarray(svc.index.cnt_sum))
-    np.testing.assert_array_equal(
-        np.asarray(svc2.index.cnt_sum),
-        np.asarray(recompute_cnt_sum(svc2.index.cnt)))
+    _assert_index_equal(svc2.index, svc.index)
 
 
 def _oracle_tables(svc):
@@ -290,19 +281,15 @@ def _oracle_tables(svc):
 
 
 @pytest.mark.parametrize("use_mesh", [False, True])
-def test_cached_bound_differential_vs_bfs(use_mesh):
-    """cnt_sum stays exact under every engine (replicated and sharded):
-    after a mixed stream it equals the row sums AND the row sums agree
-    with BFS ground truth through the serving path."""
+def test_served_answers_differential_vs_bfs(use_mesh):
+    """After a mixed stream through either engine (replicated and
+    sharded) the published index serves BFS ground truth."""
     n = 24
     edges = random_graph_edges(n, 55, seed=7)
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("model",)) if use_mesh \
         else None
     svc = DynamicSPC(n, edges, l_cap=32, mesh=mesh)
     svc.apply_events(graph_stream(edges, n, 8, 4, seed=8), batch_size=4)
-    np.testing.assert_array_equal(
-        np.asarray(svc.index.cnt_sum),
-        np.asarray(recompute_cnt_sum(svc.index.cnt)))
     truth = _oracle_tables(svc)
     eng = QueryEngine()
     serve = eng.serve_from(svc.attach_store())
@@ -348,26 +335,51 @@ def test_attach_store_rejects_store_ahead_of_service(svc):
 
 def test_from_checkpoint_restores_new_and_legacy_layouts(svc, tmp_path):
     """On-disk round trip through the manifest-driven template, for the
-    9-leaf schema AND a pre-cached-bound 7-leaf checkpoint (which
-    ``checkpoint.restore(dir, svc.state_dict())`` would reject on leaf
-    count before the legacy handling could run)."""
+    8-leaf schema, a 9-leaf checkpoint whose index still carries its
+    cached row sums (``index.cnt_sum``), and a 7-leaf one from before
+    the version counter -- ``checkpoint.restore(dir, svc.state_dict())``
+    would reject the last two on leaf count."""
     from repro.train import checkpoint as C
 
     svc.apply_events(_one_insert_one_delete(svc), batch_size=8)
     new_dir, old_dir = str(tmp_path / "new"), str(tmp_path / "old")
-    C.save(new_dir, svc.version, svc.state_dict())
-    legacy = {k: v for k, v in svc.state_dict().items()
-              if k not in ("index.cnt_sum", "version")}
+    sums_dir = str(tmp_path / "sums")
+    state = svc.state_dict()
+    assert len(state) == 8
+    C.save(new_dir, svc.version, state)
+    C.save(sums_dir, svc.version, dict(
+        state, **{"index.cnt_sum": np.asarray(svc.index.cnt).sum(axis=1)}))
+    legacy = {k: v for k, v in state.items() if k != "version"}
     C.save(old_dir, 0, legacy)
-    svc2 = DynamicSPC.from_checkpoint(new_dir, svc.n)
-    assert svc2.version == svc.version
-    _assert_index_equal(svc2.index, svc.index)
+    for path in (new_dir, sums_dir):
+        svc2 = DynamicSPC.from_checkpoint(path, svc.n)
+        assert svc2.version == svc.version
+        _assert_index_equal(svc2.index, svc.index)
     svc3 = DynamicSPC.from_checkpoint(old_dir, svc.n)
     assert svc3.version == 0
-    _assert_index_equal(svc3.index, svc.index)  # cnt_sum rebuilt
+    _assert_index_equal(svc3.index, svc.index)
     with pytest.raises(ValueError, match="leaves"):
         C.save(str(tmp_path / "bad"), 0, {"x": np.zeros(3)})
         DynamicSPC.from_checkpoint(str(tmp_path / "bad"), svc.n)
+
+
+def test_from_checkpoint_restores_a_vertex_order(tmp_path):
+    """A degree-ordered state (9 leaves: ``order.vertex_of`` and no row
+    sums) restores by its own leaf names, and answers external-id
+    queries as the service that wrote it."""
+    from repro.train import checkpoint as C
+
+    n = 30
+    svc = DynamicSPC(n, random_graph_edges(n, 70, seed=11), l_cap=32,
+                     vertex_order="degree")
+    state = svc.state_dict()
+    assert len(state) == 9 and "order.vertex_of" in state
+    C.save(str(tmp_path), svc.version, state)
+    back = DynamicSPC.from_checkpoint(str(tmp_path), n)
+    assert not back.order.identity
+    _assert_index_equal(back.index, svc.index)
+    assert [back.query(s, 0) for s in range(n)] == \
+        [svc.query(s, 0) for s in range(n)]
 
 
 def test_snapshot_is_immutable_dataclass(svc):

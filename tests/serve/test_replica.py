@@ -25,7 +25,7 @@ N, M, SEED = 16, 36, 13
 
 def _arrays(idx):
     return {k: np.asarray(getattr(idx, k)).copy()
-            for k in ("hub", "dist", "cnt", "size", "cnt_sum")}
+            for k in ("hub", "dist", "cnt", "size")}
 
 
 def _assert_index_equal(a, b):

@@ -50,6 +50,15 @@ def _assert_matches_oracle(truth, s, t, d, c):
             assert (int(d[k]), int(c[k])) == (int(dist[tk]), int(cnt[tk]))
 
 
+@pytest.mark.parametrize("update_batch", [None, 0])
+def test_update_batch_must_be_positive(update_batch):
+    """Every chunk runs through the one update engine: there is no
+    per-event mode for ``update_batch=None`` to select, so it is refused
+    before anything is built."""
+    with pytest.raises(ValueError, match="update_batch"):
+        _service(update_batch=update_batch)
+
+
 # -- routing policies -------------------------------------------------------
 def test_route_policy_validation():
     for kind in ("auto", "merge", "table", "pallas"):

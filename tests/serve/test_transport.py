@@ -27,7 +27,7 @@ N, M, SEED = 16, 36, 13
 
 def _arrays(idx):
     return {k: np.asarray(getattr(idx, k)).copy()
-            for k in ("hub", "dist", "cnt", "size", "cnt_sum")}
+            for k in ("hub", "dist", "cnt", "size")}
 
 
 def _assert_index_equal(a, b):
@@ -206,6 +206,17 @@ def test_load_snapshot_rejects_version_step_mismatch(spc, tmp_path):
     C.save(str(tmp_path), 5, tree,
            {"n": spc.n, "l_cap": spc.index.l_cap, "version": 5})
     with pytest.raises(C.CheckpointCorruptError, match="does not match"):
+        load_snapshot(str(tmp_path))
+
+
+def test_load_snapshot_rejects_row_count_mismatch(spc, tmp_path):
+    """A payload whose ``index.size`` rows disagree with the manifest's
+    n (a foreign graph's index under this one's metadata) must fail
+    verification before any reader could pin it."""
+    tree = snapshot_tree(Snapshot(3, spc.index))
+    C.save(str(tmp_path), 3, tree,
+           {"n": spc.n + 1, "l_cap": spc.index.l_cap, "version": 3})
+    with pytest.raises(C.CheckpointCorruptError, match="rows for manifest"):
         load_snapshot(str(tmp_path))
 
 
